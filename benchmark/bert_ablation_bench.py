@@ -136,8 +136,6 @@ def main():
     args = ap.parse_args()
 
     import jax
-    if _os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     on_tpu = jax.default_backend() != "cpu"
     if on_tpu:
         cfg = dict(vocab=30522, b=args.batch, s=128, m=20, h=768)

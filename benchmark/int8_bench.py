@@ -33,8 +33,6 @@ def main():
 
     if args.cpu:
         _os.environ["JAX_PLATFORMS"] = "cpu"
-        import jax
-        jax.config.update("jax_platforms", "cpu")
 
     import mxnet_tpu as mx
     from mxnet_tpu import nd

@@ -38,8 +38,6 @@ def main():
 
     if args.cpu or not _os.environ.get("MXTPU_BENCH_ON_TPU"):
         _os.environ["JAX_PLATFORMS"] = "cpu"
-        import jax
-        jax.config.update("jax_platforms", "cpu")
 
     import jax
     import mxnet_tpu as mx

@@ -42,8 +42,6 @@ def main():
 
     if args.ctx == "cpu":
         _os.environ["JAX_PLATFORMS"] = "cpu"
-        import jax
-        jax.config.update("jax_platforms", "cpu")
 
     import mxnet_tpu as mx
     from mxnet_tpu import autograd, gluon, nd

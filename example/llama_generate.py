@@ -20,6 +20,9 @@ import sys as _sys
 
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(
     _os.path.abspath(__file__))))
+from tools import jax_cache
+
+jax_cache.place()   # before jax: compiled programs survive a restart
 
 import numpy as np
 

@@ -55,8 +55,6 @@ def main():
             ).strip()
         _os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    if not _os.environ.get("MXTPU_EXAMPLE_ON_TPU"):
-        jax.config.update("jax_platforms", "cpu")
 
     import numpy as np
 

@@ -125,7 +125,7 @@ def wire_defect_corpus() -> List[dict]:
     from jax.sharding import PartitionSpec as P
 
     from .. import parallel
-    from ..parallel._compat import shard_map
+    from jax import shard_map
     from ..parallel.planner import ShardingPlan
 
     mesh = parallel.make_mesh({"dp": 8})
@@ -164,7 +164,7 @@ def wire_defect_corpus() -> List[dict]:
     def _smap(f, n_in=1):
         specs = (P("dp"), P())[:n_in]
         outs = P() if n_in == 1 else (P("dp"), P())
-        return shard_map(f, mesh, in_specs=specs, out_specs=outs,
+        return shard_map(f, mesh=mesh, in_specs=specs, out_specs=outs,
                          check_vma=False)
 
     due = jax.ShapeDtypeStruct((), jnp.bool_)

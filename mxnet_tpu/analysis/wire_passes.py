@@ -198,14 +198,14 @@ def _eqn_axes(eqn) -> tuple:
 def _sub_jaxprs(eqn) -> list:
     """Every sub-jaxpr an eqn carries (pjit/scan/while ClosedJaxprs,
     shard_map's plain Jaxpr, cond's branch tuple), as plain Jaxprs."""
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     out = []
     for v in eqn.params.values():
         items = v if isinstance(v, (tuple, list)) else (v,)
         for item in items:
-            if isinstance(item, jax.core.Jaxpr):
+            if isinstance(item, Jaxpr):
                 out.append(item)
-            elif isinstance(item, jax.core.ClosedJaxpr):
+            elif isinstance(item, ClosedJaxpr):
                 out.append(item.jaxpr)
     return out
 
@@ -254,8 +254,7 @@ def _walk(jaxpr, obs_idx, gated, mesh_axes, legs, state):
     (matching ``collective_stats``'s count-per-HLO-text-appearance
     convention, so MXL804 compares like with like); cond branches are
     ``gated``; shard_map scopes its own mesh axis sizes."""
-    import jax
-    Literal = jax.core.Literal
+    from jax.extend.core import Literal
     n_out = len(jaxpr.outvars)
     idxset = {i % n_out for i in obs_idx} if (n_out and obs_idx) \
         else set()

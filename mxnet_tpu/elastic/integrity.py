@@ -300,7 +300,7 @@ def jit_block(spec: IntegritySpec, mesh, dp_axis: str, param_leaves,
     through the block) when no drill is armed, so the production
     program carries only the sampled fingerprint reductions."""
     from jax.sharding import PartitionSpec as P
-    from ..parallel._compat import shard_map
+    from jax import shard_map
     if spec is None:
         return grad_leaves, None
     other = tuple(a for a in mesh.axis_names if a != dp_axis)

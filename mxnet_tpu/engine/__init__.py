@@ -59,6 +59,10 @@ _dispatches = 0
 # acceptance contract ("a warm restart performs 0 fresh compiles") is
 # asserted against this counter.
 _fresh_compiles = 0
+# AOT executables replaced by the plain jit path (``_note_aot_demotion``):
+# a tier that silently stopped serving shows up here, not only as an
+# event.  A run on the chip asserts it stays 0.
+_aot_demotions = 0
 
 # -- telemetry plane (PR 4) -------------------------------------------------
 # The engine is the hottest seam in the process, so the telemetry
@@ -228,6 +232,18 @@ def _note_fresh_compile(name: str, seconds: Optional[float] = None):
                         "fresh-compile wall clock (s)").observe(seconds)
 
 
+def _note_aot_demotion(name: str, err: BaseException):
+    """Count an AOT executable demoted to the plain jit path and leave
+    the ``persist_error`` event that says why."""
+    global _aot_demotions
+    with _lock:
+        _aot_demotions += 1
+    t = _telem if _telem is not None else _telemetry()
+    if t._switch.enabled:
+        t.record_event("persist_error", op=name,
+                       error=f"aot demoted: {err!r}"[:300])
+
+
 class _TieredFn:
     """Memory-tier entry backed by the persistent tier (``persist.py``).
 
@@ -235,9 +251,13 @@ class _TieredFn:
     EXPLICIT per-aval-signature resolution: persistent tier (reload, no
     trace) -> fresh AOT ``lower().compile()`` (serialized back to disk).
     The explicit step is what makes a compiled-executable object exist
-    to serialize — a plain jit call never surfaces one.  Any failure in
-    the AOT/persist path demotes that signature to the plain jit path,
-    so the tier can cost time, never a dispatch.
+    to serialize — a plain jit call never surfaces one.
+
+    An AOT lower/compile failure is raised, never demoted: a quiet
+    switch to plain jit would hide that the device refused a program.
+    The one demotion left is an AOT executable that rejects an aval
+    drift with ``TypeError`` at call time, counted in ``cache_info()
+    ["aot_demotions"]``.
     """
 
     __slots__ = ("name", "persist_name", "_bound", "_donate", "_sig",
@@ -266,28 +286,17 @@ class _TieredFn:
             fn = self._by_aval.get(s)
             if fn is not None:
                 return fn, "cached"
-            try:
-                fn, src = persist.tiered_compile(
-                    self.persist_name, self._jit(), arrays,
-                    donate=self._donate, sig=self._sig,
-                    op_label=self.name)
-            except Exception as e:
-                # AOT lower/compile rejected these args (weak types,
-                # committed-device quirks, ...): the plain jit path
-                # absorbs anything — dispatch must never break on a
-                # cache-tier optimization
-                t = _telem if _telem is not None else _telemetry()
-                if t._switch.enabled:
-                    t.record_event("persist_error", op=self.name,
-                                   error=f"aot demoted: {e!r}"[:300])
-                fn, src = self._jit(), "jit"
+            fn, src = persist.tiered_compile(
+                self.persist_name, self._jit(), arrays,
+                donate=self._donate, sig=self._sig,
+                op_label=self.name)
             self._by_aval[s] = fn
             return fn, src
 
     def warm(self, arrays) -> str:
         """Ensure an executable exists for these avals (arrays or
         ``ShapeDtypeStruct``s) WITHOUT dispatching.  Returns where it
-        came from: ``cached`` / ``persist`` / ``compiled`` / ``jit``."""
+        came from: ``cached`` / ``persist`` / ``compiled``."""
         return self._resolve(persist.aval_sig(arrays), arrays)[1]
 
     def __call__(self, *arrays):
@@ -297,14 +306,16 @@ class _TieredFn:
             fn = self._resolve(s, arrays)[0]
         try:
             return fn(*arrays)
-        except TypeError:
-            # aval drift an AOT executable rejects (e.g. weak-typed
-            # scalar vs the committed one): demote this signature to
-            # the jit path permanently; a genuine arity/type error
-            # re-raises identically from the jit call
+        except TypeError as e:
+            # a drift the (shape, dtype) signature cannot see and an
+            # AOT executable rejects (e.g. a tuple argument now passed
+            # as a list): demote this signature to the jit path
+            # permanently; a genuine arity/type error re-raises
+            # identically from the jit call
             jit = self._jit()
             if fn is jit:
                 raise
+            _note_aot_demotion(self.name, e)
             with self._rlock:
                 self._by_aval[s] = jit
             return jit(*arrays)
@@ -539,8 +550,13 @@ def retrying_call(call, probe_arrays, op: str):
     backoff policy.  ``probe_arrays``: the input buffers whose
     deletion marks the dispatch as post-donation (never retried).
     Shared by ``invoke_compiled`` and the SPMD trainer's fused
-    dispatch."""
+    dispatch — which makes it the one place a device dispatch is
+    COUNTED (``cache_info()["dispatches"]``): one call here is one
+    executable launch, whichever path built the executable."""
     import time as _time
+    global _dispatches
+    with _lock:
+        _dispatches += 1
     san = _san
     if san is not None:
         # the lifetime sanitizer's dispatch-entry check (MXL701
@@ -588,9 +604,6 @@ def invoke_compiled(name: str, fcompute: Callable, attrs: dict, *arrays,
     dispatch when ``MXTPU_ENGINE_TYPE=NaiveEngine``.
     ``persist_name``: see :func:`get_compiled`.
     """
-    global _dispatches
-    with _lock:
-        _dispatches += 1
     t = _telem if _telem is not None else _telemetry()
     telem_on = t._switch.enabled
     key, sig = _cache_key(name, attrs, donate)
@@ -754,6 +767,7 @@ def cache_info() -> dict:
             "engine": "NaiveEngine" if is_naive() else "ThreadedEngine",
             "hits": _hits, "misses": _misses, "dispatches": _dispatches,
             "fresh_compiles": _fresh_compiles,
+            "aot_demotions": _aot_demotions,
             "persist": {"enabled": persist.enabled(),
                         "dir": persist.cache_dir() or "",
                         **persist.counters()},
@@ -815,9 +829,10 @@ def reset_counters():
     """Zero the hit/miss/dispatch/fresh-compile counters (cache entries
     untouched); the persistent tier's hit/miss/saved counters reset
     with them."""
-    global _hits, _misses, _dispatches, _fresh_compiles
+    global _hits, _misses, _dispatches, _fresh_compiles, _aot_demotions
     with _lock:
         _hits = _misses = _dispatches = _fresh_compiles = 0
+        _aot_demotions = 0
     persist.reset_counters()
 
 

@@ -64,6 +64,9 @@ __all__ = ["enabled", "cache_dir", "fingerprint", "aval_sig",
 LIBRARY_SALT = "mxtpu-compile-cache-1"
 
 _MAGIC = b"MXTPUCC1"
+#: header layout version; 2 added ``devices`` (an entry without it
+#: cannot be reloaded onto the right devices and reads as a miss)
+_FORMAT = 2
 _SUFFIX = ".mxc"
 
 _lock = threading.Lock()
@@ -307,7 +310,7 @@ def fetch(persist_name: str, sig, donate, avals,
     try:
         header, payload, _ = _read_entry(path)
         if header.get("fingerprint") != fingerprint() or \
-                header.get("format") != 1:
+                header.get("format") != _FORMAT:
             raise ValueError("fingerprint/format mismatch")
         fn = _deserialize(header, payload, donate)
     except Exception as e:
@@ -357,8 +360,19 @@ def _deserialize(header: dict, payload: bytes, donate):
     if kind == "exec":
         import pickle
         from jax.experimental import serialize_executable as se
+        import jax
         blob, in_tree, out_tree = pickle.loads(payload)
-        fn = se.deserialize_and_load(blob, in_tree, out_tree)
+        # an executable is compiled for an exact device list, and
+        # deserialize_and_load otherwise assumes EVERY device of the
+        # default backend (a one-device program reloaded on an 8-device
+        # host then demands 8 shards at call time).  A recorded device
+        # this process does not have is a KeyError -> miss -> fresh
+        # compile.
+        platform, ids = header["devices"]
+        by_id = {d.id: d for d in jax.devices(platform)}
+        fn = se.deserialize_and_load(
+            blob, in_tree, out_tree, backend=platform,
+            execution_devices=[by_id[i] for i in ids])
         _loaded_execs.append(fn)
         return fn
     if kind == "export":
@@ -385,11 +399,15 @@ def save_compiled(persist_name: str, sig, donate, avals, jitted,
     ``tools/mxcache.py ls`` can show per-entry peak bytes offline."""
     if not enabled():
         return False
-    payload, kind = None, None
+    payload, kind, devices = None, None, None
     try:
         import pickle
         from jax.experimental import serialize_executable as se
         payload = pickle.dumps(se.serialize(compiled))
+        # the same object se.serialize reads: the exact, ordered device
+        # list the executable was compiled for (see _deserialize)
+        dl = compiled._executable._unloaded_executable.device_list
+        devices = [dl[0].platform, [int(d.id) for d in dl]]
         kind = "exec"
     except Exception:
         # backend executable serialization unavailable: fall back to
@@ -409,11 +427,12 @@ def save_compiled(persist_name: str, sig, donate, avals, jitted,
                                error=f"serialize failed: {e!r}"[:300])
             return False
     header = {
-        "format": 1,
+        "format": _FORMAT,
         "kind": kind,
         "op": persist_name,
         "attrs": repr(sig),
         "donate": [int(d) for d in donate],
+        "devices": devices,
         "avals": sig_to_json(avals),
         "fingerprint": fingerprint(),
         "compile_seconds": round(float(compile_seconds), 4),

@@ -255,7 +255,7 @@ def _decoder_layer(lp, x, h, kv, d, base, eps, tp_axis):
 
     from ..ops.attention import dot_product_attention, rope
 
-    from ..parallel._compat import axis_size
+    from jax.lax import axis_size
     tp = axis_size(tp_axis) if tp_axis else 1
     b, s = x.shape[0], x.shape[1]
     hl, kvl = h // tp, kv // tp

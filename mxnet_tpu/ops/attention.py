@@ -138,8 +138,8 @@ def dot_product_attention(query, key, value, *rest, num_heads=1,
         # path too (the shape RULE lives only in _as_key_padding)
         mask = mask.reshape(mask.shape[0], 1, 1, mask.shape[1])
     # a sliding window prefers the kernel: block-skip makes it O(S·W)
-    # while the XLA path masks a full S×S band — measured r5 window
-    # (bench_logs/r5/attention_bench.log): flash banded 3.9x faster at
+    # while the XLA path masks a full S×S band.  Read at sha dc2bc5d5
+    # (not measured on today's code): flash banded 3.9x faster at
     # seq 512/w256 and 6.6x at 1024/w256, par at 2048/w1024.  The one
     # contrary row (2048/w256, XLA 2.8x) contradicts the kernel's own
     # linear-in-seq scaling from the 1024/w256 row by ~4x and is
@@ -174,12 +174,13 @@ def _flash_preferred(s_q, s_k, batch=1, heads=1, causal=False):
     """Measured flash-vs-XLA crossover policy (VERDICT r3 #4: a hand
     kernel must win or step aside, the cuDNN-fast-path pattern).
 
-    r5 on-chip evidence, v5e.  The standalone kernel-vs-XLA microbench
-    (bench_logs/r5/attention_bench{,2}.log) showed a mixed, noisy,
+    On-chip evidence, v5e, read at sha dc2bc5d5 — before the growth
+    PRs; not measured on today's code (ROADMAP S2/S4 re-measure it).
+    The standalone kernel-vs-XLA microbench showed a mixed, noisy,
     causality-dependent table — but the IN-MODEL A/B settled it:
-    BERT-base b64 s128, identical math, same window, honest-slope —
+    BERT-base b64 s128, identical math, same window —
     flash kernel 956.9 samples/sec vs XLA SDPA **1535.3** (MFU 0.53
-    v1; bench_logs/r5/bench_xlaattn.log).  A Pallas custom-call is a
+    v1).  A Pallas custom-call is a
     fusion BARRIER: standalone timings miss that XLA fuses the qkv
     projections, scaling, residual and dropout INTO its attention
     when it owns the whole graph.  So inside XLA's comfortable regime
@@ -219,9 +220,12 @@ def _flash_preferred(s_q, s_k, batch=1, heads=1, causal=False):
 
 
 def _flash_viable(q, k):
-    """Pallas kernel needs TPU (or interpret mode) + 128-aligned seq
-    lens; head_dim only needs 8-alignment — the kernel zero-pads it to
-    the 128 lane width, so BERT's d=64 takes the flash path."""
+    """Pallas kernel needs a TPU backend (or interpret mode, which only
+    a test asks for) + 128-aligned seq lens; head_dim only needs
+    8-alignment — the kernel zero-pads it to the 128 lane width, so
+    BERT's d=64 takes the flash path.  This chooses a path from the
+    platform and the shape; once chosen, a kernel that cannot lower on
+    the TPU raises — nothing retries on XLA or in interpret mode."""
     # through the typed registry so '0'/'false' parse as FALSE (the raw
     # environ read treated any non-empty string as disabled)
     from .. import envs

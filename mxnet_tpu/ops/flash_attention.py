@@ -32,8 +32,9 @@ _LANE = 128  # TPU lane width: head_dim is zero-padded up to this
 
 
 def _default_blocks(s_q, s_k):
-    """Measured seq-adaptive tile defaults (bench_logs/r5/
-    attention_blocks.log, v5e): 128x128 was the WORST row at every
+    """Seq-adaptive tile defaults from a v5e block sweep (taken at
+    sha dc2bc5d5; not measured on today's code): 128x128 was the WORST
+    row at every
     swept seq — bwd at 2048 runs 2.0x faster at 256x256 (10.46 →
     5.25 ms) and at 1024 1.7x faster at 128x512 (2.11 → 1.25 ms).
     Larger tiles amortize the dq/dkv revisits across the grid; VMEM
@@ -112,8 +113,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
         # ACCUMULATOR f32 either way.  f32 inputs pin Precision.HIGHEST
         # explicitly: without it XLA's DEFAULT runs f32 matmuls at bf16
         # operand precision on TPU, making kernel numerics depend on the
-        # ambient jax.default_matmul_precision context (the r3 on-chip
-        # failures, bench_logs/r3/on_tpu_pytest.log).  Contract: f32 in
+        # ambient jax.default_matmul_precision context.  Contract: f32 in
         # → f32-grade math, bf16 in → MXU-native ops + f32 accumulate.
         prec = (None if q.dtype == jnp.bfloat16
                 else jax.lax.Precision.HIGHEST)

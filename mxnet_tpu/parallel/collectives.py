@@ -91,7 +91,7 @@ def allreduce(values, axis="dp", mesh=None, op="sum"):
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from ._compat import shard_map
+    from jax import shard_map
 
     from ..ndarray.ndarray import NDArray
 
@@ -165,7 +165,7 @@ def quantized_psum(x, axis_name, *, bits=8):
     import jax.numpy as jnp
     import jax.lax as lax
 
-    from ._compat import axis_size
+    from jax.lax import axis_size
 
     if bits != 8:
         raise MXNetError(f"quantized_psum: bits must be 8, got {bits}")
@@ -207,11 +207,7 @@ def quantized_psum(x, axis_name, *, bits=8):
         # is VARYING-typed, so its per-device cotangents accumulate
         # explicitly (psum), then re-mark varying for the input's type
         ct = lax.psum(g, axis_name)
-        pcast = getattr(lax, "pcast", None)
-        if pcast is not None:
-            return (pcast(ct, (axis_name,), to="varying"),)
-        from ._compat import pvary
-        return (pvary(ct, (axis_name,)),)
+        return (lax.pcast(ct, (axis_name,), to="varying"),)
 
     _qpsum.defvjp(_fwd, _bwd)
     return _qpsum(x)
@@ -239,7 +235,7 @@ def quantized_reduce_scatter(x, axis_name, *, bits=8):
     import jax.numpy as jnp
     import jax.lax as lax
 
-    from ._compat import axis_size
+    from jax.lax import axis_size
 
     if bits != 8:
         raise MXNetError(
@@ -286,7 +282,7 @@ def twobit_psum(x, axis_name, *, threshold=0.5, residual=None):
     import jax.numpy as jnp
     import jax.lax as lax
 
-    from ._compat import axis_size
+    from jax.lax import axis_size
     n = axis_size(axis_name)
     g = x if residual is None else x + residual
     codes = jnp.where(g >= threshold, 1,
@@ -423,7 +419,7 @@ def sharded_weight_update(param, grad, states, update_fn, axis_name,
     import jax.numpy as jnp
     import jax.lax as lax
 
-    from ._compat import axis_size
+    from jax.lax import axis_size
     n = axis_size(axis_name)
     flat = grad.reshape(-1).astype(jnp.float32)
     size = flat.size

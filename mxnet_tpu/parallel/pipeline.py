@@ -38,7 +38,7 @@ def _local_schedule(params, xs, *, stage_fn, axis, n_microbatches):
     import jax.numpy as jnp
     from jax import lax
 
-    from ._compat import axis_size
+    from jax.lax import axis_size
     n = axis_size(axis)
     p = lax.axis_index(axis)
     m = n_microbatches
@@ -196,7 +196,7 @@ def pipeline_apply(stage_fn, stacked_params, x, n_microbatches,
     """
     import jax
     import jax.numpy as jnp
-    from ._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh, axis = _resolve_plan(plan, mesh, axis)
@@ -262,7 +262,7 @@ def _local_1f1b(params, xs, ys, *, stage_fn, loss_fn, axis,
     import jax.numpy as jnp
     from jax import lax
 
-    from ._compat import axis_size
+    from jax.lax import axis_size
     n = axis_size(axis)
     p = lax.axis_index(axis)
     m = n_microbatches
@@ -324,15 +324,10 @@ def _local_1f1b(params, xs, ys, *, stage_fn, loss_fn, axis,
     if grad_fix is not None:
         # tensor-parallel closure (grad_reduce_axes): a leaf replicated
         # over a reduce axis came back as per-device PARTIALS — psum
-        # restores the replication its out_spec claims; on pre-vma jax
-        # every leaf additionally carries the seed-crossing psum
-        # factor (see _compat.pre_vma), divided back out here
-        psum_axes, scale = grad_fix
+        # restores the replication its out_spec claims
         gl, td = jax.tree_util.tree_flatten(grad_acc)
         gl = [lax.psum(g, ax) if ax else g
-              for g, ax in zip(gl, psum_axes)]
-        if scale != 1:
-            gl = [g / scale for g in gl]
+              for g, ax in zip(gl, grad_fix)]
         grad_acc = jax.tree_util.tree_unflatten(td, gl)
     grads = jax.tree_util.tree_map(
         lambda g, a: (g[None] / m).astype(a.dtype), grad_acc, local)
@@ -360,9 +355,8 @@ def pipeline_value_and_grad(stage_fn, stacked_params, x, y, loss_fn,
     projections + a tp-reduced loss): with it set, a param replicated
     over such an axis gets its per-device partial grads psummed back
     to true replication (a trained norm weight would otherwise hold
-    DIVERGENT replicas — undefined on gather), and on pre-vma jax the
-    seed-crossing psum factor (``_compat.pre_vma``) is divided out so
-    grads match the unsharded reference exactly.
+    DIVERGENT replicas — undefined on gather), so grads match the
+    unsharded reference exactly.
 
     ``plan`` (a ``parallel.ShardingPlan``) supplies the mesh and the
     stage axis (``plan.pp_axis``) — consumers of one plan never spell
@@ -374,7 +368,7 @@ def pipeline_value_and_grad(stage_fn, stacked_params, x, y, loss_fn,
     per microbatch per stage (the jax.checkpoint trade).
     """
     import jax
-    from ._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh, axis = _resolve_plan(plan, mesh, axis)
@@ -401,8 +395,6 @@ def pipeline_value_and_grad(stage_fn, stacked_params, x, y, loss_fn,
         rspec = P()
         grad_fix = None
         if reduce_axes:
-            from ._compat import pre_vma
-
             def _mentioned(spec):
                 out = set()
                 for e in tuple(spec or ()):
@@ -413,14 +405,9 @@ def pipeline_value_and_grad(stage_fn, stacked_params, x, y, loss_fn,
 
             spec_leaves = jax.tree_util.tree_leaves(
                 specs, is_leaf=lambda s: isinstance(s, P))
-            psum_axes = tuple(
+            grad_fix = tuple(
                 tuple(a for a in reduce_axes if a not in _mentioned(s))
                 for s in spec_leaves)
-            scale = 1
-            if pre_vma():
-                for a in reduce_axes:
-                    scale *= int(mesh.shape[a])
-            grad_fix = (psum_axes, scale)
         body = shard_map(
             partial(_local_1f1b, stage_fn=stage_fn, loss_fn=loss_fn,
                     axis=axis, n_microbatches=n_microbatches,

@@ -34,7 +34,7 @@ def _ring_attention_local(q, k, v, axis_name, scale, causal_offset=None):
     import jax.numpy as jnp
     from jax import lax
 
-    from ._compat import axis_size
+    from jax.lax import axis_size
     n = axis_size(axis_name)
     my = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -96,7 +96,7 @@ _RING_EXEC_CACHE = {}
 
 def _ring_executable(mesh, axis, scale, causal):
     import jax
-    from ._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     key = (mesh, axis, float(scale), bool(causal))

@@ -806,7 +806,7 @@ class DataParallelTrainer:
         import jax.numpy as jnp
         import jax.lax as lax
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from ._compat import shard_map
+        from jax import shard_map
         from .collectives import quantized_psum, twobit_psum
 
         rule = self._rule
@@ -959,7 +959,7 @@ class DataParallelTrainer:
         stage 0 is tier-1 asserted for SGD-momentum and Adam."""
         import jax
         import jax.lax as lax
-        from ._compat import shard_map
+        from jax import shard_map
         from .collectives import (sharded_weight_update,
                                   quantized_psum,
                                   quantized_reduce_scatter)
@@ -1251,30 +1251,24 @@ class DataParallelTrainer:
         on).  The explicit AOT step runs even with the persistent tier
         OFF: it costs nothing over the jit path's implicit first-call
         compile and gives the memory observatory an executable to
-        harvest.  On any failure returns ``jitted`` unchanged, so the
-        tier can cost time, never a step."""
+        harvest.  A lower/compile failure is raised: this is the step
+        the run is judged by, and a quiet demotion to ``jitted`` would
+        hide that the device's compiler refused it."""
+        import jax
         from ..engine import persist as _persist
         self._note_wire(suffix, pyfn, vals)
         name = self._persist_name() + suffix
-        try:
-            import jax
-            avals = _persist.aval_sig(vals)
-            if not self._trace_seen[0] and \
-                    _persist.contains(name, (), donate, avals):
-                # a persist hit skips the Python trace, and with it the
-                # mutated_idx discovery (BatchNorm-aux write-back
-                # routing) — one abstract trace recovers it
-                jax.eval_shape(pyfn, *vals)
-            fn, _src = _persist.tiered_compile(
-                name, jitted, vals, donate=donate,
-                op_label=f"spmd_full_step{suffix}")
-            return fn
-        except Exception as e:
-            from .. import telemetry
-            telemetry.record_event(
-                "persist_error", op=f"spmd_full_step{suffix}",
-                error=f"aot demoted: {e!r}"[:300])
-            return jitted
+        avals = _persist.aval_sig(vals)
+        if not self._trace_seen[0] and \
+                _persist.contains(name, (), donate, avals):
+            # a persist hit skips the Python trace, and with it the
+            # mutated_idx discovery (BatchNorm-aux write-back
+            # routing) — one abstract trace recovers it
+            jax.eval_shape(pyfn, *vals)
+        fn, _src = _persist.tiered_compile(
+            name, jitted, vals, donate=donate,
+            op_label=f"spmd_full_step{suffix}")
+        return fn
 
     def _record_variant(self, suffix, vals, k_steps, repeated):
         """Manifest row for :meth:`save_signature`: the data-dependent
@@ -1329,7 +1323,9 @@ class DataParallelTrainer:
             return fn(*vals)
         try:
             return fn(*vals)
-        except TypeError:
+        except TypeError as e:
+            from .. import engine
+            engine._note_aot_demotion("spmd_full_step", e)
             by_sig[s] = jit_fn        # cached demotion, not per-step
             return jit_fn(*vals)
 
@@ -2554,13 +2550,14 @@ class DataParallelTrainer:
                     _faults.on_dispatch("spmd_step_multi", probe)
                 try:
                     return call(*vals)
-                except TypeError:
+                except TypeError as e:
                     # aval drift the AOT executable rejects: demote
                     # THIS signature to the pjit path (cached — not a
                     # raise per step), which absorbs it by retracing
                     # exactly as before the persistent tier existed
                     if call is fn:
                         raise
+                    engine._note_aot_demotion("spmd_step_multi", e)
                     if cached is not None:
                         cached[0][sig] = fn
                     return fn(*vals)
@@ -2746,7 +2743,7 @@ class DataParallelTrainer:
             # shard_map the whole scanned program: state leaves ride
             # the carry in their (1, chunk) local form, the gradient
             # reduce-scatter + weight all-gather run per inner step
-            from ._compat import shard_map
+            from jax import shard_map
             repl, state_spec, _ = self._zero_specs()
             batch_k = P(self.dp_axis) if repeated \
                 else P(None, self.dp_axis)

@@ -36,6 +36,9 @@ __all__ = ["PallasModule", "CudaModule"]
 
 
 def _interpret_default() -> bool:
+    """Mosaic exists only on a TPU backend; elsewhere a kernel can only
+    be interpreted.  On a TPU the answer is always "compiled": a kernel
+    that cannot lower there raises."""
     from .base import on_accelerator
     return not on_accelerator()
 

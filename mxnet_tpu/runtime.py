@@ -32,14 +32,7 @@ def _detect():
         has_jax = True
     except ImportError:
         has_jax = False
-    tpu = False
-    if has_jax:
-        try:
-            devs = jax.devices()
-            tpu = bool(devs) and devs[0].platform != "cpu"
-        except Exception:
-            tpu = False
-    add("TPU", tpu)
+    add("TPU", has_jax and jax.default_backend() == "tpu")
     add("PJRT", has_jax)
     add("PALLAS", has_jax)
     add("DIST", has_jax)

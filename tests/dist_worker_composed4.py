@@ -44,13 +44,10 @@ if __name__ == "__main__":
     os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
 
-if __name__ == "__main__":
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 import mxnet_tpu as mx  # noqa: F401  joins the MXTPU_DIST_* rendezvous
-from mxnet_tpu.parallel._compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 from mxnet_tpu.parallel.ring_attention import _ring_attention_local
 
 F = 8          # model width
@@ -198,7 +195,7 @@ def _reference(w0, x, y, steps):
 
 
 def main():
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from jax.experimental import multihost_utils
 

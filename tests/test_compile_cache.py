@@ -191,6 +191,69 @@ def test_donation_honored_after_reload(cache_dir):
     np.testing.assert_array_equal(out, np.full((3,), 6.0, "f4"))
 
 
+class _RefusingJit:
+    """A jitted fn whose AOT ``lower`` fails while a plain call would
+    still work — what a quiet demotion to jit used to hide."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+
+    def lower(self, *args):
+        raise RuntimeError("compiler refused this program")
+
+    def __call__(self, *args):
+        return self._jitted(*args)
+
+
+@pytest.mark.parametrize("donate", [(0,), ()], ids=["donating", "per_op"])
+def test_aot_lower_failure_raises_not_demotes(cache_dir, monkeypatch,
+                                              donate):
+    """Any tiered entry — a donating step or a per-op entry tiered
+    because the persistent tier is on — raises what its AOT lower
+    raises, on every call, and counts no demotion."""
+    real_jit = engine._TieredFn._jit
+    monkeypatch.setattr(engine._TieredFn, "_jit",
+                        lambda self: _RefusingJit(real_jit(self)))
+
+    def f(a):
+        return a + 5.0
+
+    x = nd.array(np.ones((3,), "f4"))
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="compiler refused"):
+            engine.invoke_compiled("cc_refused", f, {}, x._data,
+                                   donate=donate)
+    assert not x._data.is_deleted(), "nothing ran, nothing was donated"
+    assert engine.cache_info()["aot_demotions"] == 0
+    assert telemetry.events("persist_error") == []
+
+
+def test_aval_drift_demotion_is_counted(cache_dir):
+    """The one demotion left: an AOT executable that rejects a drift the
+    (shape, dtype) signature cannot see (here tuple -> list) with
+    ``TypeError`` hands that signature to plain jit, once, counted."""
+    def f(a, pair):
+        return a * pair[0] + pair[1]
+
+    pair = (np.float32(2.0), np.float32(1.0))
+    x = nd.array(np.ones((3,), "f4"))
+    engine.invoke_compiled("cc_drift", f, {}, x._data, pair, donate=(0,))
+    assert engine.cache_info()["aot_demotions"] == 0
+
+    for _ in range(2):
+        x = nd.array(np.ones((3,), "f4"))
+        out = np.asarray(engine.invoke_compiled(
+            "cc_drift", f, {}, x._data, list(pair), donate=(0,)))
+        np.testing.assert_array_equal(out, np.full((3,), 3.0, "f4"))
+        assert x._data.is_deleted(), "the jit path keeps the donation"
+    assert engine.cache_info()["aot_demotions"] == 1
+    events = telemetry.events("persist_error")
+    assert len(events) == 1 and events[0]["op"] == "cc_drift"
+    assert "aot demoted" in events[0]["error"]
+    engine.reset_counters()
+    assert engine.cache_info()["aot_demotions"] == 0
+
+
 def test_export_fallback_when_executable_serialization_unavailable(
         cache_dir, monkeypatch):
     """Backends without executable serialization fall back to the

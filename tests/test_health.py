@@ -160,8 +160,11 @@ def test_health_on_off_bit_identical(monkeypatch):
 def test_compiled_vs_eager_parity_with_health_spliced(monkeypatch):
     """Fused-vs-eager parity with health outputs spliced in at K=1:
     the compiled step (every dispatch carrying the stats vector)
-    matches the eager record/backward/step path bit-for-bit on the
-    MLP."""
+    matches the eager record/backward/step path on the MLP — to
+    ``_FMA_ULP`` (tests/test_compiled_step.py: the whole-step program
+    contracts the last bias's gradient reduction into FMAs, one
+    rounding where the eager per-op chain takes two; both correct)."""
+    from test_compiled_step import _FMA_ULP
     from mxnet_tpu import autograd
     X, Y = _data()
     l2 = gluon.loss.L2Loss()
@@ -183,7 +186,7 @@ def test_compiled_vs_eager_parity_with_health_spliced(monkeypatch):
 
     pc, pe = _params_np(net_c), _params_np(net_e)
     for i in pc:
-        np.testing.assert_array_equal(pc[i], pe[i])
+        np.testing.assert_array_max_ulp(pc[i], pe[i], maxulp=_FMA_ULP)
 
 
 def test_sampling_cadence(monkeypatch):
@@ -694,17 +697,17 @@ def test_spmd_health_one_dispatch_and_samples():
     X = nd.array(rng.rand(16, 6).astype("f4"))
     Y = nd.array(rng.rand(16, 3).astype("f4"))
     dpt.step(X, Y)
-    # the fused SPMD step never dispatches through the engine's per-op
-    # path; health must not add ANY engine dispatches either
+    # the fused SPMD step is ONE counted dispatch (the retry seam);
+    # health must not add any
     d0 = engine.cache_info()["dispatches"]
     dpt.step(X, Y)
-    assert engine.cache_info()["dispatches"] - d0 == 0
+    assert engine.cache_info()["dispatches"] - d0 == 1
     Xk = nd.array(rng.rand(2, 16, 6).astype("f4"))
     Yk = nd.array(rng.rand(2, 16, 3).astype("f4"))
     dpt.step_multi(Xk, Yk)
     d0 = engine.cache_info()["dispatches"]
     dpt.step_multi(Xk, Yk)
-    assert engine.cache_info()["dispatches"] - d0 == 0
+    assert engine.cache_info()["dispatches"] - d0 == 1
     sent = telemetry.health.sentinels()[f"spmd:{net.name}"]
     assert sent.samples == 2 + 2 * 2
     row = sent.snapshot()["history"][-1]

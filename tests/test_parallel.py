@@ -149,7 +149,7 @@ def test_quantized_psum_accuracy_and_grad():
     exact psum; straight-through gradient equals the psum vjp."""
     import jax
     import jax.numpy as jnp
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from mxnet_tpu import parallel
     sm = shard_map
@@ -337,16 +337,23 @@ def test_fuse_step_failure_poisons_donated_state():
         {"learning_rate": 1e-3}, mesh=mesh, fuse_step=True)
     dpt.step(nd.array(X), nd.array(Y))   # healthy step builds the jit
 
-    # a PRE-dispatch failure leaves the donated buffers alive (the CPU
-    # backend never consumes them) and must NOT brick the trainer
-    def pre_dispatch_boom(*a, **k):
-        raise TypeError("bad argument binding")
+    # the seam is the resolved executable ({aval sig: executable}); a
+    # fake installed as the jitted step itself would have to survive an
+    # AOT lower(), and that failure is raised, not demoted
+    execs = dpt._full_exec[0]
+    real = dict(execs)
 
-    real_step = dpt._full_step
-    dpt._full_step = pre_dispatch_boom
-    with pytest.raises(TypeError):
+    # a PRE-dispatch failure leaves the donated buffers alive (the CPU
+    # backend never consumes them) and must NOT brick the trainer.
+    # (Not TypeError: that is the aval-drift signal the dispatch
+    # absorbs by demoting to the jit path.)
+    def pre_dispatch_boom(*a, **k):
+        raise ValueError("bad argument binding")
+
+    execs.update(dict.fromkeys(real, pre_dispatch_boom))
+    with pytest.raises(ValueError):
         dpt.step(nd.array(X), nd.array(Y))
-    dpt._full_step = real_step
+    execs.update(real)
     dpt.step(nd.array(X), nd.array(Y))   # still healthy
 
     # a failure after the executable CONSUMED the donated state (we
@@ -358,7 +365,7 @@ def test_fuse_step_failure_poisons_donated_state():
                 v.delete()
         raise RuntimeError("transient device error")
 
-    dpt._full_step = post_dispatch_boom
+    execs.update(dict.fromkeys(real, post_dispatch_boom))
     with pytest.raises(MXNetError, match="donated"):
         dpt.step(nd.array(X), nd.array(Y))
     # the trainer is now invalid and says so — even though the next
@@ -430,7 +437,7 @@ class TestGradientCompressionInTrainer:
         checked in the lowered program, not inferred from numerics."""
         import jax
         import jax.numpy as jnp
-        from mxnet_tpu.parallel._compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from mxnet_tpu.parallel import collectives
 
@@ -594,7 +601,7 @@ class TestVocabParallelCE:
     def test_matches_single_device_and_grads(self):
         import jax
         import jax.numpy as jnp
-        from mxnet_tpu.parallel._compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from mxnet_tpu.parallel import collectives
 
@@ -636,7 +643,7 @@ class TestVocabParallelCE:
         full-softmax reference — values AND grads (dH, dW)."""
         import jax
         import jax.numpy as jnp
-        from mxnet_tpu.parallel._compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from mxnet_tpu.ops.nn import chunked_softmax_ce
         from mxnet_tpu.parallel import collectives
@@ -697,7 +704,7 @@ class TestVocabParallelCE:
         sharded alongside the vocab rows}."""
         import jax
         import jax.numpy as jnp
-        from mxnet_tpu.parallel._compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from mxnet_tpu.ops.nn import chunked_softmax_ce_bias
 
@@ -778,7 +785,7 @@ class TestVocabParallelCE:
         (N, V/tp) tensor in the lowered HLO — only (N, chunk) slabs."""
         import jax
         import jax.numpy as jnp
-        from mxnet_tpu.parallel._compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from mxnet_tpu.ops.nn import chunked_softmax_ce
 
@@ -805,7 +812,7 @@ class TestVocabParallelCE:
         the whole point of the vocab split."""
         import jax
         import jax.numpy as jnp
-        from mxnet_tpu.parallel._compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from mxnet_tpu.parallel import collectives
 
@@ -833,7 +840,7 @@ class TestShardedWeightUpdate:
     def _run(self, n_params_shape, dp=4, steps=3):
         import jax
         import jax.numpy as jnp
-        from mxnet_tpu.parallel._compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from mxnet_tpu.parallel import collectives as C
 
@@ -908,7 +915,7 @@ class TestShardedWeightUpdate:
         import jax
         import jax.numpy as jnp
         import re
-        from mxnet_tpu.parallel._compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from mxnet_tpu.parallel import collectives as C
 

@@ -118,7 +118,7 @@ def test_reduce_scatter_psum_parity():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from mxnet_tpu.parallel import collectives as C
 
     mesh = parallel.make_mesh({"dp": 8})
@@ -147,7 +147,7 @@ def test_quantized_reduce_scatter_accuracy_and_wire():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from mxnet_tpu.parallel import collectives as C
 
     mesh = parallel.make_mesh({"dp": 8})
@@ -178,7 +178,7 @@ def test_sharded_weight_update_grad_reduce_modes():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from mxnet_tpu.parallel import collectives as C
     import jax.lax as lax
 
@@ -307,9 +307,10 @@ def test_zero2_wire_is_reduce_scatter_plus_all_gather():
 
 
 def test_zero_steady_state_zero_retrace():
-    """After warm-up, ZeRO steps add no engine dispatches, no cache
-    misses, no fresh compiles, and no retrace events — the
-    1-dispatch/0-retrace contract (acceptance criterion)."""
+    """After warm-up, each ZeRO step()/step_multi() is exactly one
+    counted dispatch with no cache misses, no fresh compiles, and no
+    retrace events — the 1-dispatch/0-retrace contract (acceptance
+    criterion)."""
     net, d1 = _make(1)
     for _ in range(2):
         d1.step(nd.array(_X), nd.array(_Y))
@@ -320,7 +321,7 @@ def test_zero_steady_state_zero_retrace():
         d1.step(nd.array(_X), nd.array(_Y))
     d1.step_multi(nd.array(_X), nd.array(_Y), repeat=2)
     info1 = engine.cache_info()
-    assert info1["dispatches"] == info0["dispatches"]
+    assert info1["dispatches"] == info0["dispatches"] + 4
     assert info1["misses"] == info0["misses"]
     assert info1["fresh_compiles"] == info0["fresh_compiles"]
     assert telemetry.events("retrace") == []

@@ -26,7 +26,11 @@ directly) reads ``MXTPU_DIST_*`` and calls
 
 Usage::
 
-    python tools/launch.py -n 2 [--launcher local] python train.py ...
+    JAX_PLATFORMS=cpu python tools/launch.py -n 2 python train.py ...
+
+More than one worker needs ``JAX_PLATFORMS=cpu`` in the environment: the
+workers of a ``local`` launch all see the same host, and its TPU chips
+cannot be shared between processes (see ``launch_local``).
 
 ``--launcher ssh/mpi/yarn`` are declared capability gaps: multi-host TPU
 pods are normally launched by the pod runtime (one process per host,
@@ -61,7 +65,21 @@ def launch_local(num_workers, command, extra_env=None):
     """Spawn ``num_workers`` local processes with rendezvous env set.
 
     Returns the list of exit codes (one per worker).
+
+    Every worker sees every device of the host, and a TPU chip belongs
+    to one process at a time — so more than one worker is only started
+    when the environment forces the CPU backend.  The chips of one host
+    are driven from ONE process over a ``Mesh`` (README, "Several
+    chips").
     """
+    if num_workers > 1 and {**os.environ, **(extra_env or {})}.get(
+            "JAX_PLATFORMS") != "cpu":
+        raise SystemExit(
+            f"launch.py: refusing to start {num_workers} workers: "
+            "each would claim every TPU chip of this host, and a chip "
+            "belongs to one process.  Drive the host's chips from one "
+            "process over a Mesh (parallel.make_mesh), or set "
+            "JAX_PLATFORMS=cpu to run the workers on the CPU backend.")
     coord = f"127.0.0.1:{_free_port()}"
     procs = []
     threads = []
