@@ -29,6 +29,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 from . import persist
 from ..elastic import faults as _faults
+from ..profiler import recording as _recording, span as _span
 
 __all__ = ["invoke_compiled", "waitall", "is_naive", "set_bulk_size",
            "cache_info", "cache_size", "live_bytes", "live_arrays",
@@ -385,17 +386,21 @@ def get_compiled(name: str, fcompute: Callable, attrs: dict,
     ``MXTPU_COMPILE_CACHE_DIR`` set, misses return a tiered wrapper
     that consults the on-disk executable cache before compiling.
     """
+    return _lookup(name, fcompute, attrs, donate, persist_name)[1]
+
+
+def _lookup(name, fcompute, attrs, donate, persist_name):
+    """``(cache key, executable)``: invoke_compiled shares the key with
+    the telemetry plane's aval tracking instead of recomputing the attr
+    sort/freeze per dispatch."""
     key, sig = _cache_key(name, attrs, donate)
-    return _get_compiled_keyed(key, sig, name, fcompute, attrs, donate,
-                               persist_name=persist_name)
+    return key, _get_compiled_keyed(key, sig, name, fcompute, attrs,
+                                    donate, persist_name=persist_name)
 
 
 def _get_compiled_keyed(key, sig, name, fcompute, attrs, donate,
                         persist_name=None, force_tiered=False):
-    """:func:`get_compiled` body with the cache key precomputed —
-    invoke_compiled builds the key once and shares it with the
-    telemetry plane's aval tracking instead of recomputing the
-    attr sort/freeze per dispatch."""
+    """:func:`get_compiled` body with the cache key precomputed."""
     global _hits, _misses
     fn = _jit_cache.get(key)
     if fn is None:
@@ -480,7 +485,8 @@ def live_arrays() -> list:
 
 # profiler interception point — the reference wires its profiler inside
 # ThreadedEngine::ExecuteOprBlock (SURVEY.md §5 Tracing); ours wraps the
-# dispatch here.  None when profiling is off (zero overhead).
+# dispatch here and records the per-op chrome event (``cat: operator``,
+# named by the op).  None unless ``profiler.set_state("run")``.
 _profiler_hook = None
 
 
@@ -593,6 +599,17 @@ def retrying_call(call, probe_arrays, op: str):
             _time.sleep(sleep_ms / 1000.0)
 
 
+def _account_dispatch(t, name, key, arrays, donate):
+    """The telemetry plane's share of one dispatch: counters, the
+    ``dispatch`` event, and the input signature's retrace attribution."""
+    c_disp, c_don = _counters(t)[:2]
+    c_disp.inc()
+    if donate:
+        c_don.inc()
+    t.record_event("dispatch", op=name)
+    _note_avals(name, key, arrays)
+
+
 def invoke_compiled(name: str, fcompute: Callable, attrs: dict, *arrays,
                     donate: Tuple[int, ...] = (),
                     persist_name: Optional[str] = None):
@@ -606,16 +623,21 @@ def invoke_compiled(name: str, fcompute: Callable, attrs: dict, *arrays,
     """
     t = _telem if _telem is not None else _telemetry()
     telem_on = t._switch.enabled
-    key, sig = _cache_key(name, attrs, donate)
-    fn = _get_compiled_keyed(key, sig, name, fcompute, attrs, donate,
-                             persist_name=persist_name)
-    if telem_on:
-        c_disp, c_don = _counters(t)[:2]
-        c_disp.inc()
-        if donate:
-            c_don.inc()
-        t.record_event("dispatch", op=name)
-        _note_avals(name, key, arrays)
+    # this is the eager per-op path too, where a span's 0.5 us would be
+    # over 1% of an op: the engine's spans are entered only while a sink
+    # records
+    recording = _recording()
+    if recording:
+        with _span("mxtpu.engine.lookup", "engine", op=name):
+            key, fn = _lookup(name, fcompute, attrs, donate, persist_name)
+        if telem_on:
+            with _span("mxtpu.engine.telemetry", "engine", op=name):
+                _account_dispatch(t, name, key, arrays, donate)
+    else:
+        key, fn = _lookup(name, fcompute, attrs, donate, persist_name)
+        if telem_on:
+            _account_dispatch(t, name, key, arrays, donate)
+
     def _run():
         if _faults._active:
             # deterministic fault injection (docs/elasticity.md):
@@ -625,10 +647,13 @@ def invoke_compiled(name: str, fcompute: Callable, attrs: dict, *arrays,
             # first, so the caller's poison protocol engages exactly
             # as on real hardware
             _faults.on_dispatch(name, arrays, donate)
-        hook = _profiler_hook
-        if hook is not None:
-            return hook(name, fn, arrays)
-        return fn(*arrays)
+        if not recording:
+            return fn(*arrays)
+        with _span("mxtpu.engine.execute", "engine", op=name):
+            hook = _profiler_hook
+            if hook is not None:
+                return hook(name, fn, arrays)
+            return fn(*arrays)
 
     san = _san
     if san is not None and donate:

@@ -80,9 +80,6 @@ _reg("MXTPU_PRNG_IMPL", str, "auto",
      "counter-based per-device PRNG; threefry on CPU so seeded test "
      "values stay stable), or an explicit threefry2x32 / rbg / "
      "unsafe_rbg. Latched at the first key creation.")
-_reg("MXTPU_PROFILE_SYNC", bool, False,
-     "Profiler blocks on each op for accurate per-op device time "
-     "(slower; like the reference's synchronous profiling mode).")
 _reg("MXTPU_SEED", int, 0,
      "Global RNG seed override applied at import.", "MXNET_SEED")
 _reg("MXTPU_NATIVE_IO", bool, True,

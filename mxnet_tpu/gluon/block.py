@@ -444,22 +444,6 @@ def _tree_cache_key(tree):
     return tuple(go(t) for t in tree)
 
 
-def jax_tree_leaves_of_ndarrays(out):
-    """Raw jax buffers of every NDArray in a (possibly nested) result —
-    what block_until_ready understands."""
-    bufs = []
-
-    def go(x):
-        if isinstance(x, NDArray):
-            bufs.append(x._data)
-        elif isinstance(x, (list, tuple)):
-            for y in x:
-                go(y)
-
-    go(out)
-    return bufs
-
-
 def _unflatten_args(tree, leaves):
     def go(t):
         tag = t[0]
@@ -580,11 +564,8 @@ class CachedOp:
 
     def __call__(self, *args):
         from .. import profiler
-        with profiler._span(f"CachedOp[{self.block.name}]",
-                            "cachedop") as sp:
-            out = self._execute(args)
-            sp.sync(jax_tree_leaves_of_ndarrays(out))
-            return out
+        with profiler.span(f"CachedOp[{self.block.name}]", "cachedop"):
+            return self._execute(args)
 
     def _execute(self, args):
         from .. import autograd

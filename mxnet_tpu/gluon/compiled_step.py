@@ -189,13 +189,12 @@ class CompiledStep:
         if batch_size is None:
             batch_size = label.shape[0] if label.shape else \
                 args[0].shape[0]
-        with profiler._span(f"CompiledStep[{self.net.name}]",
-                            "compiled_step") as sp, \
+        with profiler.span(f"CompiledStep[{self.net.name}]",
+                           "compiled_step"), \
                 telemetry.step_owner(self, "compiled_step"):
             t0 = time.perf_counter()
             d0 = engine.dispatch_count()
             out = self._step_or_fallback(args, label, batch_size)
-            sp.sync(out._data)
             telemetry.record_step(
                 "compiled_step", time.perf_counter() - t0,
                 dispatches=engine.dispatch_count() - d0,
@@ -238,15 +237,14 @@ class CompiledStep:
                 dshape[0] if dshape else 1)
         from .. import engine, telemetry
         import time
-        with profiler._span(f"CompiledStep[{self.net.name}].multi",
-                            "compiled_step_multi") as sp, \
+        with profiler.span(f"CompiledStep[{self.net.name}].multi",
+                           "compiled_step_multi"), \
                 telemetry.step_owner(self, "compiled_step_multi"):
             t0 = time.perf_counter()
             d0 = engine.dispatch_count()
             out = self._step_or_fallback(args, label, batch_size,
                                          k_steps=k_steps,
                                          repeat=repeat is not None)
-            sp.sync(out._data)
             telemetry.record_step(
                 "compiled_step", time.perf_counter() - t0,
                 dispatches=engine.dispatch_count() - d0,

@@ -30,6 +30,7 @@ import numpy as np
 from ..base import MXNetError
 from ..ndarray.ndarray import NDArray
 from ..ops.registry import get_op
+from ..profiler import span as _span
 from .mesh import current_mesh
 
 __all__ = ["DataParallelTrainer"]
@@ -297,6 +298,9 @@ class DataParallelTrainer:
         self._health_built_sig = None
         self._health_count = 0
         self.health_manager = None
+        # calls of step()/step_multi() so far: the `step` id of this
+        # trainer's profiler spans (docs/observability.md, "Spans")
+        self._span_step = 0
         self._rule = _FUSED_RULES.get(type(self.optimizer).__name__)
         if fuse_step and self._rule is None:
             import warnings
@@ -1292,6 +1296,20 @@ class DataParallelTrainer:
             row["extra"] = _persist.sig_to_json(
                 _persist.aval_sig(list(vals[6:])))
         self._var_avals[(k_steps or 0, bool(repeated))] = row
+        # what one call of this variant hands to jax: every leaf is
+        # checked and passed each step, and a leaf that is host numpy is
+        # also copied to the device each step
+        from .. import telemetry
+        leaves = tree_util.tree_leaves(vals)
+        telemetry.gauge(
+            "mxtpu_trainer_step_args",
+            "array leaves one fused-step call hands to its executable"
+            ).set(len(leaves))
+        telemetry.gauge(
+            "mxtpu_trainer_step_host_args",
+            "of those, host numpy leaves (transferred on every step)"
+            ).set(sum(isinstance(x, (np.ndarray, np.generic))
+                      for x in leaves))
 
     def _dispatch_full(self, vals):
         """One fused-step dispatch through the tiered executable.
@@ -1313,21 +1331,26 @@ class DataParallelTrainer:
             cached = ({}, jit_fn)
             self._full_exec = cached
         by_sig = cached[0]
-        s = _persist.aval_sig(vals)
+        n = self._span_step
+        with _span("mxtpu.trainer.aval_sig", "trainer", step=n):
+            s = _persist.aval_sig(vals)
         fn = by_sig.get(s)
         if fn is None:
             fn = self._tiered_exec("", jit_fn, self._full_fn, vals,
                                    self._full_donate)
             by_sig[s] = fn
-        if fn is jit_fn:
-            return fn(*vals)
-        try:
-            return fn(*vals)
-        except TypeError as e:
-            from .. import engine
-            engine._note_aot_demotion("spmd_full_step", e)
-            by_sig[s] = jit_fn        # cached demotion, not per-step
-            return jit_fn(*vals)
+        # jax's argument handling and the enqueue: the program itself
+        # runs on the device after this returns
+        with _span("mxtpu.trainer.execute", "trainer", step=n):
+            if fn is jit_fn:
+                return fn(*vals)
+            try:
+                return fn(*vals)
+            except TypeError as e:
+                from .. import engine
+                engine._note_aot_demotion("spmd_full_step", e)
+                by_sig[s] = jit_fn    # cached demotion, not per-step
+                return jit_fn(*vals)
 
     def save_signature(self, path: str) -> str:
         """Write the warm-start manifest for this trainer's compiled
@@ -2321,13 +2344,13 @@ class DataParallelTrainer:
         forward + kvstore push/pull with one SPMD program).
         """
         import time
-        from .. import profiler, telemetry
-        with profiler._span("DataParallelTrainer.step",
-                            "spmd_step") as sp, \
+        from .. import telemetry
+        self._span_step = n = self._span_step + 1
+        with _span("mxtpu.trainer.step", "spmd_step", step_num=n,
+                   step=n), \
                 telemetry.step_owner(self, "spmd_step"):
             t0 = time.perf_counter()
             loss = self._step_impl(data, label)
-            sp.sync(loss._data)
             telemetry.record_step(
                 "spmd_step", time.perf_counter() - t0,
                 examples=self._global_batch(label), path="spmd")
@@ -2354,13 +2377,13 @@ class DataParallelTrainer:
         Requires ``fuse_step=True`` and no gradient compression.
         """
         import time
-        from .. import profiler, telemetry
-        with profiler._span("DataParallelTrainer.step_multi",
-                            "spmd_step_multi") as sp, \
+        from .. import telemetry
+        self._span_step = n = self._span_step + 1
+        with _span("mxtpu.trainer.step_multi", "spmd_step_multi",
+                   step_num=n, step=n), \
                 telemetry.step_owner(self, "spmd_step_multi"):
             t0 = time.perf_counter()
             loss = self._step_multi_impl(data, label, repeat=repeat)
-            sp.sync(loss._data)
             k = int(repeat) if repeat is not None else \
                 (label.shape[0] if label.shape else 1)
             per_step = self._global_batch(label) if repeat is not None \
@@ -2421,29 +2444,31 @@ class DataParallelTrainer:
                              "MXTPU_ZERO_STAGE, where the int8 reduce "
                              "rides the ZeRO gradient leg)")
 
-        # single-step views drive setup/tracing (shapes minus K)
-        args0 = args if repeated else [a[0] for a in args]
-        if self._params is None:
-            self._setup(args0)
-        self._refresh_health()
-        if self._post_resize_probe is not None:
-            self._note_resize_probe_base()
-        hs = self._health_spec
-        health_out = None
-        from ..elastic import faults as _faults2
-        if _faults2._active and _faults2.nonfinite_due(
-                "spmd_step_multi"):
-            # poisons the leading element: inner step 0 of a sliced
-            # bulk; with repeat= the single shared batch poisons
-            # EVERY inner step
-            from .. import telemetry as _tm
-            args = _tm.health.poison_inputs(args)
-        if _faults2._active:
-            payload = _faults2.corrupt_due("corrupt_param")
-            if payload is not None:
-                from ..elastic import integrity as _integrity
-                _integrity.corrupt_param_host(self, payload)
-        prev = autograd.set_training(True)
+        n = self._span_step
+        with _span("mxtpu.trainer.prologue", "trainer", step=n):
+            # single-step views drive setup/tracing (shapes minus K)
+            args0 = args if repeated else [a[0] for a in args]
+            if self._params is None:
+                self._setup(args0)
+            self._refresh_health()
+            if self._post_resize_probe is not None:
+                self._note_resize_probe_base()
+            hs = self._health_spec
+            health_out = None
+            from ..elastic import faults as _faults2
+            if _faults2._active and _faults2.nonfinite_due(
+                    "spmd_step_multi"):
+                # poisons the leading element: inner step 0 of a sliced
+                # bulk; with repeat= the single shared batch poisons
+                # EVERY inner step
+                from .. import telemetry as _tm
+                args = _tm.health.poison_inputs(args)
+            if _faults2._active:
+                payload = _faults2.corrupt_due("corrupt_param")
+                if payload is not None:
+                    from ..elastic import integrity as _integrity
+                    _integrity.corrupt_param_host(self, payload)
+            prev = autograd.set_training(True)
         try:
             if self._fwd_bwd is None:
                 self._build_fwd_bwd(args0,
@@ -2467,63 +2492,67 @@ class DataParallelTrainer:
                     "Original error: "
                     f"{self._donation_poisoned}")
 
-            opt = self.optimizer
-            tr_idx = self._tr_idx
-            # per-inner-step optimizer scalars from PROSPECTIVE update
-            # counts (t+1..t+K) — the real counters only advance after
-            # a successful dispatch, so a compile/shape failure cannot
-            # silently skew Adam bias correction for later steps
-            scal_rows = []
-            for k in range(k_steps):
-                row = []
-                for i in tr_idx:
-                    t = opt._index_update_count.get(
-                        i, opt.begin_num_update) + k + 1
-                    row.extend(np.asarray(sv, dtype=np.float32)
-                               for sv in self._rule.scalars(opt, i, t))
-                scal_rows.append(np.stack(row) if row
-                                 else np.zeros((0,), np.float32))
-            scalar_k = jnp.asarray(np.stack(scal_rows))   # (K, S)
+            with _span("mxtpu.trainer.gather_args", "trainer", step=n):
+                opt = self.optimizer
+                tr_idx = self._tr_idx
+                # per-inner-step optimizer scalars from PROSPECTIVE update
+                # counts (t+1..t+K) — the real counters only advance after
+                # a successful dispatch, so a compile/shape failure cannot
+                # silently skew Adam bias correction for later steps
+                scal_rows = []
+                for k in range(k_steps):
+                    row = []
+                    for i in tr_idx:
+                        t = opt._index_update_count.get(
+                            i, opt.begin_num_update) + k + 1
+                        row.extend(np.asarray(sv, dtype=np.float32)
+                                   for sv in self._rule.scalars(opt, i, t))
+                    scal_rows.append(np.stack(row) if row
+                                     else np.zeros((0,), np.float32))
+                scalar_k = jnp.asarray(np.stack(scal_rows))   # (K, S)
 
-            # RNG: snapshot the stream so a pre-dispatch failure can
-            # rewind instead of skipping K keys
-            ctx0 = args[0].context
-            key_snapshot = dict(_rnd._keys)
-            keys = [_rnd._next_key_nd(ctx0)._data
-                    for _ in range(k_steps)]
-            keys_k = jnp.stack(keys)
+            with _span("mxtpu.trainer.rng_key", "trainer", step=n):
+                # RNG: snapshot the stream so a pre-dispatch failure can
+                # rewind instead of skipping K keys
+                ctx0 = args[0].context
+                key_snapshot = dict(_rnd._keys)
+                keys = [_rnd._next_key_nd(ctx0)._data
+                        for _ in range(k_steps)]
+                keys_k = jnp.stack(keys)
 
-            batch_k = NamedSharding(
-                self.mesh,
-                P(self.dp_axis) if repeated else P(None, self.dp_axis))
-            used = set()
-            x_vals = tuple(self._put_cached(a, batch_k, used)
-                           for a in args)
-            y_val = self._put_cached(label, batch_k, used)
-            self._prune_placed(used)
-            param_vals = tuple(p.data()._data for p in self._params)
+            with _span("mxtpu.trainer.place_batch", "trainer", step=n):
+                batch_k = NamedSharding(
+                    self.mesh,
+                    P(self.dp_axis) if repeated else P(None, self.dp_axis))
+                used = set()
+                x_vals = tuple(self._put_cached(a, batch_k, used)
+                               for a in args)
+                y_val = self._put_cached(label, batch_k, used)
+                self._prune_placed(used)
+            with _span("mxtpu.trainer.gather_args", "trainer", step=n):
+                param_vals = tuple(p.data()._data for p in self._params)
 
-            kk = (k_steps, repeated)
-            fn = self._multi_step_cache.get(kk)
-            if fn is None:
-                fn = self._build_full_step_multi(k_steps, repeated)
-            vals = (param_vals, self._state_vals(), scalar_k, x_vals,
-                    y_val, keys_k)
-            if hs is not None:
-                # per-inner-step sampling flags (K,): gate the
-                # in-graph health reductions inside the scan
-                from .. import telemetry as _tm
-                vals = vals + (jnp.asarray(_tm.health.due_flags(
-                    self._health_count, k_steps)),)
-                if hs.integrity is not None and hs.integrity.inject:
-                    # per-inner-step corruption-ctl rows (K, 4): a
-                    # baked drill fires on the exact inner step its
-                    # spec selects
-                    from ..elastic import integrity as _integrity
-                    vals = vals + (jnp.asarray(np.stack(
-                        [_integrity.ctl_vector(hs.integrity,
-                                               len(tr_idx))
-                         for _ in range(k_steps)])),)
+                kk = (k_steps, repeated)
+                fn = self._multi_step_cache.get(kk)
+                if fn is None:
+                    fn = self._build_full_step_multi(k_steps, repeated)
+                vals = (param_vals, self._state_vals(), scalar_k, x_vals,
+                        y_val, keys_k)
+                if hs is not None:
+                    # per-inner-step sampling flags (K,): gate the
+                    # in-graph health reductions inside the scan
+                    from .. import telemetry as _tm
+                    vals = vals + (jnp.asarray(_tm.health.due_flags(
+                        self._health_count, k_steps)),)
+                    if hs.integrity is not None and hs.integrity.inject:
+                        # per-inner-step corruption-ctl rows (K, 4): a
+                        # baked drill fires on the exact inner step its
+                        # spec selects
+                        from ..elastic import integrity as _integrity
+                        vals = vals + (jnp.asarray(np.stack(
+                            [_integrity.ctl_vector(hs.integrity,
+                                                   len(tr_idx))
+                             for _ in range(k_steps)])),)
             from ..engine import persist as _persist
             if kk not in self._var_avals:
                 self._record_variant(
@@ -2533,7 +2562,8 @@ class DataParallelTrainer:
             if cached is None or cached[1] is not fn:
                 cached = ({}, fn)
                 self._multi_exec[kk] = cached
-            sig = _persist.aval_sig(vals)
+            with _span("mxtpu.trainer.aval_sig", "trainer", step=n):
+                sig = _persist.aval_sig(vals)
             call = cached[0].get(sig)
             if call is None:
                 suffix = f"_k{k_steps}" + ("r" if repeated else "")
@@ -2549,7 +2579,9 @@ class DataParallelTrainer:
                 if _faults._active:
                     _faults.on_dispatch("spmd_step_multi", probe)
                 try:
-                    return call(*vals)
+                    with _span("mxtpu.trainer.execute", "trainer",
+                               step=n):
+                        return call(*vals)
                 except TypeError as e:
                     # aval drift the AOT executable rejects: demote
                     # THIS signature to the pjit path (cached — not a
@@ -2563,8 +2595,9 @@ class DataParallelTrainer:
                     return fn(*vals)
 
             try:
-                out = engine.retrying_call(_go, probe,
-                                           "spmd_step_multi")
+                with _span("mxtpu.trainer.dispatch", "trainer", step=n):
+                    out = engine.retrying_call(_go, probe,
+                                               "spmd_step_multi")
                 if engine._san is not None:
                     # mxsan: params AND state were donated to the
                     # bulked program — shadow-mark the whole probe set
@@ -2607,17 +2640,18 @@ class DataParallelTrainer:
         finally:
             autograd.set_training(prev)
 
-        for p, v in zip(self._params, new_all_params):
-            p.data()._set_data(v)
-        self._write_states(new_states)
-        if self._post_resize_probe is not None:
-            self._fire_resize_probe()
-        if hs is not None and health_out is not None:
-            from .. import telemetry as _tm
-            _tm.health.sample_owner(
-                self, f"spmd:{self.block.name}", hs, health_out,
-                k_steps)
-        return NDArray(loss_k, ctx=args[0].context)
+        with _span("mxtpu.trainer.write_back", "trainer", step=n):
+            for p, v in zip(self._params, new_all_params):
+                p.data()._set_data(v)
+            self._write_states(new_states)
+            if self._post_resize_probe is not None:
+                self._fire_resize_probe()
+            if hs is not None and health_out is not None:
+                from .. import telemetry as _tm
+                _tm.health.sample_owner(
+                    self, f"spmd:{self.block.name}", hs, health_out,
+                    k_steps)
+            return NDArray(loss_k, ctx=args[0].context)
 
     def _put_cached(self, a, sharding, used):
         """Device-place ``a._data`` under ``sharding`` through the
@@ -2827,129 +2861,135 @@ class DataParallelTrainer:
         from .. import random as _rnd
         from .. import autograd
 
-        args = list(data) if isinstance(data, (list, tuple)) else [data]
-        if self._params is None:
-            self._setup(args)
-        self._refresh_health()
-        if self._post_resize_probe is not None:
-            self._note_resize_probe_base()
-        from ..elastic import faults as _faults
-        if _faults._active and _faults.nonfinite_due("spmd_step"):
-            # the nonfinite drill: a NaN planted in the batch reaches
-            # the loss/gradients through the UNCHANGED compiled
-            # program (same shapes — no retrace)
-            from .. import telemetry as _tm
-            args = _tm.health.poison_inputs(args)
-        if _faults._active:
-            # the corrupt_param drill: a seeded single-bit flip in ONE
-            # device's live param shard (real physical corruption —
-            # same shapes, no retrace; the integrity fingerprints see
-            # the divergent replica on the next sampled step)
-            payload = _faults.corrupt_due("corrupt_param")
-            if payload is not None:
-                from ..elastic import integrity as _integrity
-                _integrity.corrupt_param_host(self, payload)
-        if self._fwd_bwd is None:
+        n = self._span_step
+        with _span("mxtpu.trainer.prologue", "trainer", step=n):
+            args = list(data) if isinstance(data, (list, tuple)) else [data]
+            if self._params is None:
+                self._setup(args)
+            self._refresh_health()
+            if self._post_resize_probe is not None:
+                self._note_resize_probe_base()
+            from ..elastic import faults as _faults
+            if _faults._active and _faults.nonfinite_due("spmd_step"):
+                # the nonfinite drill: a NaN planted in the batch reaches
+                # the loss/gradients through the UNCHANGED compiled
+                # program (same shapes — no retrace)
+                from .. import telemetry as _tm
+                args = _tm.health.poison_inputs(args)
+            if _faults._active:
+                # the corrupt_param drill: a seeded single-bit flip in ONE
+                # device's live param shard (real physical corruption —
+                # same shapes, no retrace; the integrity fingerprints see
+                # the divergent replica on the next sampled step)
+                payload = _faults.corrupt_due("corrupt_param")
+                if payload is not None:
+                    from ..elastic import integrity as _integrity
+                    _integrity.corrupt_param_host(self, payload)
+            if self._fwd_bwd is None:
+                prev = autograd.set_training(True)
+                try:
+                    self._build_fwd_bwd(args, label)
+                finally:
+                    autograd.set_training(prev)
+
+            use_full = self._fuse_step and self._rule is not None
+            hs = self._health_spec
+            health_out = None
             prev = autograd.set_training(True)
-            try:
-                self._build_fwd_bwd(args, label)
-            finally:
-                autograd.set_training(prev)
-
-        use_full = self._fuse_step and self._rule is not None
-        hs = self._health_spec
-        health_out = None
-        prev = autograd.set_training(True)
         try:
-            batch = NamedSharding(self.mesh, P(self.dp_axis))
+            with _span("mxtpu.trainer.place_batch", "trainer", step=n):
+                batch = NamedSharding(self.mesh, P(self.dp_axis))
 
-            used = set()
-            x_vals = tuple(self._put_cached(a, batch, used)
-                           for a in args)
-            y_val = self._put_cached(label, batch, used)
-            # only this step's inputs stay pinned — an epoch of
-            # distinct batches must not accumulate device copies
-            self._prune_placed(used)
-            key = _rnd._next_key_nd(args[0].context)
+                used = set()
+                x_vals = tuple(self._put_cached(a, batch, used)
+                               for a in args)
+                y_val = self._put_cached(label, batch, used)
+                # only this step's inputs stay pinned — an epoch of
+                # distinct batches must not accumulate device copies
+                self._prune_placed(used)
+            with _span("mxtpu.trainer.rng_key", "trainer", step=n):
+                key = _rnd._next_key_nd(args[0].context)
 
-            param_vals = tuple(p.data()._data for p in self._params)
+            with _span("mxtpu.trainer.gather_args", "trainer", step=n):
+                param_vals = tuple(p.data()._data for p in self._params)
+                if use_full:
+                    opt = self.optimizer
+                    for i in self._tr_idx:
+                        opt._update_count(i)
+                    scalar_vals = []
+                    for i in self._tr_idx:
+                        t = opt._index_update_count[i]
+                        scalar_vals.extend(
+                            np.asarray(sv, dtype=np.float32)
+                            for sv in self._rule.scalars(opt, i, t))
+                    # ZeRO subsumes the int8 compressed exchange (the
+                    # quantized reduce lives on its gradient leg), so the
+                    # compressed builder/call-shape only applies at stage 0
+                    compressed = self._compression_cfg is not None and \
+                        not self._zero_stage
+                    if self._full_step is None:
+                        if self._zero_stage:
+                            self._build_full_step_zero()
+                        elif self._compression_cfg is not None:
+                            self._build_full_step_compressed()
+                        else:
+                            self._build_full_step()
+                    if self._donation_poisoned is not None:
+                        from .. import engine as _eng
+                        if _eng._san is not None:
+                            _eng._san.note_poisoned_step(
+                                self, "spmd_step",
+                                self._donation_poisoned)
+                        raise MXNetError(
+                            "this trainer's optimizer state was donated to "
+                            "a fused step that failed and is no longer "
+                            "valid; call recover(manager) to restore "
+                            "parameters/optimizer state from the last "
+                            "committed checkpoint (docs/elasticity.md). "
+                            f"Original error: {self._donation_poisoned}")
+                    from .. import engine
+                    from ..elastic import faults as _faults
+                    state_flat = [v for vals in self._state_vals()
+                                  for v in vals]
+                    # everything _full_donate hands to the executable: the
+                    # compressed step donates the 2bit error-feedback
+                    # residuals (argnum 6) alongside the optimizer state,
+                    # and a plain-SGD run has ONLY residuals as donated
+                    # state — the poison probe must see them too
+                    donated_flat = state_flat + (
+                        list(self._residual_vals)
+                        if compressed and self._residual_vals else [])
+
+                    hextra = ()
+                    if hs is not None:
+                        # the dynamic sampling flag (0-d f32): gates the
+                        # in-graph health reductions without retracing
+                        from .. import telemetry as _tm
+                        hextra = (_tm.health.due_flags(
+                            self._health_count, 1)[0],)
+                        if hs.integrity is not None and \
+                                hs.integrity.inject:
+                            # the corruption-ctl row a baked drill reads
+                            # (all zeros = the XOR block is the identity)
+                            from ..elastic import integrity as _integrity
+                            hextra = hextra + (_integrity.ctl_vector(
+                                hs.integrity, len(self._tr_idx)),)
+
+                    if compressed and \
+                            not getattr(self, "_wire_noted_c", False):
+                        # the compressed path never crosses _tiered_exec,
+                        # so it registers with the wire auditor here (once;
+                        # program="" — no observatory record to reconcile)
+                        self._wire_noted_c = True
+                        self._note_wire(
+                            "_compressed",
+                            getattr(self, "_compressed_fn", None),
+                            (param_vals, self._state_vals(),
+                             tuple(scalar_vals), x_vals, y_val,
+                             key._data, self._residual_vals or ())
+                            + hextra, compressed=True, program="")
+
             if use_full:
-                opt = self.optimizer
-                for i in self._tr_idx:
-                    opt._update_count(i)
-                scalar_vals = []
-                for i in self._tr_idx:
-                    t = opt._index_update_count[i]
-                    scalar_vals.extend(
-                        np.asarray(sv, dtype=np.float32)
-                        for sv in self._rule.scalars(opt, i, t))
-                # ZeRO subsumes the int8 compressed exchange (the
-                # quantized reduce lives on its gradient leg), so the
-                # compressed builder/call-shape only applies at stage 0
-                compressed = self._compression_cfg is not None and \
-                    not self._zero_stage
-                if self._full_step is None:
-                    if self._zero_stage:
-                        self._build_full_step_zero()
-                    elif self._compression_cfg is not None:
-                        self._build_full_step_compressed()
-                    else:
-                        self._build_full_step()
-                if self._donation_poisoned is not None:
-                    from .. import engine as _eng
-                    if _eng._san is not None:
-                        _eng._san.note_poisoned_step(
-                            self, "spmd_step",
-                            self._donation_poisoned)
-                    raise MXNetError(
-                        "this trainer's optimizer state was donated to "
-                        "a fused step that failed and is no longer "
-                        "valid; call recover(manager) to restore "
-                        "parameters/optimizer state from the last "
-                        "committed checkpoint (docs/elasticity.md). "
-                        f"Original error: {self._donation_poisoned}")
-                from .. import engine
-                from ..elastic import faults as _faults
-                state_flat = [v for vals in self._state_vals()
-                              for v in vals]
-                # everything _full_donate hands to the executable: the
-                # compressed step donates the 2bit error-feedback
-                # residuals (argnum 6) alongside the optimizer state,
-                # and a plain-SGD run has ONLY residuals as donated
-                # state — the poison probe must see them too
-                donated_flat = state_flat + (
-                    list(self._residual_vals)
-                    if compressed and self._residual_vals else [])
-
-                hextra = ()
-                if hs is not None:
-                    # the dynamic sampling flag (0-d f32): gates the
-                    # in-graph health reductions without retracing
-                    from .. import telemetry as _tm
-                    hextra = (_tm.health.due_flags(
-                        self._health_count, 1)[0],)
-                    if hs.integrity is not None and \
-                            hs.integrity.inject:
-                        # the corruption-ctl row a baked drill reads
-                        # (all zeros = the XOR block is the identity)
-                        from ..elastic import integrity as _integrity
-                        hextra = hextra + (_integrity.ctl_vector(
-                            hs.integrity, len(self._tr_idx)),)
-
-                if compressed and \
-                        not getattr(self, "_wire_noted_c", False):
-                    # the compressed path never crosses _tiered_exec,
-                    # so it registers with the wire auditor here (once;
-                    # program="" — no observatory record to reconcile)
-                    self._wire_noted_c = True
-                    self._note_wire(
-                        "_compressed",
-                        getattr(self, "_compressed_fn", None),
-                        (param_vals, self._state_vals(),
-                         tuple(scalar_vals), x_vals, y_val,
-                         key._data, self._residual_vals or ())
-                        + hextra, compressed=True, program="")
-
                 def _go():
                     # the fault hook sits INSIDE the retried thunk so
                     # a one-shot "dispatch" fault is absorbed exactly
@@ -2970,8 +3010,10 @@ class DataParallelTrainer:
                          key._data) + hextra)
 
                 try:
-                    out = engine.retrying_call(
-                        _go, donated_flat, "spmd_full_step")
+                    with _span("mxtpu.trainer.dispatch", "trainer",
+                               step=n):
+                        out = engine.retrying_call(
+                            _go, donated_flat, "spmd_full_step")
                     if engine._san is not None:
                         # mxsan: the donated state set is dead now —
                         # shadow-mark it so a stale reference convicts
@@ -3009,24 +3051,26 @@ class DataParallelTrainer:
                         "committed checkpoint (docs/elasticity.md). "
                         f"Original error: {e!r}") from e
             else:
-                loss, grads, aux = self._fwd_bwd(param_vals, x_vals,
-                                                 y_val, key._data)
+                with _span("mxtpu.trainer.dispatch", "trainer", step=n):
+                    loss, grads, aux = self._fwd_bwd(param_vals, x_vals,
+                                                     y_val, key._data)
         finally:
             autograd.set_training(prev)
 
         if use_full:
-            for i, v in zip(self._mutated_idx, aux):
-                self._params[i].data()._set_data(v)
-            for i, v in zip(self._tr_idx, new_params):
-                self._params[i].data()._set_data(v)
-            self._write_states(new_states)
-            if self._post_resize_probe is not None:
-                self._fire_resize_probe()
-            if hs is not None and health_out is not None:
-                from .. import telemetry as _tm
-                _tm.health.sample_owner(
-                    self, f"spmd:{self.block.name}", hs, health_out, 1)
-            return NDArray(loss, ctx=args[0].context)
+            with _span("mxtpu.trainer.write_back", "trainer", step=n):
+                for i, v in zip(self._mutated_idx, aux):
+                    self._params[i].data()._set_data(v)
+                for i, v in zip(self._tr_idx, new_params):
+                    self._params[i].data()._set_data(v)
+                self._write_states(new_states)
+                if self._post_resize_probe is not None:
+                    self._fire_resize_probe()
+                if hs is not None and health_out is not None:
+                    from .. import telemetry as _tm
+                    _tm.health.sample_owner(
+                        self, f"spmd:{self.block.name}", hs, health_out, 1)
+                return NDArray(loss, ctx=args[0].context)
 
         # write mutated aux state (BatchNorm running stats) back
         for i, v in zip(self._mutated_idx, aux):

@@ -1,21 +1,21 @@
 """Profiler (parity: ``python/mxnet/profiler.py`` over
 ``src/profiler/profiler.cc`` — SURVEY.md §5 "Tracing / profiling").
 
-Two layers, mirroring the reference's engine-wired profiler:
+One span class, two sinks (docs/observability.md, "Spans"):
 
-* **Op events** — the engine's dispatch path is intercepted
-  (``engine._profiler_hook``) while the profiler runs; each op records a
-  host-side span (dispatch → ready when ``MXTPU_PROFILE_SYNC=1``, else
-  async dispatch span).  ``dump()`` writes chrome://tracing JSON,
-  ``dumps()`` an aggregate table — the same artifacts the reference
-  produced.
-* **Device traces** — ``profile_device=True`` brackets the run with
-  ``jax.profiler`` (XPlane/TensorBoard), the TPU-native replacement for
-  the reference's device timelines.
-
-Custom scopes: ``Marker``, ``record_scope`` map to instant events /
-ranges, and also forward to ``jax.profiler.TraceAnnotation`` so they show
-up inside XPlane traces.
+* **The profiler's own trace** — every :class:`span` (``record_scope`` is
+  its MXNet-shaped alias) is a ``jax.profiler.TraceAnnotation`` whenever
+  a jax profiler session is live (``jax.profiler.start_trace``, or
+  ``set_config(profile_device=True)`` + ``set_state("run")``), so the
+  program's phases land on the ``/host:CPU`` plane of the same XPlane
+  trace that holds the device's ``XLA Ops``, on one clock.  The switch
+  is jax's own: no session, no event.
+* **Chrome events** — while ``set_state("run")`` the same span also
+  appends a chrome://tracing complete event (``args`` = its ids), as
+  does the engine's per-op hook (``engine._profiler_hook``).  ``dump()``
+  writes the JSON, ``dumps()`` an aggregate table — the artifacts the
+  reference produced.  These time the host's side of an asynchronous
+  dispatch; device time is read from the XPlane trace.
 """
 from __future__ import annotations
 
@@ -23,13 +23,14 @@ import json
 import threading
 import time
 from collections import defaultdict
-from typing import List, Optional
+from typing import List
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from .base import MXNetError
-from . import engine
 
 __all__ = ["set_config", "set_state", "state", "pause", "resume", "dump",
-           "dumps", "Marker", "record_scope"]
+           "dumps", "Marker", "span", "record_scope"]
 
 _lock = threading.Lock()
 _events: List[dict] = []
@@ -48,6 +49,8 @@ _config = {
 }
 _device_trace_active = False
 _t0 = time.perf_counter()
+#: whether a jax profiler session is recording ``TraceMe`` events now
+_tracing = TraceAnnotation.is_enabled
 
 
 def _now_us():
@@ -62,33 +65,22 @@ def set_config(**kwargs):
     _config.update(kwargs)
 
 
-def _record_event(name, cat, start_us, end_us):
+def _record_event(name, cat, start_us, end_us, args=None):
     """Append one chrome-trace complete event (shared schema)."""
     if _paused:
         return
+    event = {"name": name, "ph": "X", "ts": start_us,
+             "dur": end_us - start_us, "pid": 0,
+             "tid": threading.get_ident() % 100000, "cat": cat}
+    if args:
+        event["args"] = args
     with _lock:
-        _events.append({"name": name, "ph": "X", "ts": start_us,
-                        "dur": end_us - start_us, "pid": 0,
-                        "tid": threading.get_ident() % 100000,
-                        "cat": cat})
-
-
-def _maybe_block(out):
-    """MXTPU_PROFILE_SYNC=1: block on outputs so spans measure device
-    time, not async dispatch."""
-    from . import envs
-    if envs.get("MXTPU_PROFILE_SYNC"):
-        import jax
-        try:
-            jax.block_until_ready(out)
-        except Exception:
-            pass  # non-array outputs (vjp closures) can't be awaited
+        _events.append(event)
 
 
 def _hook(name, fn, arrays):
     start = _now_us()
     out = fn(*arrays)
-    _maybe_block(out)
     _record_event(name, "operator", start, _now_us())
     return out
 
@@ -97,6 +89,7 @@ def set_state(state_name="stop", profile_process="worker"):
     """'run' starts collection; 'stop' ends it (parity:
     profiler.set_state)."""
     global _state, _device_trace_active
+    from . import engine
     if state_name not in ("run", "stop"):
         raise MXNetError("state must be 'run' or 'stop'")
     if state_name == "run" and _state != "run":
@@ -184,6 +177,14 @@ def active() -> bool:
     return _state == "run" and not _paused
 
 
+def recording() -> bool:
+    """True while either sink would keep a span: collection runs, or a
+    jax profiler session is live.  :class:`span` asks this itself; a
+    call site asks first only where half a microsecond per span is too
+    much (the engine's per-op path)."""
+    return _state == "run" or _tracing()
+
+
 def _mirror_event(name, args=None):
     """Telemetry mirror: one instant event in the chrome-trace stream
     for a structured telemetry event (retrace, prefetch stall, poison),
@@ -199,33 +200,55 @@ def _mirror_event(name, args=None):
                         "args": dict(args) if args else {}})
 
 
-class _span:
-    """Internal span recorder for framework call sites (CachedOp,
-    Executor, DataParallelTrainer) — the reference wired its profiler
-    INSIDE ExecuteOprBlock; these are the jit-path equivalents that the
-    imperative hook cannot see.  Cheap enough to enter unconditionally;
-    the event is only recorded while collection is active.  Call
-    ``sync(out)`` on the produced arrays before leaving the block so
-    MXTPU_PROFILE_SYNC measures device time like the imperative hook.
-    """
+class span:
+    """``with profiler.span("mxtpu.serving.admit", req=7):`` — one named
+    host range with its ids, for framework call sites and users alike.
 
-    __slots__ = ("name", "cat", "_start")
+    Entered while a jax profiler session is live it is a
+    ``jax.profiler.TraceAnnotation(name, **ids)`` (a
+    ``StepTraceAnnotation`` when ``step_num`` is given: the root of a
+    train step or a serving round), so it lands in the XPlane trace
+    beside the device's ops; left while ``set_state("run")`` it appends
+    the chrome event ``{name, cat, args: ids}`` that ``dump()`` and
+    ``dumps()`` read.  With neither on it is one ``with`` and one call
+    of jax's own switch (``TraceAnnotation.is_enabled``): under a
+    microsecond, so call sites enter it unconditionally.  A span's
+    parent is the span that encloses it on its thread; spans of one
+    request share ``req``."""
 
-    def __init__(self, name, cat):
+    __slots__ = ("name", "cat", "ids", "_step_num", "_annotation",
+                 "_start")
+
+    def __init__(self, name, cat="scope", step_num=None, **ids):
         self.name = name
         self.cat = cat
+        self.ids = ids
+        self._step_num = step_num
 
     def __enter__(self):
-        self._start = _now_us()
+        if _tracing():
+            if self._step_num is None:
+                ann = TraceAnnotation(self.name, **self.ids)
+            else:
+                ann = StepTraceAnnotation(
+                    self.name, step_num=self._step_num, **self.ids)
+            ann.__enter__()
+            self._annotation = ann
+        else:
+            self._annotation = None
+        self._start = _now_us() if _state == "run" else None
         return self
 
-    def sync(self, out):
-        if active():
-            _maybe_block(out)
-
     def __exit__(self, *exc):
-        if active():
-            _record_event(self.name, self.cat, self._start, _now_us())
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        if self._start is not None and _state == "run":
+            _record_event(self.name, self.cat, self._start, _now_us(),
+                          self.ids)
+
+
+#: parity: ``mx.profiler.record_scope`` — the same class
+record_scope = span
 
 
 class Marker:
@@ -240,32 +263,3 @@ class Marker:
                 _events.append({"name": self.name, "ph": "i",
                                 "ts": _now_us(), "pid": 0, "tid": 0,
                                 "s": "p", "cat": "marker"})
-
-
-class record_scope:
-    """``with profiler.record_scope('step'):`` — a named range, also
-    visible in XPlane traces."""
-
-    def __init__(self, name):
-        self.name = name
-        self._jax_ctx = None
-
-    def __enter__(self):
-        self._start = _now_us()
-        try:
-            import jax
-            self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
-            self._jax_ctx.__enter__()
-        except Exception:
-            self._jax_ctx = None
-        return self
-
-    def __exit__(self, *exc):
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(*exc)
-        if _state == "run" and not _paused:
-            with _lock:
-                _events.append({"name": self.name, "ph": "X",
-                                "ts": self._start,
-                                "dur": _now_us() - self._start,
-                                "pid": 0, "tid": 0, "cat": "scope"})

@@ -44,8 +44,8 @@ class Request:
 
     __slots__ = ("id", "prompt", "max_new_tokens", "temperature",
                  "eos_id", "state", "generated", "bucket", "slot",
-                 "submit_t", "first_token_t", "done_t", "evict_reason",
-                 "ttl_ms", "deadline")
+                 "submit_t", "admit_t", "first_token_t", "done_t",
+                 "evict_reason", "ttl_ms", "deadline")
 
     def __init__(self, prompt, max_new_tokens: int,
                  temperature: float = 0.0,
@@ -66,6 +66,9 @@ class Request:
         self.bucket: Optional["Bucket"] = None
         self.slot: Optional[int] = None
         self.submit_t = time.perf_counter()
+        # when its (latest) admission began: admit_t - submit_t is the
+        # wait for a slot
+        self.admit_t: Optional[float] = None
         self.first_token_t: Optional[float] = None
         self.done_t: Optional[float] = None
         self.evict_reason: Optional[str] = None

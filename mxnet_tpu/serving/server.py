@@ -50,6 +50,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..base import MXNetError
+from ..profiler import span as _span
 from .kvcache import KVCachePool
 from .scheduler import ACTIVE, BucketScheduler, Request
 
@@ -192,6 +193,9 @@ class Server:
                     "steady_misses": 0, "steady_fresh_compiles": 0}
             for b in self.sched.buckets}
         self._poisoned: Optional[str] = None
+        # calls of step() so far: the `round` id of this server's
+        # profiler spans (docs/observability.md, "Spans")
+        self._span_round = 0
         self.warm_started = False
         self._persist_pinned = False
         self._struct_hash = self._compute_struct_hash()
@@ -349,13 +353,20 @@ class Server:
                 "recover() to rebuild the pools and requeue resident "
                 "requests (docs/serving.md). Original error: "
                 f"{self._poisoned}")
+        self._span_round = n = self._span_round + 1
+        with _span("mxtpu.serving.round", "serving", step_num=n, round=n):
+            return self._round(int(decode_steps))
+
+    def _round(self, decode_steps: int) -> dict:
         # deadline sweep FIRST: an expired queued request must not
         # consume the slot (and the prefill dispatch) it can no longer
         # use, and an expired resident frees its slot for this round's
         # admissions
-        self._expire_deadlines()
+        with _span("mxtpu.serving.expire", "serving"):
+            self._expire_deadlines()
         admitted = 0
-        pending = self.sched.admissions()
+        with _span("mxtpu.serving.schedule", "serving"):
+            pending = self.sched.admissions()
         for i, (bucket, slot, req) in enumerate(pending):
             try:
                 self._admit(bucket, slot, req)
@@ -377,7 +388,7 @@ class Server:
         for bucket in self.sched.buckets:
             if bucket.n_active() == 0:
                 continue
-            tokens += self._decode(bucket, int(decode_steps))
+            tokens += self._decode(bucket, decode_steps)
         self._update_gauges()
         return {"admitted": admitted, "tokens": tokens,
                 "active": len(self.sched.active_requests()),
@@ -1062,11 +1073,16 @@ class Server:
         return prefill_pure
 
     # -- dispatch ----------------------------------------------------------
-    def _dispatch(self, bucket, kind: str, extra, k: int = 0):
+    def _dispatch(self, bucket, kind: str, extra, k: int = 0, **ids):
         """One engine dispatch of a bucket program with the pool
         donated; returns the non-cache outputs with the successor pool
         adopted.  Post-donation failures poison the bucket (the
-        recovery half lives in :meth:`recover`)."""
+        recovery half lives in :meth:`recover`).  ``ids`` (``req`` of
+        an admission) go onto the dispatch's profiler span."""
+        with _span("mxtpu.serving.dispatch", "serving", kind=kind, **ids):
+            return self._dispatch_impl(bucket, kind, extra, k)
+
+    def _dispatch_impl(self, bucket, kind: str, extra, k: int):
         from .. import engine, telemetry
         pool = self._pools[bucket.key]
         if pool.poisoned is not None:
@@ -1077,19 +1093,21 @@ class Server:
         pure = self._pure_for(bucket, kind, k)
         P = len(self._param_nds)
         L2 = 2 * pool.num_layers
-        if self._decode_sharding is not None:
-            # the planned decode mesh: params ride as the replicated
-            # copies placed at construction, and every per-dispatch
-            # extra (tokens/offsets/temps/key) is committed replicated
-            # — one coherent SPMD program, no mixed-device inputs
-            import jax as _jax
-            extra = [_jax.device_put(e, self._repl_sharding)
-                     for e in extra]
-            params_flat = list(self._placed_params)
-        else:
-            params_flat = [p._data for p in self._param_nds]
-        flat = params_flat + pool.flat() + list(extra)
-        donate = tuple(range(P, P + L2))
+        with _span("mxtpu.serving.flatten", "serving"):
+            if self._decode_sharding is not None:
+                # the planned decode mesh: params ride as the
+                # replicated copies placed at construction, and every
+                # per-dispatch extra (tokens/offsets/temps/key) is
+                # committed replicated — one coherent SPMD program, no
+                # mixed-device inputs
+                import jax as _jax
+                extra = [_jax.device_put(e, self._repl_sharding)
+                         for e in extra]
+                params_flat = list(self._placed_params)
+            else:
+                params_flat = [p._data for p in self._param_nds]
+            flat = params_flat + pool.flat() + list(extra)
+            donate = tuple(range(P, P + L2))
         name = self.name + suffix
         persist_name = self._persist_base + suffix
         m0, f0 = engine.compile_counts()
@@ -1123,111 +1141,136 @@ class Server:
                         "requests (docs/serving.md). Original error: "
                         f"{e!r}") from e
                 raise
-        n_out = len(res) - L2
-        pool.adopt(res[n_out:])
-        if suffix not in self._variants:
-            self._variants[suffix] = {
-                "suffix": suffix, "kind": kind, "k": k,
-                "donate": [int(i) for i in donate],
-                "avals": engine.persist.sig_to_json(
-                    engine.persist.aval_sig(flat))}
-            # the wire auditor (analysis.wire_passes): serving decode/
-            # prefill legs classify via the plan's decode spec; no
-            # observatory reconciliation (program="") — serving wire
-            # is GSPMD-implicit on the decode mesh
-            try:
-                from ..analysis import wire_passes as _wire
-                _wire.note_step(
-                    f"serving:{self.lm.name}", suffix, pure, flat,
-                    plan=self.plan, kind=kind, program="")
-            except Exception:
-                pass
-        if suffix not in self._warmed:
-            # first dispatch of this variant pays its compile; every
-            # later one is steady state and must compile NOTHING
-            self._warmed.add(suffix)
-        else:
-            m1, f1 = engine.compile_counts()
-            stats = self._bucket_stats[bucket.key]
-            stats["steady_dispatches"] += 1
-            stats["steady_misses"] += m1 - m0
-            stats["steady_fresh_compiles"] += f1 - f0
+        with _span("mxtpu.serving.adopt", "serving"):
+            n_out = len(res) - L2
+            pool.adopt(res[n_out:])
+            if suffix not in self._variants:
+                self._variants[suffix] = {
+                    "suffix": suffix, "kind": kind, "k": k,
+                    "donate": [int(i) for i in donate],
+                    "avals": engine.persist.sig_to_json(
+                        engine.persist.aval_sig(flat))}
+                # the wire auditor (analysis.wire_passes): serving decode/
+                # prefill legs classify via the plan's decode spec; no
+                # observatory reconciliation (program="") — serving wire
+                # is GSPMD-implicit on the decode mesh
+                try:
+                    from ..analysis import wire_passes as _wire
+                    _wire.note_step(
+                        f"serving:{self.lm.name}", suffix, pure, flat,
+                        plan=self.plan, kind=kind, program="")
+                except Exception:
+                    pass
+            if suffix not in self._warmed:
+                # first dispatch of this variant pays its compile; every
+                # later one is steady state and must compile NOTHING
+                self._warmed.add(suffix)
+            else:
+                m1, f1 = engine.compile_counts()
+                stats = self._bucket_stats[bucket.key]
+                stats["steady_dispatches"] += 1
+                stats["steady_misses"] += m1 - m0
+                stats["steady_fresh_compiles"] += f1 - f0
         return res[:n_out]
 
     def _admit(self, bucket, slot: int, req: Request):
+        with _span("mxtpu.serving.admit", "serving", req=req.id,
+                   bucket=bucket.prompt_len, slot=slot):
+            self._admit_impl(bucket, slot, req)
+
+    def _admit_impl(self, bucket, slot: int, req: Request):
         from .. import random as _rnd
         from .. import telemetry
-        t0 = time.perf_counter()
-        S = bucket.prompt_len
-        prompt = np.zeros((1, S), np.float32)
-        prompt[0, :req.prompt_len] = req.prompt
-        extra = [prompt,
-                 np.asarray([req.prompt_len - 1], np.float32),
-                 np.asarray(slot, np.float32),
-                 np.asarray([req.temperature], np.float32),
-                 _rnd._next_key_nd(self.ctx)._data]
+        t0 = req.admit_t = time.perf_counter()
+        telemetry.histogram(
+            "mxtpu_serving_queue_wait_seconds",
+            "submit -> start of the admission (s)").observe(
+            t0 - req.submit_t)
+        with _span("mxtpu.serving.build_inputs", "serving", req=req.id):
+            S = bucket.prompt_len
+            prompt = np.zeros((1, S), np.float32)
+            prompt[0, :req.prompt_len] = req.prompt
+            extra = [prompt,
+                     np.asarray([req.prompt_len - 1], np.float32),
+                     np.asarray(slot, np.float32),
+                     np.asarray([req.temperature], np.float32),
+                     _rnd._next_key_nd(self.ctx)._data]
         # pre-dispatch failures (trace/compile, retries exhausted)
         # propagate to step(), which releases THIS placement and the
         # ones behind it back to the queue in FIFO order
-        out = self._dispatch(bucket, "prefill", extra)
-        tok = int(np.asarray(out[0])[0])     # host sync: TTFT is real
-        telemetry.counter("mxtpu_serving_prefills_total",
-                          "admission prefill dispatches").inc()
-        bucket.last_tokens[slot] = float(tok)
-        self._bucket_stats[bucket.key]["tokens"] += 1
-        telemetry.counter("mxtpu_serving_tokens_total",
-                          "tokens generated by the serving plane").inc()
-        finished = req.push_token(tok)
-        telemetry.histogram(
-            "mxtpu_serving_ttft_seconds",
-            "submit -> first generated token (s)").observe(
-            req.first_token_t - req.submit_t)
-        telemetry.histogram(
-            "mxtpu_serving_prefill_seconds",
-            "one admission (prefill dispatch + first token) (s)"
-            ).observe(time.perf_counter() - t0)
-        if finished:
-            self._finish(req)
+        out = self._dispatch(bucket, "prefill", extra, req=req.id)
+        with _span("mxtpu.serving.token_read", "serving", req=req.id):
+            tok = int(np.asarray(out[0])[0])  # host sync: TTFT is real
+        with _span("mxtpu.serving.bookkeeping", "serving", req=req.id):
+            telemetry.counter("mxtpu_serving_prefills_total",
+                              "admission prefill dispatches").inc()
+            bucket.last_tokens[slot] = float(tok)
+            self._bucket_stats[bucket.key]["tokens"] += 1
+            telemetry.counter(
+                "mxtpu_serving_tokens_total",
+                "tokens generated by the serving plane").inc()
+            finished = req.push_token(tok)
+            telemetry.histogram(
+                "mxtpu_serving_ttft_seconds",
+                "submit -> first generated token (s)").observe(
+                req.first_token_t - req.submit_t)
+            telemetry.histogram(
+                "mxtpu_serving_prefill_seconds",
+                "one admission (prefill dispatch + first token) (s)"
+                ).observe(time.perf_counter() - t0)
+            if finished:
+                self._finish(req)
 
     def _decode(self, bucket, decode_steps: int) -> int:
+        with _span("mxtpu.serving.decode", "serving",
+                   bucket=bucket.prompt_len, active=bucket.n_active()):
+            return self._decode_impl(bucket, decode_steps)
+
+    def _decode_impl(self, bucket, decode_steps: int) -> int:
         from .. import random as _rnd
         from .. import telemetry
         t0 = time.perf_counter()
         k = max(1, int(decode_steps))
-        active_snap = bucket.active.copy()
-        extra = [bucket.last_tokens.reshape(bucket.slots, 1).copy(),
-                 bucket.offsets.copy(), active_snap.copy(),
-                 bucket.temps.copy(),
-                 _rnd._next_key_nd(self.ctx)._data]
+        with _span("mxtpu.serving.build_inputs", "serving"):
+            active_snap = bucket.active.copy()
+            extra = [bucket.last_tokens.reshape(bucket.slots, 1).copy(),
+                     bucket.offsets.copy(), active_snap.copy(),
+                     bucket.temps.copy(),
+                     _rnd._next_key_nd(self.ctx)._data]
         out = self._dispatch(bucket, "decode", extra,
                              k=0 if k == 1 else k)
-        toks = np.asarray(out[0])
-        if toks.ndim == 1:
-            toks = toks[None, :]               # (K, N)
-        # host bookkeeping mirrors the in-graph carry: offsets advance
-        # K per slot ACTIVE AT DISPATCH (release() rewinds finishers)
-        bucket.offsets += k * active_snap
-        produced = 0
-        for row in toks:
-            for j in np.nonzero(active_snap > 0)[0]:
-                req = bucket.requests[int(j)]
-                if req is None or req.state != ACTIVE:
-                    continue               # finished mid-K: overrun rows
-                tok = int(row[int(j)])
-                bucket.last_tokens[int(j)] = float(tok)
-                produced += 1
-                if req.push_token(tok):
-                    self._finish(req)
-        dt = time.perf_counter() - t0
-        telemetry.histogram("mxtpu_serving_decode_seconds",
-                            "one decode dispatch wall clock (s)"
-                            ).observe(dt)
-        if produced:
-            telemetry.counter(
-                "mxtpu_serving_tokens_total",
-                "tokens generated by the serving plane").inc(produced)
-        self._bucket_stats[bucket.key]["tokens"] += produced
-        return produced
+        # the host WAITS for the device here: the one span of a round
+        # in which the chip is supposed to be busy
+        with _span("mxtpu.serving.token_read", "serving"):
+            toks = np.asarray(out[0])
+        with _span("mxtpu.serving.bookkeeping", "serving"):
+            if toks.ndim == 1:
+                toks = toks[None, :]               # (K, N)
+            # host bookkeeping mirrors the in-graph carry: offsets
+            # advance K per slot ACTIVE AT DISPATCH (release() rewinds
+            # finishers)
+            bucket.offsets += k * active_snap
+            produced = 0
+            for row in toks:
+                for j in np.nonzero(active_snap > 0)[0]:
+                    req = bucket.requests[int(j)]
+                    if req is None or req.state != ACTIVE:
+                        continue           # finished mid-K: overrun rows
+                    tok = int(row[int(j)])
+                    bucket.last_tokens[int(j)] = float(tok)
+                    produced += 1
+                    if req.push_token(tok):
+                        self._finish(req)
+            dt = time.perf_counter() - t0
+            telemetry.histogram("mxtpu_serving_decode_seconds",
+                                "one decode dispatch wall clock (s)"
+                                ).observe(dt)
+            if produced:
+                telemetry.counter(
+                    "mxtpu_serving_tokens_total",
+                    "tokens generated by the serving plane").inc(produced)
+            self._bucket_stats[bucket.key]["tokens"] += produced
+            return produced
 
     def _finish(self, req: Request):
         from .. import telemetry

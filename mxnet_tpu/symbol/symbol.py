@@ -761,11 +761,9 @@ class Executor:
         for ``backward()`` — the classic forward();backward() idiom costs
         one XLA execution, not two."""
         from .. import profiler
-        with profiler._span(f"Executor.forward[train={bool(is_train)}]",
-                            "executor") as sp:
-            outs = self._forward_impl(is_train, **kwargs)
-            sp.sync([o._data for o in outs])
-            return outs
+        with profiler.span(f"Executor.forward[train={bool(is_train)}]",
+                           "executor"):
+            return self._forward_impl(is_train, **kwargs)
 
     def _forward_impl(self, is_train=False, **kwargs):
         from .. import random as _rnd

@@ -48,6 +48,34 @@ def pytest_configure(config):
         "markers",
         "needs_mesh(n=8): whole module/test needs an n-device mesh — "
         "auto-skipped on backends with fewer devices")
+    config.addinivalue_line(
+        "markers",
+        "time_limit(seconds): the test FAILS once it has run this long, "
+        "instead of holding up the run")
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_call(item):
+    """``@pytest.mark.time_limit(s)``: an interval timer raises in the
+    test's (main) thread; what the test holds is released by its own
+    ``finally`` blocks and fixtures."""
+    import signal
+    marker = item.get_closest_marker("time_limit")
+    if marker is None:
+        yield
+        return
+    seconds = float(marker.args[0])
+
+    def on_alarm(_signum, _frame):
+        raise TimeoutError(f"{item.nodeid} ran past its {seconds:g} s limit")
+
+    previous = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
 
 
 def pytest_collection_modifyitems(config, items):

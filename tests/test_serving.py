@@ -436,6 +436,94 @@ def test_serving_telemetry_events_and_metrics(net):
     assert hist.quantile(0.99) >= hist.quantile(0.5)
 
 
+def _inside(child, parent):
+    return parent["ts"] <= child["ts"] and \
+        child["ts"] + child["dur"] <= parent["ts"] + parent["dur"]
+
+
+@pytest.mark.time_limit(120)
+def test_round_spans_nest_and_share_the_request_id(net):
+    """One ``step()`` that admits one request and decodes another:
+    round > admit / decode > dispatch > engine.execute, every span of
+    the admission carries its one ``req`` id, and with the profiler
+    stopped (and no jax session) a round appends nothing."""
+    from mxnet_tpu import profiler
+    srv = Server(net, buckets=[(2, 8)], max_new_tokens=6)
+    srv.submit(_prompt(30, 4))
+    srv.step()                          # compiles prefill + decode
+    req = srv.submit(_prompt(31, 5))
+    profiler.set_state("run")
+    try:
+        srv.step()
+    finally:
+        profiler.set_state("stop")
+    with profiler._lock:
+        events = [e for e in profiler._events if e["ph"] == "X"]
+        profiler._events.clear()
+
+    def named(name, within=None):
+        return [e for e in events if e["name"] == name
+                and (within is None or _inside(e, within))]
+
+    rnd, = named("mxtpu.serving.round")
+    assert rnd["args"] == {"round": 2} and rnd["cat"] == "serving"
+    assert all(_inside(e, rnd) and e["tid"] == rnd["tid"]
+               for e in events if e is not rnd)
+    assert len(named("mxtpu.serving.expire", rnd)) == 1
+    assert len(named("mxtpu.serving.schedule", rnd)) == 1
+    admit, = named("mxtpu.serving.admit", rnd)
+    decode, = named("mxtpu.serving.decode", rnd)
+    assert admit["args"] == {"req": req.id, "bucket": 8, "slot": 1}
+    assert decode["args"] == {"bucket": 8, "active": 2}
+    assert admit["ts"] + admit["dur"] <= decode["ts"]
+    for parent, kind in ((admit, "prefill"), (decode, "decode")):
+        for leaf in ("build_inputs", "token_read", "bookkeeping"):
+            assert len(named("mxtpu.serving." + leaf, parent)) == 1
+        dispatch, = named("mxtpu.serving.dispatch", parent)
+        assert dispatch["args"]["kind"] == kind
+        assert len(named("mxtpu.serving.flatten", dispatch)) == 1
+        assert len(named("mxtpu.serving.adopt", dispatch)) == 1
+        lookup, = named("mxtpu.engine.lookup", dispatch)
+        assert len(named("mxtpu.engine.telemetry", dispatch)) == 1
+        execute, = named("mxtpu.engine.execute", dispatch)
+        assert lookup["args"]["op"] == execute["args"]["op"]
+        assert execute["args"]["op"].endswith(kind)
+    for leaf in ("build_inputs", "dispatch", "token_read", "bookkeeping"):
+        assert named("mxtpu.serving." + leaf,
+                     admit)[0]["args"]["req"] == req.id
+    assert {e["args"]["req"] for e in events
+            if "req" in e.get("args", {})} == {req.id}
+    assert not any("req" in e.get("args", {}) for e in events
+                   if _inside(e, decode))
+
+    srv.step()                          # both sinks off
+    assert profiler._events == []
+
+
+@pytest.mark.time_limit(120)
+def test_admit_stamp_and_queue_wait_histogram(net):
+    """``Request.admit_t`` is set where the admission starts, and
+    ``mxtpu_serving_queue_wait_seconds`` takes one observation per
+    admission: a request that waited for a slot shows its wait."""
+    telemetry.reset()
+    srv = Server(net, buckets=[(1, 8)], max_new_tokens=3)
+    first = srv.submit(_prompt(32, 4))
+    second = srv.submit(_prompt(33, 4))     # one slot: it must queue
+    assert first.admit_t is None and second.admit_t is None
+    srv.step()
+    assert second.admit_t is None
+    assert first.submit_t <= first.admit_t <= first.first_token_t
+    srv.run()
+    assert second.admit_t > first.done_t > first.admit_t
+    hist = telemetry.histogram(
+        "mxtpu_serving_queue_wait_seconds",
+        "submit -> start of the admission (s)").summary()
+    assert hist["count"] == 2
+    assert hist["sum"] == pytest.approx(
+        (first.admit_t - first.submit_t)
+        + (second.admit_t - second.submit_t))
+
+
 def test_evict_event_survives_dispatch_flood(net):
     """request_evicted/slot_oom live in the RETAINED rare ring: a
     flood of dispatch events cannot evict the forensics."""
