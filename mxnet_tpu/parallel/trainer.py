@@ -710,6 +710,22 @@ class DataParallelTrainer:
             else:
                 s._set_data(vals[0])
 
+    def _step_scalars(self, ahead=0):
+        """The fused rule's per-step scalars (bias-corrected lr, wd,
+        ...) as ONE float32 vector ``(n_scalars * len(tr_idx),)``, the
+        scalars of trainable param ``j`` at ``[j * n_scalars + k]``:
+        one host leaf and one transfer a step, however many params.
+        Rebuilt every step (a scheduler or ``set_learning_rate`` may
+        move any of them); ``ahead`` reads the PROSPECTIVE update count
+        ``t + ahead`` (step_multi's inner steps)."""
+        opt = self.optimizer
+        flat = []
+        for i in self._tr_idx:
+            t = opt._index_update_count.get(
+                i, opt.begin_num_update) + ahead
+            flat.extend(self._rule.scalars(opt, i, t))
+        return np.asarray(flat, dtype=np.float32)
+
     def _build_full_step(self):
         """ONE program: loss/grads + the multi-tensor optimizer update,
         with optimizer states donated (their buffers are dead the
@@ -1287,8 +1303,8 @@ class DataParallelTrainer:
             "inputs": _persist.sig_to_json(_persist.aval_sig(x)),
             "label": _persist.sig_to_json(_persist.aval_sig([y]))[0],
             "key": _persist.sig_to_json(_persist.aval_sig([key]))[0],
-            "scalars": _persist.sig_to_json(_persist.aval_sig(
-                tree_util.tree_leaves(scal))),
+            # ONE row: the (S,) vector, or step_multi's (K, S) stack
+            "scalars": _persist.sig_to_json(_persist.aval_sig([scal])),
         }
         if len(vals) > 6:
             # trailing extras (the health plane's due flag): recorded
@@ -1489,6 +1505,13 @@ class DataParallelTrainer:
                     for s, a in zip(shapes, in_avals)]
             label = nd.array(np.zeros(
                 lbl_shape, dtype=np.dtype(lbl_aval[1])))
+            if any(len(v["scalars"]) != 1 for v in variants):
+                # a manifest from before the scalars travelled as one
+                # vector (one row per scalar): its programs are ones
+                # no step calls any more, so none is pre-compiled
+                return _fail("manifest records one leaf per optimizer "
+                             "scalar; the fused step now takes them "
+                             "as one vector (re-save the signature)")
         except Exception as e:
             return _fail(f"bad aval record: {e!r}"[:300])
         if resharded:
@@ -1597,9 +1620,8 @@ class DataParallelTrainer:
                     y_sds = jax.ShapeDtypeStruct(la[0], np.dtype(la[1]))
                     ka = _persist.sig_from_json([v["key"]])[0]
                     k_sds = jax.ShapeDtypeStruct(ka[0], np.dtype(ka[1]))
-                    scal_avals = _persist.sig_from_json(v["scalars"])
-                    scal_sds = [jax.ShapeDtypeStruct(
-                        a[0], np.dtype(a[1])) for a in scal_avals]
+                    sa, = _persist.sig_from_json(v["scalars"])
+                    scal_sds = jax.ShapeDtypeStruct(sa[0], np.dtype(sa[1]))
                 except (TypeError, ValueError) as e:
                     return _fail(f"bad variant avals: {e!r}"[:300])
                 try:
@@ -1610,10 +1632,10 @@ class DataParallelTrainer:
                 except (TypeError, ValueError) as e:
                     return _fail(f"bad variant avals: {e!r}"[:300])
                 k = v.get("k_steps")
+                vals = (param_vals, state_vals, scal_sds,
+                        x_sds, y_sds, k_sds) + extra_sds
                 if k:
                     kk = (int(k), bool(v.get("repeat")))
-                    vals = (param_vals, state_vals, scal_sds[0],
-                            x_sds, y_sds, k_sds) + extra_sds
                     fn = self._multi_step_cache.get(kk)
                     if fn is None:
                         fn = self._build_full_step_multi(*kk)
@@ -1623,8 +1645,6 @@ class DataParallelTrainer:
                     self._multi_exec[kk] = (
                         {_persist.aval_sig(vals): call}, fn)
                 else:
-                    vals = (param_vals, state_vals, tuple(scal_sds),
-                            x_sds, y_sds, k_sds) + extra_sds
                     call = self._tiered_exec(
                         "", self._full_step, self._full_fn, vals,
                         self._full_donate)
@@ -2056,18 +2076,17 @@ class DataParallelTrainer:
                         f"the target dp size {n_b}; cannot resize "
                         "without changing the batch layout")
             key = (k, rep, _p.aval_sig(
-                list(scal_sds) + list(x_sds) + [y_sds, key_sds] +
+                [scal_sds] + list(x_sds) + [y_sds, key_sds] +
                 list(extra_sds)))
             jobs.setdefault(
-                key, (list(scal_sds), tuple(x_sds), y_sds, key_sds,
+                key, (scal_sds, tuple(x_sds), y_sds, key_sds,
                       tuple(extra_sds)))
 
         for (k, rep), row in self._var_avals.items():
             try:
                 _add_job(
                     k, rep,
-                    [_sds(a) for a in
-                     _persist.sig_from_json(row["scalars"])],
+                    _sds(_persist.sig_from_json(row["scalars"])[0]),
                     [_sds(a) for a in
                      _persist.sig_from_json(row["inputs"])],
                     _sds(_persist.sig_from_json([row["label"]])[0]),
@@ -2080,8 +2099,6 @@ class DataParallelTrainer:
                     f"{e!r}")
         n_p = len(self._params)
         n_state = sum(len(vals) for vals in self._state_vals())
-        n_scal_1 = len(self._rule.scalars(self.optimizer, 0, 1)) \
-            * len(self._tr_idx)
         sig_sources = []
         if self._full_exec is not None:
             sig_sources.extend((0, False, s)
@@ -2089,17 +2106,17 @@ class DataParallelTrainer:
         for (k, rep), cached in self._multi_exec.items():
             sig_sources.extend((k, rep, s) for s in cached[0])
         for k, rep, sig in sig_sources:
+            # after params and state: the scalar vector, the inputs,
+            # label, key, then the health extras
             entries = list(sig[n_p + n_state:])
-            n_scal = 1 if k else n_scal_1
-            if len(entries) < n_scal + self._n_args + 2 or \
+            if len(entries) < 1 + self._n_args + 2 or \
                     any(len(a) != 2 for a in entries):
                 continue          # unreconstructable: skip, not fatal
-            scal = [_sds(a) for a in entries[:n_scal]]
-            rest = entries[n_scal:]
+            rest = entries[1:]
             x = [_sds(a) for a in rest[:self._n_args]]
             rest = rest[self._n_args:]
-            _add_job(k, rep, scal, x, _sds(rest[0]), _sds(rest[1]),
-                     [_sds(a) for a in rest[2:]])
+            _add_job(k, rep, _sds(entries[0]), x, _sds(rest[0]),
+                     _sds(rest[1]), [_sds(a) for a in rest[2:]])
 
         # the builders read self.mesh (shard_map mesh, batch
         # shardings, n_dp) and self._persist_name() (hashes the mesh):
@@ -2145,13 +2162,13 @@ class DataParallelTrainer:
                     jobs, key=lambda j: (j[0], j[1], repr(j[2]))):
                 scal_sds, x_sds, y_sds, k_sds, extra_sds = \
                     jobs[(k, rep, _dsig)]
+                vals = (param_sds, state_sds, scal_sds,
+                        x_sds, y_sds, k_sds) + extra_sds
                 if k:
                     suffix = f"_k{k}" + ("r" if rep else "")
                     fn = self._multi_step_cache.get((k, rep))
                     if fn is None:
                         fn = self._build_full_step_multi(k, rep)
-                    vals = (param_sds, state_sds, scal_sds[0],
-                            x_sds, y_sds, k_sds) + extra_sds
                     call = self._tiered_exec(
                         suffix, fn, self._multi_fns[(k, rep)],
                         vals, (0, 1))
@@ -2159,8 +2176,6 @@ class DataParallelTrainer:
                         (k, rep), ({}, fn))[0]
                     by_sig[_persist.aval_sig(vals)] = call
                 else:
-                    vals = (param_sds, state_sds, tuple(scal_sds),
-                            x_sds, y_sds, k_sds) + extra_sds
                     call = self._tiered_exec(
                         "", self._full_step, self._full_fn, vals,
                         self._full_donate)
@@ -2499,17 +2514,9 @@ class DataParallelTrainer:
                 # counts (t+1..t+K) — the real counters only advance after
                 # a successful dispatch, so a compile/shape failure cannot
                 # silently skew Adam bias correction for later steps
-                scal_rows = []
-                for k in range(k_steps):
-                    row = []
-                    for i in tr_idx:
-                        t = opt._index_update_count.get(
-                            i, opt.begin_num_update) + k + 1
-                        row.extend(np.asarray(sv, dtype=np.float32)
-                                   for sv in self._rule.scalars(opt, i, t))
-                    scal_rows.append(np.stack(row) if row
-                                     else np.zeros((0,), np.float32))
-                scalar_k = jnp.asarray(np.stack(scal_rows))   # (K, S)
+                scalar_k = jnp.asarray(np.stack(
+                    [self._step_scalars(k + 1)
+                     for k in range(k_steps)]))             # (K, S)
 
             with _span("mxtpu.trainer.rng_key", "trainer", step=n):
                 # RNG: snapshot the stream so a pre-dispatch failure can
@@ -2706,9 +2713,6 @@ class DataParallelTrainer:
         # scanned xs (elastic.integrity; production programs carry
         # only the due flags)
         has_ictl = _ispec is not None and _ispec.inject
-        # same count _build_full_step derives as n_scalars per param
-        n_scal = len(self._rule.scalars(self.optimizer, 0, 1)) \
-            * len(tr_idx)
 
         def full_k(param_vals, tstate_vals, scalar_k, inputs_k,
                    label_k, keys_k, due_k=None, ictl_k=None):
@@ -2732,15 +2736,14 @@ class DataParallelTrainer:
                     scal_row, inputs, label, key, due = xs
                 else:
                     scal_row, inputs, label, key = xs
-                scal = tuple(scal_row[i] for i in range(n_scal))
                 if has_ictl:
-                    out = full(params, tstates, scal, inputs, label,
+                    out = full(params, tstates, scal_row, inputs, label,
                                key, due, ictl)
                 elif has_health:
-                    out = full(params, tstates, scal, inputs, label,
+                    out = full(params, tstates, scal_row, inputs, label,
                                key, due)
                 else:
-                    out = full(params, tstates, scal, inputs, label,
+                    out = full(params, tstates, scal_row, inputs, label,
                                key)
                 if has_health:
                     loss, new_params, new_states, aux, hvec = out
@@ -2916,12 +2919,7 @@ class DataParallelTrainer:
                     opt = self.optimizer
                     for i in self._tr_idx:
                         opt._update_count(i)
-                    scalar_vals = []
-                    for i in self._tr_idx:
-                        t = opt._index_update_count[i]
-                        scalar_vals.extend(
-                            np.asarray(sv, dtype=np.float32)
-                            for sv in self._rule.scalars(opt, i, t))
+                    scalar_vals = self._step_scalars()
                     # ZeRO subsumes the int8 compressed exchange (the
                     # quantized reduce lives on its gradient leg), so the
                     # compressed builder/call-shape only applies at stage 0
@@ -2985,7 +2983,7 @@ class DataParallelTrainer:
                             "_compressed",
                             getattr(self, "_compressed_fn", None),
                             (param_vals, self._state_vals(),
-                             tuple(scalar_vals), x_vals, y_val,
+                             scalar_vals, x_vals, y_val,
                              key._data, self._residual_vals or ())
                             + hextra, compressed=True, program="")
 
@@ -3001,12 +2999,12 @@ class DataParallelTrainer:
                     if compressed:
                         return self._full_step(
                             param_vals, self._state_vals(),
-                            tuple(scalar_vals), x_vals, y_val,
+                            scalar_vals, x_vals, y_val,
                             key._data, self._residual_vals or (),
                             *hextra)
                     return self._dispatch_full(
                         (param_vals, self._state_vals(),
-                         tuple(scalar_vals), x_vals, y_val,
+                         scalar_vals, x_vals, y_val,
                          key._data) + hextra)
 
                 try:
@@ -3082,19 +3080,13 @@ class DataParallelTrainer:
                 opt._update_count(i)
             if self._fused_update is None:
                 self._build_fused_update()
-            scalar_vals = []
-            for i in self._tr_idx:
-                t = opt._index_update_count[i]
-                scalar_vals.extend(
-                    np.asarray(s, dtype=np.float32)
-                    for s in self._rule.scalars(opt, i, t))
             from .. import engine as _eng
             _san_hook = _eng._san
             tparam_vals = tuple(
                 self._params[i].data()._data for i in self._tr_idx)
             tstate_vals = self._state_vals()
             new_params, new_states = self._fused_update(
-                tparam_vals, tstate_vals, grads, tuple(scalar_vals))
+                tparam_vals, tstate_vals, grads, self._step_scalars())
             if _san_hook is not None:
                 # mxsan: donate_argnums=(0, 1) consumed the params and
                 # optimizer state — shadow-mark them so a stale

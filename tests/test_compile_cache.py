@@ -599,6 +599,68 @@ def test_spmd_warm_start_records_and_checks_mesh(cache_dir, tmp_path):
     np.testing.assert_array_equal(l1, l5)
 
 
+@pytest.mark.parametrize("variant", ["k0", "k3"])
+def test_spmd_warm_start_round_trips_the_scalar_vector(
+        cache_dir, tmp_path, variant):
+    """The manifest records the optimizer scalars as ONE row, the
+    ``(S,)`` vector of ``step()`` or the ``(K, S)`` stack of
+    ``step_multi``; a fresh trainer warm-started from it pays no
+    compile on its first step."""
+    X, Y = _batch()
+    Xk, Yk = (nd.array(np.stack([a.asnumpy()] * 3)) for a in (X, Y))
+
+    def first_step(dpt):
+        if variant == "k0":
+            return dpt.step(X, Y).asnumpy()
+        return dpt.step_multi(Xk, Yk).asnumpy()
+
+    net, dpt = _spmd(f"cc_vec_{variant}_a_")
+    l1 = first_step(dpt)
+    manifest = str(tmp_path / "spmd.json")
+    dpt.save_signature(manifest)
+    row, = json.loads(open(manifest).read())["variants"]
+    n_scalars = 2 * len(dpt._tr_idx)               # Adam: lr, wd
+    assert row["scalars"] == [[[n_scalars] if variant == "k0"
+                               else [3, n_scalars], "float32"]]
+
+    _restart()
+    net2, dpt2 = _spmd(f"cc_vec_{variant}_b_")
+    assert dpt2.warm_start(manifest) is True
+    assert _fresh_compiles() == 0
+    np.testing.assert_array_equal(first_step(dpt2), l1)
+    assert _fresh_compiles() == 0
+
+
+def test_spmd_warm_start_refuses_one_leaf_per_scalar_manifest(
+        cache_dir, tmp_path):
+    """A manifest from before the scalars travelled as one vector
+    lists one 0-d row per scalar for the single-step variant.  It
+    fails open: a failure record, nothing pre-compiled for a call
+    shape no step makes, and the next step runs cold and correct."""
+    X, Y = _batch()
+    net, dpt = _spmd("cc_old_a_")
+    l1 = dpt.step(X, Y).asnumpy()
+    manifest = str(tmp_path / "spmd.json")
+    dpt.save_signature(manifest)
+    m = json.loads(open(manifest).read())
+    (n_scalars,), dtype = m["variants"][0]["scalars"][0]
+    m["variants"][0]["scalars"] = [[[], dtype]] * n_scalars
+    old = str(tmp_path / "spmd_old.json")
+    open(old, "w").write(json.dumps(m))
+
+    _restart()
+    telemetry.reset()
+    net2, dpt2 = _spmd("cc_old_b_")
+    assert dpt2.warm_start(old) is False
+    ev = telemetry.events("warm_start")[-1]
+    assert ev["ok"] is False and "one vector" in ev["reason"]
+    assert not dpt2.warm_started and dpt2._full_exec is None
+    assert _fresh_compiles() == 0
+    np.testing.assert_array_equal(dpt2.step(X, Y).asnumpy(), l1)
+    np.testing.assert_array_equal(dpt2.step(X, Y).asnumpy(),
+                                  dpt.step(X, Y).asnumpy())
+
+
 def test_spmd_warm_start_batchnorm_aux(cache_dir, tmp_path):
     """The SPMD twin of the gluon BN test: a persist hit never traces,
     so the manifest's mutated_idx must survive _build_fwd_bwd's list
