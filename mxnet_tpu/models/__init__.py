@@ -11,6 +11,9 @@ from . import ssd
 from .ssd import SSD, ssd_tiny, MultiBoxLoss
 from .llama import (LlamaModel, LlamaForCausalLM, get_llama,
                     llama_tiny, llama3_8b)
+from . import sambay
+from .sambay import (SambaYModel, SambaYForCausalLM, get_sambay,
+                     sambay_tiny, phi4_mini_flash)
 from . import hf_loader
 from .hf_loader import (read_safetensors, write_safetensors,
                         load_hf_llama, export_hf_llama,
@@ -36,7 +39,9 @@ __all__ = ["hf_loader", "read_safetensors", "write_safetensors",
            "bert", "BERTModel", "BERTForPretrain", "bert_base",
            "bert_small", "bert_large", "get_bert", "forecast",
            "DeepAR", "TransformerForecaster", "llama", "LlamaModel",
-           "LlamaForCausalLM", "get_llama", "llama_tiny", "llama3_8b",
+           "LlamaForCausalLM", "get_llama", "llama_tiny", "llama3_8b", "sambay", "SambaYModel",
+           "SambaYForCausalLM", "get_sambay", "sambay_tiny",
+           "phi4_mini_flash",
            "nmt", "TransformerNMT", "BeamSearchScorer",
            "BeamSearchSampler", "get_nmt", "nmt_tiny",
            "transformer_en_de_512", "segmentation", "FCN", "DeepLabV3",

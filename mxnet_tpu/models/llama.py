@@ -299,7 +299,8 @@ class LlamaForCausalLM(HybridBlock):
 
     def init_cache(self, batch_size, max_len, ctx=None, rolling=False,
                    dtype="float32"):
-        """Preallocate per-layer KV caches (B, C, KV, D).
+        """Preallocate the KV caches (B, C, KV, D): one flat list ``[k0,
+        v0, k1, v1, ...]``, the order of ``state_spec``.
 
         ``rolling=True`` (sliding-window models only) allocates the
         Mistral rolling buffer: C = min(sliding_window, max_len), so
@@ -314,13 +315,29 @@ class LlamaForCausalLM(HybridBlock):
         from .. import ndarray as nd
         self._check_cache_dtype(dtype)
         cache_len = self._rolling_cache_len(max_len, rolling)
-        caches = []
-        for layer in self.model.layers:
-            a = layer.attn
-            shp = (batch_size, cache_len, a._kv, a._d)
-            caches.append((nd.zeros(shp, ctx=ctx, dtype=dtype),
-                           nd.zeros(shp, ctx=ctx, dtype=dtype)))
-        return caches
+        return [nd.zeros(shape, ctx=ctx, dtype=dt) for _n, _k, shape, dt
+                in self.state_spec(batch_size, cache_len, dtype)]
+
+    def state_spec(self, slots, cache_len, dtype="float32"):
+        """What the serving plane's pool holds for this model
+        (docs/serving.md, "State kinds"): one K and one V page a layer,
+        ``(name, kind, shape, dtype)`` rows in the flat order ``[k0, v0,
+        k1, v1, ...]`` that ``prefill`` / ``decode_step`` take.
+        A sliding-window model's pages are full length (the band is a
+        mask), so the kind only says which mask reads them."""
+        self._check_cache_dtype(dtype)
+        kind = "kv_full" if self.model.sliding_window is None \
+            else "kv_window"
+        rows = []
+        for i, layer in enumerate(self.model.layers):
+            shp = (slots, cache_len, layer.attn._kv, layer.attn._d)
+            rows += [(f"layer{i}_k", kind, shp, str(dtype)),
+                     (f"layer{i}_v", kind, shp, str(dtype))]
+        return rows
+
+    def _layer_caches(self, caches):
+        """(layer, K page, V page) over the flat ``state_spec`` list."""
+        return zip(self.model.layers, caches[0::2], caches[1::2])
 
     def _head(self, h):
         """LM-head projection shared by full-forward and decode paths."""
@@ -339,12 +356,14 @@ class LlamaForCausalLM(HybridBlock):
         the logits at each row's OWN last real token instead of the
         final position — the right-padded bucket-prompt shape the
         serving plane feeds (pad rows beyond ``last_pos`` stay causal
-        garbage that the decode-time validity mask never exposes)."""
+        garbage that the decode-time validity mask never exposes: true
+        of K/V pages only; a model with recurrent state must stop at
+        ``last_pos``, as ``models/sambay.py`` does)."""
         import numpy as np
         from .. import ndarray as nd
         x = self.model.embed(tokens)
         s = tokens.shape[1]
-        c = caches[0][0].shape[1]
+        c = caches[0].shape[1]
         perm = None
         if s > c:
             # rolling buffer shorter than the prompt: slot j holds the
@@ -354,7 +373,7 @@ class LlamaForCausalLM(HybridBlock):
             perm = nd.array(
                 (start + (np.arange(c) - start) % c).astype("f4"),
                 ctx=tokens.context)
-        for layer, (ck, cv) in zip(self.model.layers, caches):
+        for layer, ck, cv in self._layer_caches(caches):
             x = layer.prefill(x, ck, cv, perm=perm)
         h = self.model.final_norm(x)
         if last_pos is None:
@@ -383,7 +402,7 @@ class LlamaForCausalLM(HybridBlock):
         # key-validity mask (pos <= offset), shared across all layers;
         # offset rides the dynamic-scalar path (nd.full would bake it
         # into static attrs and compile a fresh program per step)
-        max_len = caches[0][0].shape[1]
+        max_len = caches[0].shape[1]
         # build the mask on the token's device: the default ctx is
         # cpu(0), and a host mask would not mix with chip arrays in one
         # program.  offset may be a python number (the
@@ -417,7 +436,7 @@ class LlamaForCausalLM(HybridBlock):
                 # prefill kernels apply
                 mask = mask * (pos > off - float(w))
         mask = mask.reshape((1, 1, 1, max_len))
-        for layer, (ck, cv) in zip(self.model.layers, caches):
+        for layer, ck, cv in self._layer_caches(caches):
             x = layer.step(x, ck, cv, offset, mask, slot=slot)
         h = self.model.final_norm(x)
         return self._head(h)
@@ -443,7 +462,7 @@ class LlamaForCausalLM(HybridBlock):
             if w is not None:
                 mask = mask * (posr > offv - float(w))
         mask = mask.reshape((b, 1, 1, max_len))
-        for layer, (ck, cv) in zip(self.model.layers, caches):
+        for layer, ck, cv in self._layer_caches(caches):
             x = layer.step(x, ck, cv, off, mask, slot=slot)
         h = self.model.final_norm(x)
         return self._head(h)
@@ -519,9 +538,7 @@ class LlamaForCausalLM(HybridBlock):
         # identical rows
         caches_b = self.init_cache(b, max_len, ctx=tokens.context)
         self.prefill(tokens, caches_b)
-        caches = [(nd.repeat(ck, repeats=k, axis=0),
-                   nd.repeat(cv, repeats=k, axis=0))
-                  for ck, cv in caches_b]
+        caches = [nd.repeat(c, repeats=k, axis=0) for c in caches_b]
         last = nd.repeat(tokens[:, -1:], repeats=k, axis=0)
 
         def decoder(tok, step_idx, states):
@@ -586,12 +603,8 @@ class LlamaForCausalLM(HybridBlock):
         kk = min(int(top_k), self.model.vocab_size) \
             if (top_k and sample) else 0
 
-        self._check_cache_dtype(cache_dtype)
-        cache_len = self._rolling_cache_len(max_len, rolling)
-        cache_shapes = []
-        for layer in self.model.layers:
-            a = layer.attn
-            cache_shapes.append((b, cache_len, a._kv, a._d))
+        spec = self.state_spec(
+            b, self._rolling_cache_len(max_len, rolling), cache_dtype)
 
         key = (b, s, max_new_tokens, sample, kk, rolling,
                str(cache_dtype), str(tokens.dtype))
@@ -606,11 +619,8 @@ class LlamaForCausalLM(HybridBlock):
                     # dtype (a FLOAT dtype — int tokens once leaked
                     # int32 caches here, truncating every K/V write;
                     # bf16 halves decode cache bandwidth)
-                    cdt = jnp.dtype(cache_dtype)
-                    shells = [
-                        (NDArray(jnp.zeros(shp, cdt), ctx=ctx),
-                         NDArray(jnp.zeros(shp, cdt), ctx=ctx))
-                        for shp in cache_shapes]
+                    shells = [NDArray(jnp.zeros(shp, jnp.dtype(dt)),
+                                      ctx=ctx) for _n, _k, shp, dt in spec]
                     toks = NDArray(tok_val, ctx=ctx)
                     logits0 = self.prefill(toks, shells)._data
 
@@ -626,18 +636,13 @@ class LlamaForCausalLM(HybridBlock):
                     def body(carry, _):
                         tok, off, k, flat = carry
                         k, sub = jax.random.split(k)
-                        cshells = [
-                            (NDArray(flat[2 * i], ctx=ctx),
-                             NDArray(flat[2 * i + 1], ctx=ctx))
-                            for i in range(len(cache_shapes))]
+                        cshells = [NDArray(c, ctx=ctx) for c in flat]
                         lg = self.decode_step(
                             NDArray(tok, ctx=ctx), cshells,
                             NDArray(off, ctx=ctx))._data
                         nxt = pick(lg, sub).astype(tok.dtype)
                         nxt = nxt.reshape((b, 1))
-                        new_flat = tuple(
-                            shell._data for pair in cshells
-                            for shell in pair)
+                        new_flat = tuple(c._data for c in cshells)
                         return (nxt, off + 1.0, k, new_flat), \
                             nxt[:, 0]
 
@@ -645,8 +650,7 @@ class LlamaForCausalLM(HybridBlock):
                     k0, sub0 = jax.random.split(k0)
                     first = pick(logits0, sub0).astype(
                         tok_val.dtype).reshape((b, 1))
-                    flat0 = tuple(shell._data for pair in shells
-                                  for shell in pair)
+                    flat0 = tuple(c._data for c in shells)
                     off0 = jnp.asarray(float(s), jnp.float32)
                     (_, _, _, _), toks_out = lax.scan(
                         body, (first, off0, k0, flat0), None,

@@ -12,4 +12,5 @@ from . import optimizer_ops  # noqa: F401
 from . import attention  # noqa: F401  (fused SDPA + contrib transformer)
 from . import det     # noqa: F401  (roi_align / box_nms / box_iou)
 from . import moe     # noqa: F401  (expert-parallel MoE FFN)
+from . import ssm     # noqa: F401  (selective scan + causal conv)
 from . import quantization_ops  # noqa: F401  (int8 quantize family)

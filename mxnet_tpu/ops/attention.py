@@ -16,6 +16,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from .registry import register
 
@@ -168,6 +169,65 @@ def dot_product_attention(query, key, value, *rest, num_heads=1,
         return flash_attention(query, key, value, kmask=kmask, scale=s,
                                causal=causal, window=window)
     return _sdpa_xla(query, key, value, mask, s, causal, window=window)
+
+
+@register("_diff_attention", num_inputs=None)
+def diff_attention(query, key, value, lam, gamma, *rest, lambda_init=0.8,
+                   causal=False, window=None, use_mask=False, eps=1e-5):
+    """Differential attention (arXiv:2410.05258) with grouped K/V.
+
+    query (B, Sq, H, d), key/value (B, Sk, KV, d), H and KV even.  Query
+    heads ``2j, 2j+1`` are pair ``j``; K/V heads ``2c, 2c+1`` are K/V pair
+    ``c``, shared by the ``H / KV`` query pairs ``j`` with ``j // (H / KV)
+    == c``.  ``A_i = softmax(Q_i K_i^T / sqrt(d) + mask)``, ``O = (A_1 -
+    lam A_2) [V_1 ; V_2]`` (width 2d), then ``RMSNorm_2d(O) * gamma * (1 -
+    lambda_init)``.  ``lam`` is the layer's scalar, shape (1,); ``gamma``
+    (2d,).  Mask: ``causal`` (optionally banded by ``window``: position i
+    sees (i - window, i]) and/or a boolean key mask (B, 1, 1, Sk) as a
+    sixth input when ``use_mask``.  Returns (B, Sq, H * d).  Scores and
+    the softmax are float32; the two matmuls run in the inputs' dtype."""
+    mask = rest[0] if use_mask and rest else None
+    f32 = jnp.float32
+    b, s_q, h, d = query.shape
+    s_k, kv = key.shape[1], key.shape[2]
+    c, g = kv // 2, h // kv
+    q = query.reshape(b, s_q, c, g, 2, d)
+    k = key.reshape(b, s_k, c, 2, d)
+    v = value.reshape(b, s_k, c, 2 * d)
+    logits = jnp.einsum("bqcgid,bkcid->bcgiqk", q, k,
+                        preferred_element_type=f32) * f32(1.0 / np.sqrt(d))
+    keep = None
+    if causal:
+        keep = _causal_band(s_q, s_k, window)[None, None, None, None]
+    if mask is not None:
+        m = mask.astype(bool).reshape(mask.shape[0], 1, 1, 1, -1, s_k)
+        keep = m if keep is None else keep & m
+    if keep is not None:
+        logits = jnp.where(keep, logits, f32(-1e30))
+    probs = jax.nn.softmax(logits, axis=-1)
+    diff = probs[:, :, :, 0] - lam.astype(f32).reshape(()) * probs[:, :, :, 1]
+    out = jnp.einsum("bcgqk,bkce->bqcge", diff.astype(v.dtype), v,
+                     preferred_element_type=f32)
+    out = out * lax.rsqrt(jnp.mean(jnp.square(out), axis=-1, keepdims=True)
+                          + f32(eps))
+    out = out * gamma.astype(f32) * f32(1.0 - lambda_init)
+    return out.reshape(b, s_q, h * d).astype(query.dtype)
+
+
+@register("_rolling_window_fill", num_inputs=2)
+def rolling_window_fill(kv, last_pos, *, length=1):
+    """The rolling K or V buffer a right-padded prompt leaves behind.
+
+    kv (B, S, KV, d), last_pos (B,).  Returns (B, length, KV, d) whose slot
+    ``j`` holds the newest position ``p <= last_pos`` with ``p = j (mod
+    length)``, which is where the decode step's ``offset % length`` write
+    expects it; a slot whose position would be negative holds position 0's
+    row, which the decode mask (slot <= offset) never exposes."""
+    c = int(length)
+    lp = last_pos.astype(jnp.int32).reshape(-1, 1)
+    j = jnp.arange(c, dtype=jnp.int32)[None, :]
+    p = jnp.maximum(lp - jnp.mod(lp - j, c), 0)                 # (B, C)
+    return jnp.take_along_axis(kv, p[:, :, None, None], axis=1)
 
 
 def _flash_preferred(s_q, s_k, batch=1, heads=1, causal=False):

@@ -530,6 +530,15 @@ def rms_norm(data, gamma, *, axis=-1, eps=1e-6):
     return data * lax.rsqrt(ms + eps) * gamma
 
 
+@register("_head_logits", num_inputs=2)
+def head_logits(hidden, weight):
+    """LM head over a tied or untied (V, h) matrix: hidden (T, h) ->
+    (T, V) logits accumulated AND returned in float32 (a bfloat16 logit
+    keeps 8 bits, which moves the argmax of a 200k-wide row)."""
+    return jnp.einsum("th,vh->tv", hidden, weight,
+                      preferred_element_type=jnp.float32)
+
+
 @register("InstanceNorm", num_inputs=3)
 def instance_norm(data, gamma, beta, *, eps=1e-3):
     axes = tuple(range(2, data.ndim))

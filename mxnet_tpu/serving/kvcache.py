@@ -1,46 +1,81 @@
-"""KV-cache plane: preallocated per-slot K/V pages as DONATED carry
-state (docs/serving.md).
+"""State plane: preallocated per-slot state buffers as DONATED carry
+state (docs/serving.md, "State kinds").
 
-A bucket's caches are one NDArray pair per transformer layer, shaped
-``(slots, cache_len, kv_heads, head_dim)`` — slot ``j`` is request
-``j``'s page.  Every decode dispatch donates the whole pool to the
-compiled program (the PR 2/3 donation protocol): the executable writes
-each active slot's new K/V in place and returns the successor buffers,
-so a decode step never doubles cache HBM.  ``adopt()`` swaps the
+A bucket's state is the flat list of buffers the model's
+``state_spec(slots, cache_len, dtype)`` names: rows ``(name, kind, shape,
+dtype)`` with ``shape[0] == slots``, any rank, any dtype.  Slot ``j`` of
+every buffer is request ``j``'s.  ``kind`` says what a buffer is (it
+labels gauges and manifests; the shape is what the programs use):
+
+* ``kv_full``   K or V of a full-attention layer, ``(slots, cache_len,
+  kv_heads, head_dim)``: grows with the request;
+* ``kv_window`` K or V of a sliding-window layer, as long as the model's
+  window asks (a rolling buffer, or a full page behind a banded mask);
+* ``ssm``       a recurrent state, ``(slots, d_state, d_inner)``;
+* ``conv``      the tail of inputs a causal convolution continues from.
+
+A decoder of identical attention layers declares two ``kv_*`` buffers a
+layer; a hybrid declares what each layer kind holds, and nothing for a
+layer that reads another layer's buffers.  Every dispatch donates the
+whole pool to the compiled program (the PR 2/3 donation protocol): the
+executable updates each active slot in place and returns the successor
+buffers, so a decode step never doubles state HBM.  ``adopt()`` swaps the
 successors in; a dispatch that fails AFTER the donation consumed the
-buffers latches ``poisoned`` (the pool holds dead arrays) and
-``reset()`` — driven by ``Server.recover()`` — rebuilds zeroed pages.
+buffers latches ``poisoned`` (the pool holds dead arrays) and ``reset()``
+— driven by ``Server.recover()`` — rebuilds zeroed buffers.
 
 Slot lifecycle is content-swap only: admission scatters a freshly
-prefilled page into slot ``j`` (one ``lax.dynamic_update_slice`` per
-layer inside the admit program), eviction just drops the slot's
-active-mask bit on the host.  Shapes never change, so steady state
-retraces NOTHING (docs/serving.md, "Bucket anatomy").
+prefilled batch-1 state into slot ``j`` (one ``lax.dynamic_update_slice``
+per buffer, at the buffer's own rank, inside the admit program), eviction
+just drops the slot's active-mask bit on the host.  Shapes never change,
+so steady state retraces NOTHING (docs/serving.md, "Bucket anatomy").
 """
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+import math
+from typing import List, Optional
 
 from ..base import MXNetError
 
-__all__ = ["KVCachePool"]
+__all__ = ["KVCachePool", "STATE_KINDS", "check_spec"]
+
+STATE_KINDS = ("kv_full", "kv_window", "ssm", "conv")
+
+
+def check_spec(spec, slots: int):
+    """Normalize a model's ``state_spec`` rows to ``(name, kind, shape,
+    dtype)`` tuples of plain types and hold them to the contract."""
+    rows = []
+    for name, kind, shape, dtype in spec:
+        shape = tuple(int(x) for x in shape)
+        if kind not in STATE_KINDS:
+            raise MXNetError(f"state buffer {name!r}: kind {kind!r} is not "
+                             f"one of {STATE_KINDS}")
+        if not shape or shape[0] != slots:
+            raise MXNetError(f"state buffer {name!r}: shape {shape} must "
+                             f"lead with the slot dim {slots}")
+        rows.append((str(name), str(kind), shape, str(dtype)))
+    if not rows:
+        raise MXNetError("the model's state_spec names no buffer")
+    return rows
 
 
 class KVCachePool:
-    """Per-bucket preallocated K/V pages for ``slots`` concurrent
-    requests over ``lm``'s layers.
+    """Per-bucket preallocated state for ``slots`` concurrent requests of
+    ``lm``, built from ``lm.state_spec``.
 
     Args:
-      lm: a ``models.LlamaForCausalLM`` (anything with ``init_cache``).
+      lm: a model-zoo decoder (anything with ``state_spec``).
       slots: concurrent requests the pool holds (the bucket batch dim).
       cache_len: positions per slot (bucket prompt length + the
         server's max new tokens).
-      ctx: device context for the pages.
+      ctx: device context for the buffers.
       dtype: cache dtype (float; ``bfloat16`` halves page HBM and
-        decode bandwidth — ``init_cache`` validates).
-      sharding: optional ``jax.sharding.NamedSharding`` for the pages
+        decode bandwidth — ``state_spec`` validates, and may keep a
+        buffer in a type of its own: a recurrent state stays float32).
+      sharding: optional ``jax.sharding.NamedSharding`` for the buffers
         — the sharding planner's decode spec (``ShardingPlan.decode``,
-        typically the slot dim over ``dp``).  Applied after EVERY page
+        typically the slot dim over ``dp``).  Applied after EVERY
         build (construction AND :meth:`reset`), so a recovery can
         never silently drop the planned layout.
     """
@@ -51,71 +86,79 @@ class KVCachePool:
             raise MXNetError(
                 f"KVCachePool needs slots >= 1 and cache_len >= 1, got "
                 f"{slots}/{cache_len}")
-        self._lm = lm
         self.slots = int(slots)
         self.cache_len = int(cache_len)
         self.ctx = ctx
         self.dtype = str(dtype)
         self.sharding = sharding
         self.poisoned: Optional[str] = None
-        self._pairs: List[Tuple] = self._build_pages()
+        self.spec = check_spec(
+            lm.state_spec(self.slots, self.cache_len, self.dtype),
+            self.slots)
+        self._bufs: List = self._build()
 
-    def _build_pages(self):
-        pairs = self._lm.init_cache(
-            self.slots, self.cache_len, ctx=self.ctx, dtype=self.dtype)
+    def _build(self):
+        from .. import ndarray as nd
+        bufs = [nd.zeros(shape, ctx=self.ctx, dtype=dtype)
+                for _name, _kind, shape, dtype in self.spec]
         if self.sharding is not None:
             import jax
-            for k, v in pairs:
-                k._set_data(jax.device_put(k._data, self.sharding))
-                v._set_data(jax.device_put(v._data, self.sharding))
-        return pairs
+            for b in bufs:
+                b._set_data(jax.device_put(b._data, self.sharding))
+        return bufs
 
     @property
-    def num_layers(self) -> int:
-        return len(self._pairs)
+    def num_buffers(self) -> int:
+        return len(self._bufs)
 
-    def pairs(self):
-        """The live per-layer ``(K, V)`` NDArray pairs."""
-        return list(self._pairs)
+    def buffers(self) -> list:
+        """The live state NDArrays, in spec order."""
+        return list(self._bufs)
 
     def flat(self) -> list:
-        """Flat jax buffers ``[k0, v0, k1, v1, ...]`` in donate order —
-        exactly the slice of the dispatch argument list the donate
-        tuple names."""
-        return [s._data for pair in self._pairs for s in pair]
+        """Flat jax buffers in spec (= donate) order — exactly the slice
+        of the dispatch argument list the donate tuple names."""
+        return [b._data for b in self._bufs]
 
     def nbytes(self) -> int:
-        return sum(int(s._data.nbytes) for pair in self._pairs
-                   for s in pair)
+        return sum(int(b._data.nbytes) for b in self._bufs)
+
+    def bytes_by_kind(self) -> dict:
+        """{kind: bytes}, from the spec (what the buffers hold, not how a
+        device tiles them)."""
+        import jax.numpy as jnp
+        out = {}
+        for _name, kind, shape, dtype in self.spec:
+            out[kind] = out.get(kind, 0) \
+                + math.prod(shape) * jnp.dtype(dtype).itemsize
+        return out
 
     def adopt(self, new_flat):
         """Swap the post-dispatch successor buffers in (the donated
         predecessors are already dead)."""
-        if len(new_flat) != 2 * len(self._pairs):
+        if len(new_flat) != len(self._bufs):
             raise MXNetError(
-                f"adopt: expected {2 * len(self._pairs)} cache buffers, "
+                f"adopt: expected {len(self._bufs)} state buffers, "
                 f"got {len(new_flat)}")
-        for i, (k, v) in enumerate(self._pairs):
-            k._set_data(new_flat[2 * i])
-            v._set_data(new_flat[2 * i + 1])
+        for b, new in zip(self._bufs, new_flat):
+            b._set_data(new)
 
     def poison(self, error: str):
-        """Latch the post-donation-failure state: the pages were
+        """Latch the post-donation-failure state: the buffers were
         consumed by a dispatch that died, so nothing here is
         dispatchable until :meth:`reset`."""
         self.poisoned = error
 
     def consumed(self) -> bool:
-        """Did a dispatch actually consume the pages?  (Distinguishes
+        """Did a dispatch actually consume the buffers?  (Distinguishes
         post-donation failures — dead buffers — from pre-dispatch
         trace/compile errors that left everything alive.)"""
-        return any(
-            getattr(s._data, "is_deleted", lambda: False)()
-            for pair in self._pairs for s in pair)
+        return any(getattr(b._data, "is_deleted", lambda: False)()
+                   for b in self._bufs)
 
     def reset(self):
-        """Rebuild zeroed pages and clear the poison latch (the
+        """Rebuild zeroed buffers and clear the poison latch (the
         recovery half of the donation protocol — every resident
         request must be re-prefilled by the caller)."""
-        self._pairs = self._build_pages()
+        self._bufs = self._build()
         self.poisoned = None

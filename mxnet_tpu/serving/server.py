@@ -3,12 +3,16 @@
 One compiled PREFILL program and one compiled DECODE program per
 ``(slots, prompt_len)`` bucket (plus ``decode_multi(K)`` lax.scan
 variants), all dispatched through ``engine.invoke_compiled`` with the
-bucket's KV-cache pool DONATED:
+bucket's state pool DONATED.  What a slot holds is the model's to say
+(``lm.state_spec``: K/V pages, rolling windows, recurrent and conv
+state, any rank and dtype; docs/serving.md, "State kinds"); the server
+moves the buffers and never looks inside a layer:
 
-* **admit** — prefill one right-padded prompt at batch 1, scatter the
-  resulting K/V page into the pool at the assigned slot
-  (``lax.dynamic_update_slice`` per layer), and sample the first token
-  at the prompt's own last position — ONE dispatch per admission;
+* **admit** — prefill one right-padded prompt at batch 1 into a batch-1
+  state, scatter every buffer of it into the pool at the assigned slot
+  (``lax.dynamic_update_slice`` per buffer, at its own rank), and sample
+  the first token at the prompt's own last position — ONE dispatch per
+  admission;
 * **decode** — every active slot advances one token in lockstep at its
   OWN absolute position (per-slot rope offsets / cache scatter /
   validity mask ride as dynamic inputs), the sampler picks
@@ -51,7 +55,7 @@ import numpy as np
 
 from ..base import MXNetError
 from ..profiler import span as _span
-from .kvcache import KVCachePool
+from .kvcache import KVCachePool, check_spec
 from .scheduler import ACTIVE, BucketScheduler, Request
 
 __all__ = ["Server", "servers"]
@@ -85,9 +89,10 @@ def _default_buckets():
 
 
 class Server:
-    """Continuously batched serving over a ``LlamaForCausalLM``-shaped
-    model (anything exposing ``init_cache``/``prefill``/``decode_step``
-    — the model-zoo decoder contract).
+    """Continuously batched serving over a model-zoo decoder: anything
+    exposing ``state_spec``/``prefill``/``decode_step`` over a flat state
+    list and ``model.vocab_size`` (``LlamaForCausalLM``,
+    ``SambaYForCausalLM``).
 
     Args:
       lm: initialized causal LM.
@@ -177,6 +182,7 @@ class Server:
                 lm, b.slots, b.cache_len, ctx=self.ctx,
                 dtype=self.cache_dtype,
                 sharding=self._decode_sharding)
+        self._state_gauges()
         if plan is not None:
             # the planner registry (MXL313 coverage audit + mxplan):
             # the serving leg registers its resolved param tree too
@@ -210,14 +216,18 @@ class Server:
         to hash INSTEAD of the live ones — the resize pre-warm keys
         the target configuration's persist identities while the old
         buckets still serve."""
-        rows = buckets if buckets is not None else \
+        rows = sorted(tuple(r) for r in (
+            buckets if buckets is not None else
             [(b.slots, b.prompt_len, b.cache_len)
-             for b in self.sched.buckets]
+             for b in self.sched.buckets]))
         parts = (
             tuple((tuple(p.data(self.ctx).shape),
                    str(p.data(self.ctx).dtype))
                   for p in self.lm.collect_params().values()),
-            tuple(sorted(tuple(r) for r in rows)),
+            tuple(rows),
+            # what a slot holds: the compiled programs' state avals
+            tuple(tuple(self._spec_for(slots, cache_len))
+                  for slots, _p, cache_len in rows),
             self._kk, self.cache_dtype, self.max_new_tokens,
             int(self.lm.model.vocab_size)) + (
                 # the plan pin: decode sharding is baked into the
@@ -227,6 +237,27 @@ class Server:
                 (self.plan.struct_hash(),)
                 if self.plan is not None else ())
         return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
+
+    def _spec_for(self, slots, cache_len):
+        return check_spec(
+            self.lm.state_spec(slots, cache_len, self.cache_dtype), slots)
+
+    def _n_state(self, bucket):
+        """Buffers in a bucket's state, from the spec: a resize's shadow
+        bucket has programs before it has a pool."""
+        return len(self._spec_for(bucket.slots, bucket.cache_len))
+
+    def _state_gauges(self):
+        """``mxtpu_serving_state_bytes`` per bucket and state kind (the
+        registry has no labels: they ride in the name)."""
+        from .. import telemetry
+        for b in self.sched.buckets:
+            for kind, n in self._pools[b.key].bytes_by_kind().items():
+                telemetry.gauge(
+                    f"mxtpu_serving_state_bytes_b{b.slots}x"
+                    f"{b.prompt_len}_{kind}",
+                    "bytes of one bucket's state buffers of one kind"
+                    ).set(n)
 
     # -- public API -------------------------------------------------------
     def submit(self, prompt, max_new_tokens: Optional[int] = None,
@@ -498,8 +529,8 @@ class Server:
           scheduling rounds nothing is in flight; this is the settled
           boundary (fault point ``resize_drain``) and where the
           downtime clock starts.
-        * **migrate** — resident K/V pages gather into the new pool by
-          slot index (one ``take`` per page tensor; generated tokens/
+        * **migrate** — resident state gathers into the new pool by
+          slot index (one ``take`` per state buffer; generated tokens/
           offsets are host-owned and ride along), so live requests
           keep their progress.  On a shrink, residents beyond the new
           capacity are evicted-with-requeue (they replay from their
@@ -564,16 +595,16 @@ class Server:
                     continue
                 nb = shadow[b.key]
                 kind, k = str(v["kind"]), int(v.get("k") or 0)
-                L2 = 2 * self._pools[b.key].num_layers
+                NS = self._pools[b.key].num_buffers
                 avals = list(engine.persist.sig_from_json(v["avals"]))
                 for i, a in enumerate(avals):
-                    # the slot dim is dim 0 of every cache page and —
+                    # the slot dim is dim 0 of every state buffer and —
                     # for decode — of the 4 per-slot extras (tok/off/
                     # active/temp); everything else (params, prefill
                     # extras, the RNG key) is slot-count-independent
-                    per_slot = (P <= i < P + L2) or (
+                    per_slot = (P <= i < P + NS) or (
                         kind == "decode" and
-                        P + L2 <= i < P + L2 + 4)
+                        P + NS <= i < P + NS + 4)
                     if per_slot and len(a) == 2 and a[0]:
                         avals[i] = ((new_slots,) + tuple(a[0][1:]),
                                     a[1])
@@ -744,6 +775,7 @@ class Server:
         self._struct_hash = new_hash
         self._persist_base = new_base
         self._persist_pinned = False
+        self._state_gauges()
         rec = {
             "kind": "serving", "name": self.name,
             "slots_from": old_slots, "slots_to": new_slots,
@@ -806,7 +838,8 @@ class Server:
             "top_k": self._kk, "cache_dtype": self.cache_dtype,
             "buckets": [
                 {"slots": b.slots, "prompt_len": b.prompt_len,
-                 "cache_len": b.cache_len}
+                 "cache_len": b.cache_len,
+                 "state": [list(r) for r in self._pools[b.key].spec]}
                 for b in self.sched.buckets],
             "variants": [self._variants[k]
                          for k in sorted(self._variants)],
@@ -870,6 +903,16 @@ class Server:
         if want != have:
             return _fail(f"bucket mismatch: manifest {want} vs "
                          f"configured {have}")
+        for row in m.get("buckets", ()):
+            pool = self._pools[(row["slots"], row["prompt_len"])]
+            mine = json.loads(json.dumps(pool.spec))    # tuples -> lists
+            theirs = row.get("state") or []
+            if theirs != mine:
+                a, b = next(ab for ab in itertools.zip_longest(theirs, mine)
+                            if ab[0] != ab[1])
+                return _fail(
+                    f"state spec mismatch in bucket {row['slots']}x"
+                    f"{row['prompt_len']}: manifest {a} vs configured {b}")
         if self._poisoned is not None:
             return _fail("server is poisoned")
         try:
@@ -955,7 +998,7 @@ class Server:
     def _make_decode(self, bucket):
         lm, ctx = self.lm, self.ctx
         params = self._param_nds
-        P, L = len(params), len(lm.model.layers)
+        P, NS = len(params), self._n_state(bucket)
         N = bucket.slots
 
         def decode_pure(*flat):
@@ -964,17 +1007,13 @@ class Server:
             from ..gluon import block as block_mod
             from ..ndarray.ndarray import NDArray
             param_vals = list(flat[:P])
-            cache_vals = flat[P:P + 2 * L]
-            tok, off, active, temp, key_raw = flat[P + 2 * L:]
+            tok, off, active, temp, key_raw = flat[P + NS:]
             with block_mod.tracing_scope(params, param_vals):
-                shells = [(NDArray(cache_vals[2 * i], ctx=ctx),
-                           NDArray(cache_vals[2 * i + 1], ctx=ctx))
-                          for i in range(L)]
+                shells = [NDArray(c, ctx=ctx) for c in flat[P:P + NS]]
                 logits = lm.decode_step(
                     NDArray(tok, ctx=ctx), shells,
                     NDArray(off, ctx=ctx))._data
-                new_caches = tuple(s._data for pair in shells
-                                   for s in pair)
+                new_caches = tuple(s._data for s in shells)
             k0 = jax.random.wrap_key_data(key_raw)
             keys = jax.vmap(lambda i: jax.random.fold_in(k0, i))(
                 jnp.arange(N))
@@ -986,7 +1025,7 @@ class Server:
     def _make_decode_multi(self, bucket, k_steps: int):
         lm, ctx = self.lm, self.ctx
         params = self._param_nds
-        P, L = len(params), len(lm.model.layers)
+        P, NS = len(params), self._n_state(bucket)
         N = bucket.slots
 
         def decode_multi_pure(*flat):
@@ -996,21 +1035,18 @@ class Server:
             from ..gluon import block as block_mod
             from ..ndarray.ndarray import NDArray
             param_vals = list(flat[:P])
-            cache_vals = tuple(flat[P:P + 2 * L])
-            tok, off, active, temp, key_raw = flat[P + 2 * L:]
+            cache_vals = tuple(flat[P:P + NS])
+            tok, off, active, temp, key_raw = flat[P + NS:]
             k0 = jax.random.wrap_key_data(key_raw)
 
             def body(carry, step_i):
                 tok_c, off_c, caches = carry
                 with block_mod.tracing_scope(params, param_vals):
-                    shells = [(NDArray(caches[2 * i], ctx=ctx),
-                               NDArray(caches[2 * i + 1], ctx=ctx))
-                              for i in range(L)]
+                    shells = [NDArray(c, ctx=ctx) for c in caches]
                     logits = lm.decode_step(
                         NDArray(tok_c, ctx=ctx), shells,
                         NDArray(off_c, ctx=ctx))._data
-                    new_caches = tuple(s._data for pair in shells
-                                       for s in pair)
+                    new_caches = tuple(s._data for s in shells)
                 k_step = jax.random.fold_in(k0, step_i)
                 keys = jax.vmap(
                     lambda i: jax.random.fold_in(k_step, i))(
@@ -1031,9 +1067,9 @@ class Server:
     def _make_prefill(self, bucket):
         lm, ctx = self.lm, self.ctx
         params = self._param_nds
-        P, L = len(params), len(lm.model.layers)
-        S = bucket.prompt_len
-        cdt = self.cache_dtype
+        P, NS = len(params), self._n_state(bucket)
+        # the batch-1 state one prompt of this bucket prefills into
+        one = self._spec_for(1, bucket.prompt_len)
 
         def prefill_pure(*flat):
             import jax
@@ -1042,28 +1078,20 @@ class Server:
             from ..gluon import block as block_mod
             from ..ndarray.ndarray import NDArray
             param_vals = list(flat[:P])
-            cache_vals = flat[P:P + 2 * L]
-            prompt, last_pos, slot, temp, key_raw = flat[P + 2 * L:]
-            dt = jnp.dtype(cdt)
+            prompt, last_pos, slot, temp, key_raw = flat[P + NS:]
             with block_mod.tracing_scope(params, param_vals):
-                tmp = []
-                for layer in lm.model.layers:
-                    a = layer.attn
-                    shp = (1, S, a._kv, a._d)
-                    tmp.append((NDArray(jnp.zeros(shp, dt), ctx=ctx),
-                                NDArray(jnp.zeros(shp, dt), ctx=ctx)))
+                tmp = [NDArray(jnp.zeros(shape, jnp.dtype(dt)), ctx=ctx)
+                       for _name, _kind, shape, dt in one]
                 logits = lm.prefill(
                     NDArray(prompt, ctx=ctx), tmp,
                     last_pos=NDArray(last_pos, ctx=ctx))._data
-                tmp_flat = [s._data for pair in tmp for s in pair]
             slot_i = jnp.asarray(slot, jnp.int32)
             zero = jnp.int32(0)
-            new_caches = []
-            for i in range(2 * L):
-                c = cache_vals[i]
-                new_caches.append(lax.dynamic_update_slice(
-                    c, tmp_flat[i].astype(c.dtype),
-                    (slot_i, zero, zero, zero)))
+            new_caches = [
+                lax.dynamic_update_slice(
+                    c, t._data.astype(c.dtype),
+                    (slot_i,) + (zero,) * (c.ndim - 1))
+                for c, t in zip(flat[P:P + NS], tmp)]
             k0 = jax.random.wrap_key_data(key_raw)
             keys = jax.vmap(lambda i: jax.random.fold_in(k0, i))(
                 slot_i.reshape(1))
@@ -1092,7 +1120,7 @@ class Server:
         suffix = self._suffix(bucket, kind, k)
         pure = self._pure_for(bucket, kind, k)
         P = len(self._param_nds)
-        L2 = 2 * pool.num_layers
+        NS = pool.num_buffers
         with _span("mxtpu.serving.flatten", "serving"):
             if self._decode_sharding is not None:
                 # the planned decode mesh: params ride as the
@@ -1107,7 +1135,7 @@ class Server:
             else:
                 params_flat = [p._data for p in self._param_nds]
             flat = params_flat + pool.flat() + list(extra)
-            donate = tuple(range(P, P + L2))
+            donate = tuple(range(P, P + NS))
         name = self.name + suffix
         persist_name = self._persist_base + suffix
         m0, f0 = engine.compile_counts()
@@ -1141,8 +1169,8 @@ class Server:
                         "requests (docs/serving.md). Original error: "
                         f"{e!r}") from e
                 raise
-        with _span("mxtpu.serving.adopt", "serving"):
-            n_out = len(res) - L2
+        with _span("mxtpu.serving.state_adopt", "serving"):
+            n_out = len(res) - NS
             pool.adopt(res[n_out:])
             if suffix not in self._variants:
                 self._variants[suffix] = {

@@ -341,10 +341,8 @@ def test_prefill_matches_stepwise():
         last2 = net.decode_step(toks[:, i:i + 1], c2, i)
     np.testing.assert_allclose(last1, last2.asnumpy(), rtol=2e-4,
                                atol=2e-5)
-    for (k1, v1), (k2, v2) in zip(c1, c2):
-        np.testing.assert_allclose(k1.asnumpy(), k2.asnumpy(),
-                                   rtol=2e-4, atol=2e-5)
-        np.testing.assert_allclose(v1.asnumpy(), v2.asnumpy(),
+    for page1, page2 in zip(c1, c2):             # flat: k0, v0, k1, v1
+        np.testing.assert_allclose(page1.asnumpy(), page2.asnumpy(),
                                    rtol=2e-4, atol=2e-5)
 
 
@@ -651,9 +649,9 @@ class TestRollingCache:
     def test_cache_is_window_sized(self):
         net = self._mnet()
         caches = net.init_cache(2, 100, rolling=True)
-        assert caches[0][0].shape == (2, 32, 2, 16)   # C == W == 32
+        assert caches[0].shape == (2, 32, 2, 16)   # C == W == 32
         full = net.init_cache(2, 100)
-        assert full[0][0].shape[1] == 100
+        assert full[0].shape[1] == 100
 
     def test_rolling_requires_window(self):
         from mxnet_tpu.base import MXNetError
@@ -699,7 +697,7 @@ class TestBF16Cache:
         toks = _tokens(seed=40, b=2, s=10)
         c32 = net.init_cache(2, 10)
         c16 = net.init_cache(2, 10, dtype="bfloat16")
-        assert "bfloat16" in str(c16[0][0].dtype)
+        assert "bfloat16" in str(c16[0].dtype)
         l32 = np.stack(
             [net.decode_step(toks[:, i:i + 1], c32, i).asnumpy()
              for i in range(10)], axis=1)

@@ -615,7 +615,7 @@ def test_serving_decode_sharding_from_plan():
                  plan=plan)
     t2 = _serve(srv)
     assert t1 == t2
-    k0 = list(srv._pools.values())[0].pairs()[0][0]._data
+    k0 = list(srv._pools.values())[0].buffers()[0]._data
     assert "dp" in str(k0.sharding.spec)
     assert len(k0.sharding.device_set) == 8
     with tempfile.TemporaryDirectory() as d:
@@ -636,7 +636,7 @@ def test_serving_decode_sharding_from_plan():
     # a slot resize keeps the planned page layout (migration adopt
     # bypasses the pool's build path — review finding, regression)
     srv.resize_slots(16, reason="test")
-    k1 = list(srv._pools.values())[0].pairs()[0][0]._data
+    k1 = list(srv._pools.values())[0].buffers()[0]._data
     assert "dp" in str(k1.sharding.spec)
     assert len(k1.sharding.device_set) == 8
     # slot counts must divide the decode fan-out

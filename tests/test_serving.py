@@ -152,15 +152,13 @@ def test_model_level_row_isolation(net):
     rng = np.random.RandomState(0)
     base = net.init_cache(2, 8)
     c_zero, c_garb = [], []
-    for (k, v) in base:
-        kz, vz = k.asnumpy().copy(), v.asnumpy().copy()
-        kz[0] = rng.randn(*kz[0].shape)         # row 0: shared history
-        vz[0] = rng.randn(*vz[0].shape)
-        kg, vg = kz.copy(), vz.copy()
-        kg[1] = rng.randn(*kg[1].shape) * 1e3   # row 1: garbage
-        vg[1] = rng.randn(*vg[1].shape) * 1e3
-        c_zero.append((nd.array(kz), nd.array(vz)))
-        c_garb.append((nd.array(kg), nd.array(vg)))
+    for page in base:                           # flat: k0, v0, k1, v1
+        z = page.asnumpy().copy()
+        z[0] = rng.randn(*z[0].shape)           # row 0: shared history
+        g = z.copy()
+        g[1] = rng.randn(*g[1].shape) * 1e3     # row 1: garbage
+        c_zero.append(nd.array(z))
+        c_garb.append(nd.array(g))
     l_zero = net.decode_step(toks, c_zero, off).asnumpy()
     l_garb = net.decode_step(toks, c_garb, off).asnumpy()
     np.testing.assert_array_equal(l_zero[0], l_garb[0])
@@ -482,7 +480,7 @@ def test_round_spans_nest_and_share_the_request_id(net):
         dispatch, = named("mxtpu.serving.dispatch", parent)
         assert dispatch["args"]["kind"] == kind
         assert len(named("mxtpu.serving.flatten", dispatch)) == 1
-        assert len(named("mxtpu.serving.adopt", dispatch)) == 1
+        assert len(named("mxtpu.serving.state_adopt", dispatch)) == 1
         lookup, = named("mxtpu.engine.lookup", dispatch)
         assert len(named("mxtpu.engine.telemetry", dispatch)) == 1
         execute, = named("mxtpu.engine.execute", dispatch)
@@ -621,3 +619,214 @@ def test_mxl601_runtime_twin(net):
     assert "1x4" in found[0].message
     fs2, _ = analysis.self_check()
     assert [f for f in fs2 if f.rule == "MXL601"]
+
+
+# -- a mixed state spec: SambaY behind the same Server ------------------------
+# (docs/serving.md, "State kinds": recurrent, conv, window and full-KV
+# buffers in one pool; models/sambay.py)
+
+@pytest.fixture(scope="module")
+def hybrid():
+    from mxnet_tpu.models import SambaYForCausalLM, sambay_tiny
+    mx.random.seed(1)
+    np.random.seed(1)
+    lm = SambaYForCausalLM(sambay_tiny(vocab_size=V))
+    lm.initialize(mx.init.Xavier())
+    return lm
+
+
+def _reference_tokens(lm, prompt, new):
+    return lm.generate(nd.array(prompt[None]),
+                       max_new_tokens=new).asnumpy()[0]
+
+
+def test_hybrid_pool_follows_the_models_spec(hybrid):
+    pool = KVCachePool(hybrid, slots=3, cache_len=20, dtype="bfloat16")
+    assert pool.num_buffers == len(hybrid.state_spec(3, 20)) == 12
+    kinds = {k for _n, k, _s, _d in pool.spec}
+    assert kinds == {"conv", "ssm", "kv_window", "kv_full"}
+    for (_n, kind, shape, dtype), buf in zip(pool.spec, pool.flat()):
+        assert tuple(buf.shape) == shape and str(buf.dtype) == dtype
+        # a recurrent state stays float32 whatever the cache dtype
+        assert dtype == ("float32" if kind == "ssm" else "bfloat16")
+    by = pool.bytes_by_kind()
+    assert sum(by.values()) == pool.nbytes()
+    assert by["ssm"] == 3 * 3 * 4 * 128 * 4
+    with pytest.raises(MXNetError, match="adopt"):
+        pool.adopt(pool.flat()[:3])
+
+
+def test_pool_rejects_a_spec_outside_the_contract(hybrid):
+    class Bad:
+        def __init__(self, rows):
+            self.rows = rows
+
+        def state_spec(self, slots, cache_len, dtype):
+            return self.rows
+    with pytest.raises(MXNetError, match="kind"):
+        KVCachePool(Bad([("x", "pages", (2, 4), "float32")]), 2, 4)
+    with pytest.raises(MXNetError, match="slot dim"):
+        KVCachePool(Bad([("x", "ssm", (3, 4), "float32")]), 2, 4)
+    with pytest.raises(MXNetError, match="no buffer"):
+        KVCachePool(Bad([]), 2, 4)
+
+
+def test_hybrid_greedy_parity_and_one_dispatch(hybrid):
+    """Continuous batching over SSM, conv, window and full-KV state is
+    bit-transparent for greedy, prompts shorter and longer than the
+    window (8) side by side; steady state is one dispatch a round."""
+    prompts = [_prompt(40, 5), _prompt(41, 14), _prompt(42, 2),
+               _prompt(43, 16)]
+    srv = Server(hybrid, buckets=[(2, 16)], max_new_tokens=12)
+    outs = srv.generate(prompts)
+    for p, out in zip(prompts, outs):
+        np.testing.assert_array_equal(out, _reference_tokens(hybrid, p, 12))
+    st = srv.stats()["buckets"]["2x16"]
+    assert st["steady_misses"] == 0 and st["steady_fresh_compiles"] == 0
+    r = srv.submit(_prompt(44, 9))
+    srv.step()
+    d0 = engine.dispatch_count()
+    srv.step()
+    assert engine.dispatch_count() - d0 == 1
+    srv.run()
+    np.testing.assert_array_equal(
+        r.tokens(), _reference_tokens(hybrid, _prompt(44, 9), 12))
+    # K-step bulking carries every kind of state through lax.scan
+    bulk = srv.generate(prompts[:2], decode_steps=4)
+    for p, out in zip(prompts, bulk):
+        np.testing.assert_array_equal(out, _reference_tokens(hybrid, p, 12))
+
+
+def test_hybrid_readmitted_slot_keeps_no_recurrent_trace(hybrid):
+    """Admit, evict, re-admit: the request that takes over a slot gets
+    EXACTLY the tokens it gets in a fresh server.  A recurrent state has
+    no validity mask to hide behind: admission must replace all of it."""
+    pa, pb = _prompt(45, 11), _prompt(46, 13)
+    want = _reference_tokens(hybrid, pa, 10)
+    srv = Server(hybrid, buckets=[(1, 16)], max_new_tokens=10)
+    rb = srv.submit(pb)
+    for _ in range(6):
+        srv.step()                       # slot 0 now deep in B's state
+    ssm = [i for i, r in enumerate(srv._pools[(1, 16)].spec)
+           if r[1] == "ssm"]
+    before = [np.asarray(srv._pools[(1, 16)].flat()[i]) for i in ssm]
+    assert all(np.abs(b).max() > 0 for b in before)
+    srv.evict(rb, reason="preempt")
+    ra = srv.submit(pa)
+    srv.run()
+    np.testing.assert_array_equal(ra.tokens(), want)
+    # and next to an evicted neighbour's live garbage (two slots)
+    srv2 = Server(hybrid, buckets=[(2, 16)], max_new_tokens=10)
+    ra2, rb2 = srv2.submit(pa), srv2.submit(pb)
+    srv2.step()
+    srv2.evict(rb2, reason="preempt")
+    srv2.run()
+    np.testing.assert_array_equal(ra2.tokens(), want)
+
+
+def test_hybrid_resize_slots_migrates_every_kind(hybrid):
+    pa, pb = _prompt(47, 6), _prompt(48, 12)
+    want = [_reference_tokens(hybrid, p, 10) for p in (pa, pb)]
+    srv = Server(hybrid, buckets=[(2, 16)], max_new_tokens=10)
+    ra, rb = srv.submit(pa), srv.submit(pb)
+    srv.step()
+    srv.step()
+    rec = srv.resize_slots(3)
+    assert rec["migrated"] == 2 and rec["requeued"] == 0
+    assert rec["prewarmed_variants"] == 2
+    assert [tuple(b.shape)[0] for b in srv._pools[(3, 16)].flat()] \
+        == [3] * 12
+    m0, f0 = engine.compile_counts()
+    srv.run()
+    assert engine.compile_counts() == (m0, f0)     # the pre-warm held
+    np.testing.assert_array_equal(ra.tokens(), want[0])
+    np.testing.assert_array_equal(rb.tokens(), want[1])
+    snap = telemetry.snapshot()["gauges"]
+    assert snap["mxtpu_serving_state_bytes_b3x16_ssm"] == 3 * 3 * 4 * 128 * 4
+
+
+def test_hybrid_poison_recover_round_trip(hybrid):
+    p = _prompt(49, 9)
+    want = _reference_tokens(hybrid, p, 8)
+    srv = Server(hybrid, buckets=[(2, 16)], max_new_tokens=8)
+    req = srv.submit(p)
+    srv.step()
+    faults.configure("dispatch_post:nth=1")
+    try:
+        with pytest.raises(MXNetError, match="recover"):
+            srv.step()
+    finally:
+        faults.clear()
+    assert srv.recover() == 1
+    assert all(float(np.abs(np.asarray(b, np.float32)).max()) == 0.0
+               for b in srv._pools[(2, 16)].flat())
+    srv.run()
+    np.testing.assert_array_equal(req.tokens(), want)
+
+
+def test_hybrid_warm_start_carries_the_spec(hybrid, tmp_path, monkeypatch):
+    import json
+    monkeypatch.setenv("MXTPU_COMPILE_CACHE_DIR", str(tmp_path))
+    prompts = [_prompt(50, 5), _prompt(51, 12)]
+    engine.clear_cache()
+    srv = Server(hybrid, buckets=[(2, 16)], max_new_tokens=6,
+                 cache_dtype="bfloat16")
+    cold = srv.generate(prompts)
+    man = str(tmp_path / "hybrid.json")
+    srv.save_signature(man)
+    rows = json.load(open(man))["buckets"][0]["state"]
+    assert ["layer0_ssm", "ssm", [2, 4, 128], "float32"] in rows
+    assert ["layer5_k", "kv_full", [2, 22, 4, 8], "bfloat16"] in rows
+    engine.clear_cache()
+    engine.reset_counters()
+    srv2 = Server(hybrid, buckets=[(2, 16)], max_new_tokens=6,
+                  cache_dtype="bfloat16")
+    assert srv2.warm_start(man)
+    warm = srv2.generate(prompts)
+    assert engine.cache_info()["fresh_compiles"] == 0
+    for a, b in zip(cold, warm):
+        np.testing.assert_array_equal(a, b)
+    # another cache dtype is another spec: fail open, by the hash
+    other = Server(hybrid, buckets=[(2, 16)], max_new_tokens=6)
+    assert other.warm_start(man) is False
+    # and a manifest whose spec rows were edited names the buffer
+    m = json.load(open(man))
+    m["buckets"][0]["state"][1][3] = "bfloat16"
+    with open(man, "w") as f:
+        json.dump(m, f)
+    srv3 = Server(hybrid, buckets=[(2, 16)], max_new_tokens=6,
+                  cache_dtype="bfloat16")
+    assert srv3.warm_start(man) is False
+    assert any("state spec mismatch" in str(e.get("reason"))
+               and "layer0_ssm" in str(e.get("reason"))
+               for e in telemetry.events("warm_start"))
+
+
+def test_llama_declares_the_spec_it_always_had(net):
+    """Llama/Mistral run the same state plane: two K/V buffers a layer,
+    in the flat order the programs always took."""
+    spec = net.state_spec(2, 8, "bfloat16")
+    assert [n for n, _k, _s, _d in spec] == [
+        "layer0_k", "layer0_v", "layer1_k", "layer1_v"]
+    assert {(k, s, d) for _n, k, s, d in spec} == {
+        ("kv_full", (2, 8, 2, 16), "bfloat16")}
+    # init_cache IS the spec, zeroed: one form of the state, flat
+    caches = net.init_cache(2, 8, dtype="bfloat16")
+    assert [(c.shape, str(c.dtype)) for c in caches] == [
+        (s, d) for _n, _k, s, d in spec]
+
+
+def test_rolling_cache_is_the_spec_at_the_window(hybrid):
+    """The rolling buffer is the same flat list, only shorter; the hybrid
+    decoder's window pages are per layer."""
+    from mxnet_tpu.models import LlamaForCausalLM, get_llama
+    mx.random.seed(5)
+    win = LlamaForCausalLM(get_llama("llama_tiny", vocab_size=61,
+                                     sliding_window=4))
+    win.initialize()
+    assert [c.shape[1] for c in win.init_cache(1, 8, rolling=True)] \
+        == [4] * 4
+    assert {k for _n, k, _s, _d in win.state_spec(1, 8)} == {"kv_window"}
+    lens = {n: s[1] for n, k, s, _d in hybrid.state_spec(1, 16)
+            if k.startswith("kv_")}
+    assert set(lens.values()) == {8, 16}        # windows of 8, ONE full page
