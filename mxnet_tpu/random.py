@@ -20,6 +20,10 @@ __all__ = ["seed", "uniform", "normal", "randn", "randint", "exponential",
 
 _keys = {}
 _DEFAULT_SEED = 0
+# bumped by every `seed()`: a holder of a key drawn from the stream
+# (`serving.Server`'s resident base key) compares it to know that the
+# stream was reseeded since, at the cost of one int compare
+_seed_epoch = 0
 
 # CachedOp tracing hook: while a hybridized graph is being traced, RNG keys
 # must be *inputs* to the graph (a constant key would freeze every dropout
@@ -96,7 +100,8 @@ def _ensure_prng_impl(required=True):
 
 def seed(seed_state: int, ctx: Optional[Context] = None):
     """Reset the RNG. ``ctx=None`` reseeds every context (parity: 'all')."""
-    global _keys
+    global _keys, _seed_epoch
+    _seed_epoch += 1
     # the all-contexts path stores only an int — no key is created, so
     # a not-yet-initialized backend must not make seed-at-startup fail
     _ensure_prng_impl(required=ctx is not None and ctx != "all")

@@ -25,9 +25,11 @@ moves the buffers and never looks inside a layer:
 
 Sampling is greedy at ``temperature == 0`` and softmax sampling with
 optional server-wide top-k truncation otherwise; the RNG threads the
-CachedOp fold_in scheme — one base key INPUT per dispatch (drawn from
-the global stream, so keys never retrace) folded per inner step and
-per slot.
+CachedOp fold_in scheme — ONE base key a server, drawn from the global
+stream and resident on the device, INPUT to every dispatch beside a
+dispatch counter that the program folds into it, then folded per inner
+step and per slot (nothing is made on the host before a dispatch, and
+keys never retrace).
 
 ``save_signature``/``warm_start`` extend the PR 5 AOT warm-start
 machinery to serving: a fresh process precompiles every recorded
@@ -78,6 +80,19 @@ def _reset_registry():
     """Test hook."""
     with _reg_lock:
         _servers.clear()
+
+
+# inputs of a bucket program after the params and the state: four of the
+# kind's own (prefill: prompt, last_pos, slot, temp; decode: tok, off,
+# active, temp), then the resident base key and the dispatch counter
+_N_INPUTS = 6
+
+
+def _dispatch_key(key_raw, counter):
+    """This dispatch's key (traced): the counter folded into the base
+    key.  Rows and scanned steps fold their index into it in turn."""
+    import jax
+    return jax.random.fold_in(jax.random.wrap_key_data(key_raw), counter)
 
 
 def _default_buckets():
@@ -199,6 +214,12 @@ class Server:
                     "steady_misses": 0, "steady_fresh_compiles": 0}
             for b in self.sched.buckets}
         self._poisoned: Optional[str] = None
+        # the sampler's base key (raw data, on the device), the
+        # random._seed_epoch it was drawn under, and the dispatches
+        # since: _rng_inputs
+        self._key_base = None
+        self._key_epoch = None
+        self._key_counter = 0
         # calls of step() so far: the `round` id of this server's
         # profiler spans (docs/observability.md, "Spans")
         self._span_round = 0
@@ -601,7 +622,8 @@ class Server:
                     # the slot dim is dim 0 of every state buffer and —
                     # for decode — of the 4 per-slot extras (tok/off/
                     # active/temp); everything else (params, prefill
-                    # extras, the RNG key) is slot-count-independent
+                    # extras, the RNG key and counter) is
+                    # slot-count-independent
                     per_slot = (P <= i < P + NS) or (
                         kind == "decode" and
                         P + NS <= i < P + NS + 4)
@@ -915,6 +937,22 @@ class Server:
                     f"{row['prompt_len']}: manifest {a} vs configured {b}")
         if self._poisoned is not None:
             return _fail("server is poisoned")
+        for v in m.get("variants", ()):
+            bucket = self._bucket_for_suffix(str(v.get("suffix")))
+            if bucket is None:
+                continue                    # named and refused below
+            have = len(v.get("avals", ()))
+            want = len(self._param_nds) + self._n_state(bucket) + _N_INPUTS
+            if have != want:
+                # a manifest from before the key was resident: its
+                # programs are ones no dispatch calls any more, so
+                # none is pre-compiled
+                return _fail(
+                    f"variant {v.get('suffix')!r} records {have} inputs "
+                    f"where the program takes {want}: the RNG key input "
+                    "is now the server's resident base key and a "
+                    "dispatch counter, not a key made per dispatch "
+                    "(re-save the signature)")
         try:
             import jax
             self._persist_base = m["persist_base"]
@@ -1007,14 +1045,14 @@ class Server:
             from ..gluon import block as block_mod
             from ..ndarray.ndarray import NDArray
             param_vals = list(flat[:P])
-            tok, off, active, temp, key_raw = flat[P + NS:]
+            tok, off, active, temp, key_raw, counter = flat[P + NS:]
             with block_mod.tracing_scope(params, param_vals):
                 shells = [NDArray(c, ctx=ctx) for c in flat[P:P + NS]]
                 logits = lm.decode_step(
                     NDArray(tok, ctx=ctx), shells,
                     NDArray(off, ctx=ctx))._data
                 new_caches = tuple(s._data for s in shells)
-            k0 = jax.random.wrap_key_data(key_raw)
+            k0 = _dispatch_key(key_raw, counter)
             keys = jax.vmap(lambda i: jax.random.fold_in(k0, i))(
                 jnp.arange(N))
             nxt = self._pick(logits, temp, active, keys)
@@ -1036,8 +1074,8 @@ class Server:
             from ..ndarray.ndarray import NDArray
             param_vals = list(flat[:P])
             cache_vals = tuple(flat[P:P + NS])
-            tok, off, active, temp, key_raw = flat[P + NS:]
-            k0 = jax.random.wrap_key_data(key_raw)
+            tok, off, active, temp, key_raw, counter = flat[P + NS:]
+            k0 = _dispatch_key(key_raw, counter)
 
             def body(carry, step_i):
                 tok_c, off_c, caches = carry
@@ -1078,7 +1116,7 @@ class Server:
             from ..gluon import block as block_mod
             from ..ndarray.ndarray import NDArray
             param_vals = list(flat[:P])
-            prompt, last_pos, slot, temp, key_raw = flat[P + NS:]
+            prompt, last_pos, slot, temp, key_raw, counter = flat[P + NS:]
             with block_mod.tracing_scope(params, param_vals):
                 tmp = [NDArray(jnp.zeros(shape, jnp.dtype(dt)), ctx=ctx)
                        for _name, _kind, shape, dt in one]
@@ -1092,7 +1130,7 @@ class Server:
                     c, t._data.astype(c.dtype),
                     (slot_i,) + (zero,) * (c.ndim - 1))
                 for c, t in zip(flat[P:P + NS], tmp)]
-            k0 = jax.random.wrap_key_data(key_raw)
+            k0 = _dispatch_key(key_raw, counter)
             keys = jax.vmap(lambda i: jax.random.fold_in(k0, i))(
                 slot_i.reshape(1))
             nxt = self._pick(logits, temp, jnp.ones((1,)), keys)
@@ -1124,12 +1162,14 @@ class Server:
         with _span("mxtpu.serving.flatten", "serving"):
             if self._decode_sharding is not None:
                 # the planned decode mesh: params ride as the
-                # replicated copies placed at construction, and every
-                # per-dispatch extra (tokens/offsets/temps/key) is
+                # replicated copies placed at construction, the base
+                # key as the one placed when it was drawn, and every
+                # host-made extra (tokens/offsets/temps/counter) is
                 # committed replicated — one coherent SPMD program, no
                 # mixed-device inputs
                 import jax as _jax
                 extra = [_jax.device_put(e, self._repl_sharding)
+                         if isinstance(e, np.ndarray) else e
                          for e in extra]
                 params_flat = list(self._placed_params)
             else:
@@ -1201,13 +1241,37 @@ class Server:
                 stats["steady_fresh_compiles"] += f1 - f0
         return res[:n_out]
 
+    def _rng_inputs(self):
+        """The sampler's two inputs of one dispatch: the base key and
+        the count of dispatches since it was drawn, which the program
+        folds into it (``_dispatch_key``).  The key is drawn from the
+        global stream at the first dispatch, and again at the first one
+        after a ``mx.random.seed``: the only device work the host does
+        for the RNG, and none of it in a steady round."""
+        from .. import random as _rnd
+        if self._key_epoch != _rnd._seed_epoch:
+            from .. import telemetry
+            self._key_epoch = _rnd._seed_epoch
+            base = _rnd._next_key_nd(self.ctx)._data
+            if self._repl_sharding is not None:
+                import jax
+                base = jax.device_put(base, self._repl_sharding)
+            self._key_base = base
+            self._key_counter = 0
+            telemetry.counter(
+                "mxtpu_serving_host_keys_total",
+                "base RNG keys a Server drew from the host's stream "
+                "(one a server, plus one a reseed)").inc()
+        n = self._key_counter
+        self._key_counter = (n + 1) & 0xFFFFFFFF
+        return self._key_base, np.asarray(n, np.uint32)
+
     def _admit(self, bucket, slot: int, req: Request):
         with _span("mxtpu.serving.admit", "serving", req=req.id,
                    bucket=bucket.prompt_len, slot=slot):
             self._admit_impl(bucket, slot, req)
 
     def _admit_impl(self, bucket, slot: int, req: Request):
-        from .. import random as _rnd
         from .. import telemetry
         t0 = req.admit_t = time.perf_counter()
         telemetry.histogram(
@@ -1222,7 +1286,7 @@ class Server:
                      np.asarray([req.prompt_len - 1], np.float32),
                      np.asarray(slot, np.float32),
                      np.asarray([req.temperature], np.float32),
-                     _rnd._next_key_nd(self.ctx)._data]
+                     *self._rng_inputs()]
         # pre-dispatch failures (trace/compile, retries exhausted)
         # propagate to step(), which releases THIS placement and the
         # ones behind it back to the queue in FIFO order
@@ -1255,7 +1319,6 @@ class Server:
             return self._decode_impl(bucket, decode_steps)
 
     def _decode_impl(self, bucket, decode_steps: int) -> int:
-        from .. import random as _rnd
         from .. import telemetry
         t0 = time.perf_counter()
         k = max(1, int(decode_steps))
@@ -1264,7 +1327,7 @@ class Server:
             extra = [bucket.last_tokens.reshape(bucket.slots, 1).copy(),
                      bucket.offsets.copy(), active_snap.copy(),
                      bucket.temps.copy(),
-                     _rnd._next_key_nd(self.ctx)._data]
+                     *self._rng_inputs()]
         out = self._dispatch(bucket, "decode", extra,
                              k=0 if k == 1 else k)
         # the host WAITS for the device here: the one span of a round

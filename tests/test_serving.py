@@ -830,3 +830,173 @@ def test_rolling_cache_is_the_spec_at_the_window(hybrid):
     lens = {n: s[1] for n, k, s, _d in hybrid.state_spec(1, 16)
             if k.startswith("kv_")}
     assert set(lens.values()) == {8, 16}        # windows of 8, ONE full page
+
+
+# -- the resident RNG key (docs/serving.md, "Sampling") -----------------------
+# ONE base key a server, drawn from the global stream and kept on the
+# device; every dispatch hands it over beside a counter that the program
+# folds in.  Each contract below holds for both state planes.
+
+@pytest.fixture(params=["llama", "hybrid"])
+def lm_bucket(request):
+    """A decoder and a two-slot bucket that fits its prompts."""
+    if request.param == "llama":
+        return request.getfixturevalue("net"), (2, 8)
+    return request.getfixturevalue("hybrid"), (2, 16)
+
+
+def _host_keys():
+    return telemetry.counter("mxtpu_serving_host_keys_total").value
+
+
+def test_one_host_key_a_server(lm_bucket, monkeypatch):
+    """The host draws a key at a server's first dispatch and never
+    again: no steady round, admitting or decoding, touches the stream."""
+    from mxnet_tpu import random as rnd
+    lm, bucket = lm_bucket
+    k0 = _host_keys()
+    srv = Server(lm, buckets=[bucket], max_new_tokens=6)
+    assert _host_keys() == k0               # drawn when first needed
+    srv.submit(_prompt(60, 4), temperature=1.0)
+    srv.step()                              # first prefill AND decode
+    assert _host_keys() == k0 + 1
+    calls = []
+    real = rnd._next_key_nd
+    monkeypatch.setattr(rnd, "_next_key_nd",
+                        lambda ctx: calls.append(ctx) or real(ctx))
+    d0 = engine.dispatch_count()
+    for i in range(5):                      # admissions between decodes
+        srv.submit(_prompt(61 + i, 3 + i), temperature=float(i % 2))
+        srv.step()
+    srv.run()
+    assert engine.dispatch_count() - d0 >= 10
+    assert calls == [] and _host_keys() == k0 + 1
+    # a second server is a second key, not a shared one
+    Server(lm, buckets=[bucket], max_new_tokens=6).generate(
+        [_prompt(60, 4)])
+    assert len(calls) == 1 and _host_keys() == k0 + 2
+
+
+def test_keys_differ_by_dispatch_and_by_row(lm_bucket):
+    """Every dispatch folds its own count into the base key and every
+    row its own index: the same prompt draws other tokens in the next
+    round, and in the neighbouring slot of the same round."""
+    lm, (slots, plen) = lm_bucket
+    p = _prompt(62, 5)
+    mx.random.seed(11)
+    one = Server(lm, buckets=[(1, plen)], max_new_tokens=12)
+    first = one.generate([p], temperature=1.0)[0]
+    again = one.generate([p], temperature=1.0)[0]   # same slot, later
+    assert not np.array_equal(first, again)
+    two = Server(lm, buckets=[(slots, plen)], max_new_tokens=12)
+    a, b = two.generate([p, p], temperature=1.0)    # one round, two rows
+    assert not np.array_equal(a, b)
+    for out in (first, again, a, b):
+        assert len(out) == 5 + 12
+        assert (out >= 0).all() and (out < V).all()
+
+
+def test_reseed_takes_effect_at_the_next_dispatch(lm_bucket):
+    """``mx.random.seed`` between requests re-draws the base key at the
+    next dispatch (one more host key) and restarts the count: the same
+    seed gives the same tokens, another seed gives others."""
+    lm, bucket = lm_bucket
+    p = [_prompt(63, 4), _prompt(64, 6)]
+    srv = Server(lm, buckets=[bucket], max_new_tokens=8)
+    k0 = _host_keys()
+    mx.random.seed(5)
+    a = srv.generate(p, temperature=1.0)
+    mx.random.seed(6)
+    b = srv.generate(p, temperature=1.0)
+    mx.random.seed(5)
+    c = srv.generate(p, temperature=1.0)
+    assert _host_keys() == k0 + 3
+    for x, y, z in zip(a, b, c):
+        np.testing.assert_array_equal(x, z)
+    assert any(not np.array_equal(x, y) for x, y in zip(a, b))
+    # unseeded, the server keeps its key: no draw, and no repeat
+    d = srv.generate(p, temperature=1.0)
+    assert _host_keys() == k0 + 3
+    assert any(not np.array_equal(x, y) for x, y in zip(c, d))
+
+
+@pytest.mark.parametrize("decode_steps", [1, 4])
+def test_mixed_batch_greedy_exact_sampled_seeded(lm_bucket, decode_steps):
+    """One batch, one greedy and one sampled row, per step and K steps a
+    dispatch: the greedy row is bit-equal to ``generate`` (the sampler's
+    key never reaches it), the sampled row is a function of the seed."""
+    lm, bucket = lm_bucket
+    pg, ps = _prompt(65, 5), _prompt(66, 7)
+
+    def serve():
+        mx.random.seed(21)
+        srv = Server(lm, buckets=[bucket], max_new_tokens=9, top_k=10)
+        rg = srv.submit(pg, temperature=0.0)
+        rs = srv.submit(ps, temperature=1.0)
+        srv.run(decode_steps=decode_steps)
+        return rg.tokens(), rs.tokens()
+
+    g1, s1 = serve()
+    g2, s2 = serve()
+    np.testing.assert_array_equal(g1, _reference_tokens(lm, pg, 9))
+    np.testing.assert_array_equal(g1, g2)
+    np.testing.assert_array_equal(s1, s2)
+    assert len(s1) == 7 + 9 and (s1 >= 0).all() and (s1 < V).all()
+    # the scanned steps fold their index in: not one draw K times
+    assert len(set(s1[7:].tolist())) > 1
+
+
+def test_warm_start_key_input_round_trip_and_old_manifest(
+        lm_bucket, tmp_path):
+    """A manifest of today's call shape (state, four inputs, the base
+    key, the counter) warm-starts with 0 fresh compiles; one from before
+    the key was resident (a key made per dispatch as the LAST input)
+    fails open: False, a ``warm_start`` event that names the key input,
+    nothing pre-compiled, and the next step cold and correct."""
+    import json
+    lm, bucket = lm_bucket
+    prompts = [_prompt(67, 5), _prompt(68, 7)]
+    engine.clear_cache()
+    srv = Server(lm, buckets=[bucket], max_new_tokens=5)
+    cold = srv.generate(prompts)
+    man = str(tmp_path / "serving.json")
+    srv.save_signature(man)
+    m = json.load(open(man))
+    n_in = len(srv._param_nds) + srv._pools[bucket].num_buffers + 6
+    assert {v["kind"] for v in m["variants"]} == {"prefill", "decode"}
+    for v in m["variants"]:
+        assert len(v["avals"]) == n_in
+        assert v["avals"][-1] == [[], "uint32"]         # the counter
+        assert v["avals"][-2][1] == "uint32"            # the base key
+        assert v["avals"][-3][1] == "float32"           # temp
+
+    engine.clear_cache()
+    engine.reset_counters()
+    srv2 = Server(lm, buckets=[bucket], max_new_tokens=5)
+    assert srv2.warm_start(man) is True
+    fresh = engine.cache_info()["fresh_compiles"]
+    warm = srv2.generate(prompts)
+    assert engine.cache_info()["fresh_compiles"] == fresh
+    st = srv2.stats()["buckets"]["%dx%d" % bucket]
+    assert st["steady_misses"] == 0 and st["steady_fresh_compiles"] == 0
+
+    for v in m["variants"]:
+        del v["avals"][-1]
+    old = str(tmp_path / "serving_old.json")
+    with open(old, "w") as f:
+        json.dump(m, f)
+    engine.clear_cache()
+    engine.reset_counters()
+    telemetry.clear_events()
+    srv3 = Server(lm, buckets=[bucket], max_new_tokens=5)
+    fresh = engine.cache_info()["fresh_compiles"]   # the pool's zeros
+    assert srv3.warm_start(old) is False
+    ev = telemetry.events("warm_start")[-1]
+    assert ev["ok"] is False and "RNG key input" in ev["reason"]
+    assert not srv3.warm_started and not srv3._warmed
+    assert engine.cache_info()["fresh_compiles"] == fresh
+    again = srv3.generate(prompts)
+    assert engine.cache_info()["fresh_compiles"] >= fresh + 2
+    for a, b, c in zip(cold, warm, again):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(a, c)
