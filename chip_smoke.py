@@ -184,8 +184,8 @@ def _free():
 # ---------------------------------------------------------------------------
 
 def _build_bert_trainer(shape, seed, devices, dropout):
-    """BERTForPretrain + the fused SPMD trainer, as bench.py builds
-    them, on a ``{"dp": len(devices)}`` mesh; plus one seeded batch."""
+    """BERTForPretrain + the fused SPMD trainer on a
+    ``{"dp": len(devices)}`` mesh; plus one seeded batch."""
     import mxnet_tpu as mx
     from mxnet_tpu import models, nd, parallel
     from mxnet_tpu.gluon.block import HybridBlock

@@ -587,12 +587,12 @@ def analyze_wire(jaxpr=None, plan=None, *, drift: float = 0.10,
     return findings
 
 
-# -- report (the CLI / bench surface) ---------------------------------------
+# -- report (the CLI surface) -----------------------------------------------
 
 def wire_report() -> Dict[str, dict]:
-    """Per-variant leg inventory for ``tools/mxwire.py show`` and the
-    bench ``wire`` block: ``{"owner:variant": {legs, static/measured
-    wire bytes, drift, ...}}``."""
+    """Per-variant leg inventory for ``tools/mxwire.py show``:
+    ``{"owner:variant": {legs, static/measured wire bytes, drift,
+    ...}}``."""
     out: Dict[str, dict] = {}
     with _lock:
         recs = list(_variants.values())

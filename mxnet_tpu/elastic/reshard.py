@@ -182,8 +182,8 @@ def plan_moves(named_shapes, plan_src, plan_dst,
     every param whose layout actually changes (``moves`` from
     :func:`plan`; ``nbytes`` is the GLOBAL tensor size — the upper
     bound on bytes the move touches).  Derived purely from shapes +
-    the two plans, never from device state — the ``mxplan diff`` /
-    bench accounting input."""
+    the two plans, never from device state — the ``mxplan diff``
+    accounting input."""
     out: Dict[str, dict] = {}
     for row in plan_src.diff(plan_dst, named_shapes,
                              dtype_bytes=dtype_bytes):

@@ -47,9 +47,9 @@ _live: "weakref.WeakValueDictionary[int, Any]" = \
 
 # dispatch/compile-cache telemetry (surfaced via cache_info()): one
 # "dispatch" = one invoke_compiled call = one XLA executable launch.
-# The fused-optimizer tier-1 test and bench.py's
-# ``optimizer_dispatches_per_step`` read these, so the counters are
-# part of the public introspection contract, not debug scaffolding.
+# The tier-1 dispatch-count tests, chip_smoke.py and the benchmark's
+# ``dispatches_per_step.train`` read these, so the counters are part
+# of the public introspection contract, not debug scaffolding.
 _hits = 0
 _misses = 0
 _dispatches = 0
@@ -74,7 +74,7 @@ _telem = None
 # mxsan hook (analysis.sanitizer, docs/static_analysis.md "The
 # sanitizer"): the sanitizer module itself when MXTPU_SANITIZE >= 1,
 # None otherwise — the off cost is ONE attribute load per dispatch
-# (the bench `sanitizer` block's contract).  Set via
+# (held by tests/test_sanitizer.py).  Set via
 # sanitizer.configure(), never imported here (the analysis package
 # imports the engine; a top-level import back would cycle).
 _san = None
@@ -90,7 +90,7 @@ _AVAL_HISTORY_CAP = 64
 # lock below: DataLoader workers dispatch while the train thread does —
 # an unlocked check-then-append would let two first-time dispatches of
 # the same signature emit a phantom empty-diff retrace event, and the
-# bench/CI contract is that steady state shows ZERO retrace events)
+# contract is that steady state shows ZERO retrace events)
 _attr_lock = threading.Lock()
 
 
@@ -450,7 +450,7 @@ def _get_compiled_keyed(key, sig, name, fcompute, attrs, donate,
     # += on a module global is not atomic (read-modify-write can lose
     # increments across threads, e.g. DataLoader workers dispatching
     # while the main thread trains) and the dispatch counters are an
-    # exact contract for tests/bench — take the lock
+    # exact contract for the tests and the benchmark — take the lock
     with _lock:
         _hits += 1
     return fn

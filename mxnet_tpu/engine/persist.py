@@ -1,9 +1,9 @@
 """Persistent second tier under the engine's jit cache.
 
 The in-memory tier (``engine._jit_cache``) dies with the process, so
-every restart re-pays the full XLA compile bill: in the r05 bench,
-bert_small spent ~18 s of a 24 s stage in "compiling + warmup" before
-the one-dispatch step ever ran.  Restarts are a first-class hot path
+every restart re-pays the full XLA compile bill before the
+one-dispatch step ever runs (PERF.md section 5, "Set-up": cold against
+warm ``setup_s`` on the chip).  Restarts are a first-class hot path
 for the ROADMAP north-star (production traffic, autoscaled replicas),
 and compiled-program reuse is the standard answer in TPU compilation
 stacks (the serializable-artifact design of Relay, arXiv:1810.00952;

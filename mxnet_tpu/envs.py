@@ -38,9 +38,6 @@ _reg("MXTPU_ENGINE_TYPE", str, "",
 _reg("MXTPU_TEST_ON_TPU", bool, False,
      "Run the test suite against the real TPU chip instead of the "
      "8-device CPU mesh.")
-_reg("MXTPU_DISABLE_FLASH", bool, False,
-     "Disable the Pallas flash-attention kernel (use the XLA SDPA "
-     "path everywhere).")
 _reg("MXTPU_FLASH_BLOCK_Q", int, 0,
      "Flash-attention query block size (rows per grid step). 0 = the "
      "measured seq-adaptive default; values that do not divide the "
@@ -58,8 +55,9 @@ _reg("MXTPU_FLASH_MODE", str, "auto",
 _reg("MXTPU_FLASH_XLA_FROM", int, 0,
      "CAUSAL attention: below this sequence length auto mode prefers "
      "the flash kernel; 0 (default) = XLA SDPA whenever it can "
-     "(the r5 IN-MODEL A/B measured the Pallas custom-call as a "
-     "fusion barrier: BERT-base 956.9 -> 1535.3 samples/sec on XLA). "
+     "(an in-model A/B read at sha dc2bc5d5 found the Pallas "
+     "custom-call a fusion barrier; not measured on today's code: no "
+     "benchmark cell runs the kernel yet). "
      "The kernel still takes windowed, HBM-exceeding, and "
      "seq>=UNTIL attention regardless.")
 _reg("MXTPU_FLASH_XLA_FROM_NONCAUSAL", int, 0,
@@ -211,8 +209,8 @@ _reg("MXTPU_HEALTH", bool, True,
 _reg("MXTPU_HEALTH_EVERY", int, 10,
      "Health sampling period K: the device health vector is read back "
      "to the host every K train steps (the read is the plane's only "
-     "host sync; K=10 measured <1% step-time overhead on the CPU "
-     "smoke — bench.py's health block). K=1 samples every step.")
+     "host sync; its cost on the chip is not measured). K=1 samples "
+     "every step.")
 _reg("MXTPU_HEALTH_ACTION", str, "warn",
      "What a health verdict does: 'warn' records events only; 'skip' "
      "bakes an in-graph nonfinite gate into the step (a step whose "
@@ -333,13 +331,7 @@ _reg("MXTPU_WIRE_AUDIT", bool, True,
      "bytes). 0 disables registration entirely.")
 _reg("MXTPU_MEM_REPORT_TOP_N", int, 10,
      "How many programs (sorted by peak per-device bytes) "
-     "telemetry.memory.report(), tools/mxmem.py, and bench.py's "
-     "memory block include.")
-_reg("MXTPU_BENCH_MAX_PEAK_BYTES", int, 0,
-     "Opt-in bench.py memory regression gate: when any harvested "
-     "program's per-device peak footprint exceeds this many bytes, "
-     "the emitted JSON line carries a failed memory_gate block and "
-     "bench.py exits 1. 0 (default) disables the gate.")
+     "telemetry.memory.report() and tools/mxmem.py include.")
 
 
 def registry():

@@ -2379,7 +2379,7 @@ class DataParallelTrainer:
         Alternatively pass SINGLE-batch (B, ...) data with ``repeat=K``
         to run K steps over the same batch without materializing K host
         copies (the batch becomes a plain program input the scanned
-        step body reuses — what bench.py's warm-cache bulking needs).
+        step body reuses).
 
         A ``lax.scan`` over the fused step with params + optimizer
         state as the carry — the XLA rebuild of the reference engine's

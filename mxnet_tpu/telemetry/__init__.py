@@ -70,7 +70,7 @@ def disable():
 def reset():
     """Zero every metric, empty the event ring, rewind the global
     step counter, and forget the memory observatory's harvested
-    programs (test isolation / per-run bench hygiene).  Instrument
+    programs (test isolation).  Instrument
     identities survive."""
     from . import recorder
     reset_metrics()

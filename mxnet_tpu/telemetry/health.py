@@ -17,8 +17,8 @@ contracts the rest of the stack fought for:
   dispatches — the one-dispatch contract holds with health on;
 * **sampled host transfer** — the device vector is read back only
   every ``MXTPU_HEALTH_EVERY`` steps (the read is the only host sync
-  the plane adds; at the default K=10 it is <1% of step time on the
-  CPU smoke, see bench.py's ``health`` block);
+  the plane adds; its cost on the chip is not measured alone —
+  PERF.md section 5 finds it as one of the train cell's two drains);
 * **host sentinel** — :class:`Sentinel` keeps rolling loss/grad-norm
   statistics per step owner and emits retained ``health_anomaly``
   flight-recorder events (loss spike, grad-norm explosion,
@@ -773,7 +773,7 @@ def handle_verdict(owner, verdict: Optional[dict]) -> bool:
     return True
 
 
-# -- per-process registry (tools/mxhealth.py / bench read it) ----------
+# -- per-process registry (tools/mxhealth.py reads it) ------------------
 
 _reg_lock = threading.Lock()
 _sentinels: Dict[str, Sentinel] = {}

@@ -31,9 +31,8 @@ parameters by name; ``oom_risk`` events fire when live + peak
 approaches the device capacity (``device.memory_stats()`` — absent on
 CPU, so the check is inert there).
 
-Consumers: ``engine.cache_info()["memory"]``, ``tools/mxmem.py``,
-``bench.py``'s per-stage ``memory`` block, and the mxlint rules
-MXL308/MXL309 (``analysis.analyze_memory``).  See
+Consumers: ``engine.cache_info()["memory"]``, ``tools/mxmem.py``
+and the mxlint rules MXL308/MXL309 (``analysis.analyze_memory``).  See
 docs/observability.md ("Device memory & comms").
 """
 from __future__ import annotations
@@ -683,7 +682,7 @@ def report(top_n: Optional[int] = None, params=None) -> dict:
     """The observatory's one-call summary: top-N programs by peak
     bytes, the live-buffer census, collective traffic, device capacity,
     and (when ``params`` is given) the per-param HBM table.  This is
-    what ``tools/mxmem.py`` renders and ``bench.py`` embeds."""
+    what ``tools/mxmem.py`` renders."""
     if top_n is None:
         from .. import envs
         top_n = envs.get("MXTPU_MEM_REPORT_TOP_N")

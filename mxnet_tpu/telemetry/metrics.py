@@ -17,8 +17,7 @@ other half, ``recorder.py``).  Design constraints, in order:
 
 Exporters: :func:`snapshot` (point-in-time dict), :func:`to_prometheus`
 (text exposition format) and :func:`write_jsonl` / :func:`read_jsonl`
-(one JSON object per instrument per line — the append-friendly format
-the bench trajectory files consume).
+(one JSON object per instrument per line, append-friendly).
 """
 from __future__ import annotations
 
@@ -182,7 +181,7 @@ class Histogram(_Instrument):
 
     def summary(self) -> dict:
         """Aggregate view: count/sum/min/max/avg plus cumulative bucket
-        counts — the shape the bench telemetry block embeds."""
+        counts."""
         with _lock:
             counts = list(self._counts)
             n, s = self._count, self._sum
@@ -276,8 +275,8 @@ def snapshot() -> dict:
 
 
 def reset_metrics():
-    """Zero every instrument (identity/buckets retained) — for tests
-    and per-run bench isolation."""
+    """Zero every instrument (identity/buckets retained) — for test
+    isolation."""
     with _lock:
         for inst in _instruments.values():
             inst._reset()

@@ -136,7 +136,7 @@ class TestRuntime:
         assert envs.get("MXTPU_ENGINE_TYPE") == ""
         monkeypatch.setenv("MXNET_ENGINE_TYPE", "NaiveEngine")
         assert envs.get("MXTPU_ENGINE_TYPE") == "NaiveEngine"
-        assert "MXTPU_DISABLE_FLASH" in envs.registry()
+        assert "MXTPU_FLASH_MODE" in envs.registry()
 
 
 class TestExportImport:

@@ -244,12 +244,12 @@ def test_int8_matmul_on_chip():
 
 def test_flash_auto_select_on_chip(monkeypatch):
     """The measured policy steers dispatch ON CHIP (VERDICT r3 #4):
-    since the r5 in-model A/B (bert_base 956.9 flash vs 1535.3 XLA —
-    the custom-call is a fusion barrier) XLA takes every ordinary
-    seq, and the kernel keeps seq>=UNTIL and beyond-HBM-budget score
-    tensors.  The DEFAULT policy is pinned explicitly: a chip window
-    may export MXTPU_FLASH_MODE / _XLA_FROM for the bench sweep, and
-    those must not flip this test's expectations."""
+    since the r5 in-model A/B (sha dc2bc5d5: the custom-call is a
+    fusion barrier) XLA takes every ordinary seq, and the kernel keeps
+    seq>=UNTIL and beyond-HBM-budget score tensors.  The DEFAULT
+    policy is pinned explicitly: the environment of a chip call may
+    export MXTPU_FLASH_MODE / _XLA_FROM for a sweep, and those must
+    not flip this test's expectations."""
     import jax.numpy as jnp
     from mxnet_tpu.ops import attention as attn
     for k in ("MXTPU_FLASH_MODE", "MXTPU_FLASH_XLA_FROM",

@@ -538,7 +538,7 @@ class TestStepMulti:
     def test_repeat_matches_sequential_steps_same_batch(self):
         """repeat=K scans one batch K times — identical to K step()
         calls on it, with no (K, B, ...) host broadcast materialized
-        (the bench.py warm-cache bulking path)."""
+        (the path chip_smoke.py runs on the chip)."""
         from mxnet_tpu import nd
         rng = np.random.RandomState(2)
         K, B = 3, 16

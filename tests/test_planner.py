@@ -389,6 +389,31 @@ def test_redistribute_plan_round_trip_exact():
                                                              8)
 
 
+def test_plan_moves_lists_only_changed_params_with_global_bytes():
+    """The move report is a count from shapes and the two plans: on ONE
+    mesh it lists exactly the params whose spec changes, each with its
+    GLOBAL tensor bytes; identical plans move nothing; across a change
+    of mesh axes every param is listed, a replicated one as a
+    re-replication."""
+    shapes = [("n_dense0_weight", (16, 8)), ("n_dense0_bias", (16,)),
+              ("n_dense1_weight", (4, 16)), ("n_dense1_bias", (4,))]
+    nbytes = {n: 4 * int(np.prod(s)) for n, s in shapes}
+    flat = ShardingPlan({"dp": 4, "tp": 2})
+    ruled = ShardingPlan({"dp": 4, "tp": 2}, _mlp_rules())
+    same_mesh = reshard.plan_moves(shapes, flat, ruled)
+    assert sorted(same_mesh) == ["n_dense0_bias", "n_dense0_weight",
+                                 "n_dense1_weight"]
+    assert {n: r["nbytes"] for n, r in same_mesh.items()} == \
+        {n: nbytes[n] for n in same_mesh}
+    assert same_mesh["n_dense1_weight"]["moves"] == ["slice(dim=1, tp:2)"]
+    assert reshard.plan_moves(shapes, ruled, ruled) == {}
+    other_mesh = reshard.plan_moves(shapes, ShardingPlan({"dp": 8}), ruled)
+    assert sorted(other_mesh) == sorted(nbytes)
+    assert other_mesh["n_dense1_bias"]["moves"] == ["replicate(dp:4xtp:2)"]
+    assert sum(r["nbytes"] for r in other_mesh.values()) == \
+        sum(nbytes.values())
+
+
 def test_checkpoint_matrix_across_plans_fp32_exact():
     """Checkpoint portability THROUGH plans: save under (dp8, ZeRO-2)
     plan, restore into a (dp4 x tp2, ZeRO-off) plan trainer and back —

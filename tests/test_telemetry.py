@@ -229,6 +229,31 @@ def test_compiled_step_records_one_dispatch():
     assert steps and steps[-1]["dispatches"] == 1
 
 
+def test_steady_window_across_health_samples_is_one_dispatch_no_retrace():
+    """The steady-state window an operator watches: warmed past the
+    first health-sampled step (every 10th by default; its program
+    variant traces once), 20 more steps cross two sampled steps and
+    still count ONE dispatch each, no retrace event, and a stall ratio
+    of 0 with no loader in the loop."""
+    X, Y = _data()
+    net = _mlp()
+    tr = gluon.Trainer(net.collect_params(), "sgd",
+                       {"learning_rate": 0.05})
+    cs = tr.compile_step(net, gluon.loss.L2Loss())
+    for _ in range(11):
+        cs.step(X, Y, 4)
+    telemetry.clear_events()
+    d0 = engine.cache_info()["dispatches"]
+    for _ in range(20):
+        cs.step(X, Y, 4)
+    assert engine.cache_info()["dispatches"] - d0 == 20
+    assert telemetry.events("retrace") == []
+    assert telemetry.snapshot()["gauges"][
+        "mxtpu_last_step_dispatches"] == 1.0
+    assert telemetry.prefetch_stall_ratio() == 0.0
+    assert telemetry.health.sentinels()[cs.name].samples == 3
+
+
 def test_momentum_drift_retrace_names_the_attr():
     X, Y = _data()
     net = _mlp()

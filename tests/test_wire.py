@@ -9,6 +9,7 @@ serialization contract (round-trip, legacy fail-open, stable legacy
 static-vs-observatory reconciliation (within MXL804's 10%), the ZeRO-2
 explicit-leg walk, and the llama_tiny dp x tp demo-trainer self-lint.
 """
+import collections
 import json
 import os
 import tempfile
@@ -211,17 +212,24 @@ def test_mxl313_decode_only_plan_replicated_big_tensor():
 # ---------------------------------------------------------------------------
 
 
-def test_dense_dp8_reconciles_within_ten_percent():
-    """ISSUE 16 acceptance: on the dense dp8 fused step the derived
-    static wire model lands within MXL804's 10% of the memory
-    observatory's runtime accounting — and the audit is quiet."""
+def _dp8_report(zero_stage=0):
+    """Three fused Adam steps of the MLP on dp=8 at ``zero_stage``; the
+    variant's row of ``wire_report()``."""
+    os.environ["MXTPU_ZERO_STAGE"] = str(zero_stage)
     net = _mlp()
     dpt = parallel.DataParallelTrainer(
         net, SoftmaxCrossEntropyLoss(), "adam",
         {"learning_rate": 1e-3}, mesh=parallel.make_mesh({"dp": 8}),
         fuse_step=True)
     _step_a_trainer(dpt)
-    rep = wire_passes.wire_report()[f"spmd:{net.name}"]
+    return wire_passes.wire_report()[f"spmd:{net.name}"]
+
+
+def test_dense_dp8_reconciles_within_ten_percent():
+    """ISSUE 16 acceptance: on the dense dp8 fused step the derived
+    static wire model lands within MXL804's 10% of the memory
+    observatory's runtime accounting — and the audit is quiet."""
+    rep = _dp8_report()
     assert rep["derived"] and rep["reconciled"]
     assert rep["trace_error"] is None
     assert rep["measured_wire_bytes"] is not None
@@ -241,19 +249,38 @@ def test_zero2_walks_explicit_contract_legs():
     """The ZeRO-2 fused step's jaxpr carries the stage-2 wire contract
     EXPLICITLY — reduce-scatter (zero_scatter) + all-gather
     (zero_gather) — and reconciles exactly; no MXL802."""
-    os.environ["MXTPU_ZERO_STAGE"] = "2"
-    net = _mlp()
-    dpt = parallel.DataParallelTrainer(
-        net, SoftmaxCrossEntropyLoss(), "adam",
-        {"learning_rate": 1e-3}, mesh=parallel.make_mesh({"dp": 8}),
-        fuse_step=True)
-    _step_a_trainer(dpt)
-    rep = wire_passes.wire_report()[f"spmd:{net.name}"]
+    rep = _dp8_report(zero_stage=2)
     kinds = {leg["kind"] for leg in rep["legs"]}
     assert "zero_scatter" in kinds and "zero_gather" in kinds
     assert not rep["derived"] and rep["reconciled"]
     assert rep["drift"] <= 0.10, rep
     assert analysis.analyze_wire() == []
+
+
+@pytest.mark.parametrize("stage", [0, 2], ids=["dense_dp8", "zero2_dp8"])
+def test_static_legs_sum_to_the_ring_model(stage):
+    """Bytes on the wire per leg are a count from shapes.  Dense dp8:
+    the per-param gradient legs sum to the ring all-reduce of every
+    trainable byte, 2 (n-1)/n.  ZeRO-2: each leg of the pair moves
+    (n-1)/n of a weight matrix, reduce-scatter in and all-gather out
+    (both matrices gather; only the first is large enough to count as a
+    scatter leg, the rest ride the scalar legs), and the static total
+    equals what the observatory measured."""
+    rep = _dp8_report(zero_stage=stage)
+    by_kind = collections.Counter()
+    for leg in rep["legs"]:
+        by_kind[leg["kind"]] += leg["wire_bytes"]
+    w0, w1 = 4 * 256 * 64, 4 * 10 * 256          # the two weight matrices
+    param_bytes = w0 + 4 * 256 + w1 + 4 * 10
+    if stage == 0:
+        implicit = sum(leg["wire_bytes"] for leg in rep["legs"]
+                       if leg["implicit"])
+        assert implicit == 2 * 7 * param_bytes // 8 == 134470
+        assert by_kind["dp_grad"] == 2 * 7 * (w0 + w1) // 8
+    else:
+        assert by_kind["zero_scatter"] == 7 * w0 // 8
+        assert by_kind["zero_gather"] == 7 * (w0 + w1) // 8
+        assert rep["static_wire_bytes"] == rep["measured_wire_bytes"]
 
 
 def test_declared_precision_fires_mxl801_on_dense_leg():

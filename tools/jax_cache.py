@@ -1,7 +1,7 @@
 """Where this checkout's entry points keep compiled programs.
 
-``chip_smoke.py``, ``bench.py`` and the ``example/`` scripts they stand
-for call :func:`place` BEFORE importing jax.  The rule is one line: a
+``chip_smoke.py``, ``chipbench/run.py`` and the ``example/`` scripts
+they stand for call :func:`place` BEFORE importing jax.  The rule is one line: a
 ``JAX_COMPILATION_CACHE_DIR`` set from outside wins and nothing here
 sets another; unset, the cache is the fixed ``<checkout>/.jax_cache``
 (git-ignored).  The path is part of a cache entry's key, so a

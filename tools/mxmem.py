@@ -17,9 +17,7 @@ data three ways:
 
 Sections: top-N programs by peak bytes (``MXTPU_MEM_REPORT_TOP_N``),
 the per-param HBM table, per-collective traffic, and the live census
-against device capacity.  ``bench.py`` embeds the same report in its
-per-stage ``memory`` block, so a committed bench artifact renders with
-``mxmem render`` too.  See docs/observability.md.
+against device capacity.  See docs/observability.md.
 """
 from __future__ import annotations
 
@@ -133,8 +131,7 @@ def render_report(rep: dict) -> str:
 def cmd_render(args) -> int:
     with open(args.report) as f:
         rep = json.load(f)
-    # a bench stage's memory block and a dump_report artifact share
-    # the schema; a whole bench report is not a memory report
+    # anything without the report's schema is not a memory report
     if "programs" not in rep:
         print(f"mxmem: {args.report} does not look like a memory "
               "report (no 'programs' key)", file=sys.stderr)

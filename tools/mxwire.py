@@ -32,7 +32,7 @@ on the 8-virtual-device CPU mesh first, then audits what it compiled:
         # is the silent-widening class the rule exists for)
 
 ``--model`` picks a shipped demo (``mlp`` | ``llama_tiny``); the
-workload is 3 fused steps, exactly the bench ``wire`` block's shape.
+workload is 3 fused steps.
 """
 from __future__ import annotations
 
