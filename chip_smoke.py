@@ -571,7 +571,9 @@ def phase_serve(shape, seed):
         telemetry.clear_events()
         reqs = [srv.submit(p) for p in prompts]
         decode_ms, rounds = [], 0
-        while srv.sched.active_requests() or srv.sched.queue_depth():
+        # the server reads a round's decode tokens in the NEXT round:
+        # the last round of the loop enqueues nothing and only reads
+        while not srv.idle():
             check(rounds < 64 + len(reqs) * (shape["max_new"] + 2),
                   "serving loop did not drain")
             busy_before = sum(1 for b in srv.sched.buckets

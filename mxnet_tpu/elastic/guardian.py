@@ -331,7 +331,13 @@ def drain_server(server, directory: str) -> dict:
     process can resubmit them (:func:`restore_drained_requests`).
     Returns ``{"requeued", "queued", "manifest"}``."""
     from . import integrity as _integrity
-    residents = server.sched.active_requests()
+    # the server runs one decode ahead of its reads: take what the
+    # device still owes first, so "generated so far" is all of it.  A
+    # poisoned server cannot; the requests it owed their last tokens
+    # hold no slot any more and drain beside the residents
+    if not server.stats()["poisoned"]:
+        server.settle()
+    residents = server.awaiting() + server.sched.active_requests()
     queued = list(server.sched.queue)
     rows = []
     for req in residents + queued:

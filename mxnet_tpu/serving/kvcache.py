@@ -16,7 +16,13 @@ labels gauges and manifests; the shape is what the programs use):
 
 A decoder of identical attention layers declares two ``kv_*`` buffers a
 layer; a hybrid declares what each layer kind holds, and nothing for a
-layer that reads another layer's buffers.  Every dispatch donates the
+layer that reads another layer's buffers.  After the model's buffers the
+pool holds one of its own, the slots' LAST TOKENS (``(slots, 1)``
+float32): the admit program writes a slot's first token there, the
+decode programs read it as their input and write the next one back, so
+no decode waits for the host to have read the one before
+(docs/serving.md, "A round").  It is no row of ``spec`` and no byte of
+``nbytes``: those are the model's.  Every dispatch donates the
 whole pool to the compiled program (the PR 2/3 donation protocol): the
 executable updates each active slot in place and returns the successor
 buffers, so a decode step never doubles state HBM.  ``adopt()`` swaps the
@@ -98,30 +104,42 @@ class KVCachePool:
         self._bufs: List = self._build()
 
     def _build(self):
+        """The spec's buffers, then the last-token vector."""
         from .. import ndarray as nd
         bufs = [nd.zeros(shape, ctx=self.ctx, dtype=dtype)
                 for _name, _kind, shape, dtype in self.spec]
+        bufs.append(nd.zeros((self.slots, 1), ctx=self.ctx,
+                             dtype="float32"))
         if self.sharding is not None:
             import jax
-            for b in bufs:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            for b in bufs[:-1]:
                 b._set_data(jax.device_put(b._data, self.sharding))
+            # the token vector has the slot dim and one more: it takes
+            # the spec's leading entry only
+            bufs[-1]._set_data(jax.device_put(
+                bufs[-1]._data, NamedSharding(
+                    self.sharding.mesh, P(*self.sharding.spec[:1]))))
         return bufs
 
     @property
     def num_buffers(self) -> int:
+        """Buffers a dispatch donates: the spec's and the token vector."""
         return len(self._bufs)
 
     def buffers(self) -> list:
-        """The live state NDArrays, in spec order."""
+        """The live NDArrays, in :meth:`flat`'s order."""
         return list(self._bufs)
 
     def flat(self) -> list:
-        """Flat jax buffers in spec (= donate) order — exactly the slice
-        of the dispatch argument list the donate tuple names."""
+        """Flat jax buffers in donate order (spec order, then the token
+        vector) — exactly the slice of the dispatch argument list the
+        donate tuple names."""
         return [b._data for b in self._bufs]
 
     def nbytes(self) -> int:
-        return sum(int(b._data.nbytes) for b in self._bufs)
+        """Bytes of the model's state (the spec's buffers)."""
+        return sum(int(b._data.nbytes) for b in self._bufs[:-1])
 
     def bytes_by_kind(self) -> dict:
         """{kind: bytes}, from the spec (what the buffers hold, not how a
@@ -138,8 +156,8 @@ class KVCachePool:
         predecessors are already dead)."""
         if len(new_flat) != len(self._bufs):
             raise MXNetError(
-                f"adopt: expected {len(self._bufs)} state buffers, "
-                f"got {len(new_flat)}")
+                f"adopt: expected {len(self._bufs)} buffers (the "
+                f"state and the token vector), got {len(new_flat)}")
         for b, new in zip(self._bufs, new_flat):
             b._set_data(new)
 

@@ -43,7 +43,7 @@ class Request:
     in the queue or in a slot.  ``None`` (default) = no deadline."""
 
     __slots__ = ("id", "prompt", "max_new_tokens", "temperature",
-                 "eos_id", "state", "generated", "bucket", "slot",
+                 "eos_id", "state", "generated", "owed", "bucket", "slot",
                  "submit_t", "admit_t", "first_token_t", "done_t",
                  "evict_reason", "ttl_ms", "deadline")
 
@@ -63,6 +63,10 @@ class Request:
         self.eos_id = None if eos_id is None else int(eos_id)
         self.state = QUEUED
         self.generated: List[int] = []
+        # tokens the device was asked for and the host has not read yet
+        # (docs/serving.md, "A round"): the server stays one decode
+        # dispatch ahead of its reads
+        self.owed = 0
         self.bucket: Optional["Bucket"] = None
         self.slot: Optional[int] = None
         self.submit_t = time.perf_counter()
@@ -89,6 +93,12 @@ class Request:
     @property
     def prompt_len(self) -> int:
         return int(self.prompt.size)
+
+    def room(self) -> int:
+        """Tokens of the budget not yet asked of the device: read ones
+        and owed ones both count against it.  At 0 the request leaves
+        its slot, whatever the owed tokens turn out to be."""
+        return self.max_new_tokens - len(self.generated) - self.owed
 
     def tokens(self) -> np.ndarray:
         """Prompt + generated continuation (what the caller reads
@@ -127,7 +137,6 @@ class Bucket:
         self.offsets = np.zeros(self.slots, np.float32)
         self.active = np.zeros(self.slots, np.float32)
         self.temps = np.zeros(self.slots, np.float32)
-        self.last_tokens = np.zeros(self.slots, np.float32)
 
     @property
     def key(self):
@@ -163,7 +172,7 @@ class Bucket:
         """Move ``src``'s slot ``j`` bookkeeping into THIS bucket's
         slot ``j2`` — the host half of a live slot-count resize
         (``Server.resize_slots``): the request keeps its absolute
-        offset / temperature / last token (its K/V page migrates by
+        offset / temperature (its state and its last token migrate by
         the same index on the device side), only its (bucket, slot)
         address changes."""
         req = src.requests[j]
@@ -176,7 +185,6 @@ class Bucket:
         self.offsets[j2] = src.offsets[j]
         self.active[j2] = 1.0
         self.temps[j2] = src.temps[j]
-        self.last_tokens[j2] = src.last_tokens[j]
 
     def release(self, slot: int):
         """Drop a slot back to free: active-mask off, offset rewound.
@@ -187,7 +195,6 @@ class Bucket:
         self.active[slot] = 0.0
         self.offsets[slot] = 0.0
         self.temps[slot] = 0.0
-        self.last_tokens[slot] = 0.0
         if req is not None:
             req.bucket, req.slot = None, None
 
@@ -277,8 +284,10 @@ class BucketScheduler:
 
     def evict(self, req: Request, reason: str,
               requeue: bool = False) -> bool:
-        """Remove a live request from its slot (or the queue); returns
-        True when anything happened.  A request already in a terminal
+        """Remove a live request from its slot or the queue (one that
+        left its slot by count and waits for its last tokens is in
+        neither, and just changes state); returns True when anything
+        happened.  A request already in a terminal
         state (DONE/EVICTED) is left untouched — evicting a request
         that finished in the same scheduling round must not wipe its
         output or skew the lifecycle counters.  With ``requeue=True``
@@ -294,6 +303,7 @@ class BucketScheduler:
         if requeue:
             req.state = QUEUED
             req.generated = []
+            req.owed = 0
             req.first_token_t = None
             # head, not tail: a requeued request (transient admit
             # failure, recovery) keeps its place ahead of

@@ -23,6 +23,16 @@ moves the buffers and never looks inside a layer:
   with the pool as carry, like ``step_multi``): one host sync per K
   tokens instead of per token.
 
+The host stays ONE decode dispatch ahead of its reads
+(docs/serving.md, "A round"): a slot's last token lives in the pool, on
+the device, where the next decode finds it, so a round enqueues its
+prefills and its decodes first and only then reads what the device owes
+it — the previous round's decode tokens and this round's first tokens.
+What the host needs before a dispatch it knows from COUNTS (offsets, the
+active mask, whose budget is spent); what only a token's VALUE tells
+(an ``eos_id``) it learns one dispatch late, and drops the one token
+decoded past the end.
+
 Sampling is greedy at ``temperature == 0`` and softmax sampling with
 optional server-wide top-k truncation otherwise; the RNG threads the
 CachedOp fold_in scheme — ONE base key a server, drawn from the global
@@ -51,6 +61,7 @@ import os
 import threading
 import time
 import weakref
+from collections import deque
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -82,10 +93,24 @@ def _reset_registry():
         _servers.clear()
 
 
-# inputs of a bucket program after the params and the state: four of the
-# kind's own (prefill: prompt, last_pos, slot, temp; decode: tok, off,
-# active, temp), then the resident base key and the dispatch counter
-_N_INPUTS = 6
+# inputs of a bucket program after the params and the pool (the state,
+# then the slots' last tokens): the kind's own (prefill: prompt,
+# last_pos, slot, temp; decode: off, active, temp), then the resident
+# base key and the dispatch counter
+_N_INPUTS = {"prefill": 6, "decode": 5}
+
+
+class _Owed:
+    """One dispatch whose tokens the host has not read yet: ``out`` is
+    the program's un-donated output, ``take`` the ``(column, request,
+    n)`` rows that say whose tokens it holds (``n`` of its ``k`` steps,
+    the rest were decoded past the request's budget)."""
+
+    __slots__ = ("kind", "bucket", "out", "k", "take", "t0", "ids")
+
+    def __init__(self, kind, bucket, out, k, take, t0, ids):
+        self.kind, self.bucket, self.out, self.k = kind, bucket, out, k
+        self.take, self.t0, self.ids = take, t0, ids
 
 
 def _dispatch_key(key_raw, counter):
@@ -214,6 +239,11 @@ class Server:
                     "steady_misses": 0, "steady_fresh_compiles": 0}
             for b in self.sched.buckets}
         self._poisoned: Optional[str] = None
+        # dispatches whose tokens are still on the device, oldest first:
+        # between rounds at most one decode a bucket (docs/serving.md,
+        # "A round"), and when the last of them was read
+        self._owed: deque = deque()
+        self._read_mark = 0.0
         # the sampler's base key (raw data, on the device), the
         # random._seed_epoch it was drawn under, and the dispatches
         # since: _rng_inputs
@@ -264,9 +294,10 @@ class Server:
             self.lm.state_spec(slots, cache_len, self.cache_dtype), slots)
 
     def _n_state(self, bucket):
-        """Buffers in a bucket's state, from the spec: a resize's shadow
-        bucket has programs before it has a pool."""
-        return len(self._spec_for(bucket.slots, bucket.cache_len))
+        """Buffers in a bucket's pool (the spec's and the token vector),
+        from the spec: a resize's shadow bucket has programs before it
+        has a pool."""
+        return len(self._spec_for(bucket.slots, bucket.cache_len)) + 1
 
     def _state_gauges(self):
         """``mxtpu_serving_state_bytes`` per bucket and state kind (the
@@ -394,10 +425,21 @@ class Server:
 
     def step(self, decode_steps: int = 1) -> dict:
         """One scheduling round: admit every queued request with a free
-        slot (one prefill dispatch each), then advance every non-empty
+        slot (one prefill dispatch each), advance every non-empty
         bucket by ``decode_steps`` tokens (ONE decode dispatch per
         bucket; ``decode_steps > 1`` uses the scan-bulked variant —
-        one host sync per K tokens).  Returns round stats."""
+        one host sync per K tokens), and only then read what the device
+        owes: the PREVIOUS round's decode tokens and this round's first
+        tokens.  This round's decodes stay outstanding, so a token
+        decoded in round n is in ``req.generated`` when round n+1
+        returns (docs/serving.md, "A round").  Returns round stats;
+        ``tokens`` counts the decode tokens that ARRIVED."""
+        self._check_poisoned()
+        self._span_round = n = self._span_round + 1
+        with _span("mxtpu.serving.round", "serving", step_num=n, round=n):
+            return self._round(int(decode_steps))
+
+    def _check_poisoned(self):
         if self._poisoned is not None:
             raise MXNetError(
                 "this Server's KV-cache pages were donated to a "
@@ -405,9 +447,6 @@ class Server:
                 "recover() to rebuild the pools and requeue resident "
                 "requests (docs/serving.md). Original error: "
                 f"{self._poisoned}")
-        self._span_round = n = self._span_round + 1
-        with _span("mxtpu.serving.round", "serving", step_num=n, round=n):
-            return self._round(int(decode_steps))
 
     def _round(self, decode_steps: int) -> dict:
         # deadline sweep FIRST: an expired queued request must not
@@ -436,15 +475,58 @@ class Server:
                                          requeue=True)
                 raise
             admitted += 1
-        tokens = 0
+        # everything enqueued so far is due; this round's decodes go in
+        # behind it and are read by the next round
+        due = len(self._owed)
         for bucket in self.sched.buckets:
             if bucket.n_active() == 0:
                 continue
-            tokens += self._decode(bucket, decode_steps)
+            self._decode(bucket, decode_steps)
+        tokens = self._collect(due)
         self._update_gauges()
         return {"admitted": admitted, "tokens": tokens,
                 "active": len(self.sched.active_requests()),
                 "queued": self.sched.queue_depth()}
+
+    def _collect(self, n: Optional[int] = None) -> int:
+        """Read the ``n`` oldest owed dispatches (default: all of them);
+        returns the decode tokens that arrived."""
+        tokens = 0
+        for _ in range(len(self._owed) if n is None else n):
+            rec = self._owed[0]
+            got = self._read(rec)
+            self._owed.popleft()
+            if rec.kind == "decode":
+                tokens += got
+        return tokens
+
+    def settle(self) -> int:
+        """Read every token the device still owes (the outstanding
+        decodes' included) without enqueueing anything: afterwards
+        ``req.generated`` is complete and nothing is in flight.  What a
+        caller does before it looks at the slots from outside a round
+        (``resize_slots``, ``save_signature``, a preemption drain).
+        Returns the decode tokens that arrived."""
+        self._check_poisoned()
+        tokens = self._collect()
+        self._update_gauges()
+        return tokens
+
+    def idle(self) -> bool:
+        """Nothing queued, nothing resident and no token owed: every
+        submitted request has finished or was evicted."""
+        return not (self._owed or self.sched.queue_depth()
+                    or self.sched.active_requests())
+
+    def awaiting(self) -> List[Request]:
+        """Requests that left their slot by count and still wait for
+        their last tokens, oldest first."""
+        out = {}
+        for rec in self._owed:
+            for _col, req, _n in rec.take:
+                if req.state == ACTIVE and req.bucket is None:
+                    out[req.id] = req
+        return list(out.values())
 
     def run(self, decode_steps: int = 1,
             max_rounds: Optional[int] = None) -> int:
@@ -453,11 +535,10 @@ class Server:
         budget derived from the workload)."""
         if max_rounds is None:
             pending = len(self.sched.active_requests()) \
-                + self.sched.queue_depth()
+                + len(self.awaiting()) + self.sched.queue_depth()
             max_rounds = 16 + pending * (self.max_new_tokens + 2)
         rounds = 0
-        while (self.sched.active_requests()
-               or self.sched.queue_depth()):
+        while not self.idle():
             if rounds >= max_rounds:
                 raise MXNetError(
                     f"serving run() exceeded {max_rounds} rounds with "
@@ -478,14 +559,16 @@ class Server:
 
     def evict(self, req: Request, reason: str = "user",
               requeue: bool = False) -> bool:
-        """Remove a live request (slot or queue); returns True when it
-        was live (a request that already finished is left untouched —
-        no event, no counter).  Retained ``request_evicted`` event +
-        counter; ``requeue=True`` restarts it from its prompt (the
-        recovery path)."""
+        """Remove a live request (slot, queue, or waiting for its last
+        tokens); returns True when it was live (a request that already
+        finished is left untouched — no event, no counter).  Tokens the
+        device still owes it are dropped, not delivered.  Retained
+        ``request_evicted`` event + counter; ``requeue=True`` restarts
+        it from its prompt (the recovery path)."""
         from .. import telemetry
         if not self.sched.evict(req, reason, requeue=requeue):
             return False
+        self._disown(req)
         telemetry.counter("mxtpu_serving_requests_evicted_total",
                           "requests evicted from the serving plane"
                           ).inc()
@@ -498,18 +581,23 @@ class Server:
 
     def recover(self) -> int:
         """Rebuild every poisoned (or healthy) KV-cache pool and
-        requeue resident requests; clears the poison latch.  Returns
-        the number of requests requeued.  The serving twin of the
-        trainers' ``recover(manager)`` — state here is cache pages
-        rebuilt by replaying host-owned prompts, so no checkpoint is
-        involved."""
+        requeue every request that holds a slot or is owed tokens (what
+        the device owes is lost with the pool); clears the poison
+        latch.  Returns the number of requests requeued.  The serving
+        twin of the trainers' ``recover(manager)`` — state here is
+        cache pages rebuilt by replaying host-owned prompts, so no
+        checkpoint is involved."""
         from ..elastic.manager import record_recovery
         t0 = time.perf_counter()
         was_poisoned = self._poisoned is not None
         requeued = 0
         # reverse: evict(requeue=True) pushes to the queue HEAD, so
-        # iterating backwards preserves the residents' relative order
-        for req in reversed(self.sched.active_requests()):
+        # iterating backwards leaves them in the order they were
+        # submitted in
+        live = sorted(self.awaiting() + self.sched.active_requests(),
+                      key=lambda r: r.id)
+        self._owed.clear()
+        for req in reversed(live):
             self.evict(req, reason="recover", requeue=True)
             requeued += 1
         for pool in self._pools.values():
@@ -518,7 +606,6 @@ class Server:
             b.offsets[:] = 0.0
             b.active[:] = 0.0
             b.temps[:] = 0.0
-            b.last_tokens[:] = 0.0
         self._poisoned = None
         record_recovery("serving", time.perf_counter() - t0,
                         was_poisoned, name=self.name,
@@ -546,16 +633,17 @@ class Server:
           compiles (the variants land pre-warmed in the steady
           accounting MXL601 audits).  Compile time is not downtime —
           the old buckets could still serve here.
-        * **drain** — serving dispatches are synchronous, so between
-          scheduling rounds nothing is in flight; this is the settled
-          boundary (fault point ``resize_drain``) and where the
-          downtime clock starts.
+        * **drain** — between rounds one decode a bucket is in flight:
+          its tokens are read here (:meth:`settle`), after which
+          nothing is; this is the settled boundary (fault point
+          ``resize_drain``) and where the downtime clock starts.
         * **migrate** — resident state gathers into the new pool by
-          slot index (one ``take`` per state buffer; generated tokens/
-          offsets are host-owned and ride along), so live requests
-          keep their progress.  On a shrink, residents beyond the new
-          capacity are evicted-with-requeue (they replay from their
-          host-owned prompts — the documented recovery semantics).
+          slot index (one ``take`` per pool buffer, the slots' last
+          tokens among them; generated tokens/offsets are host-owned
+          and ride along), so live requests keep their progress.
+          On a shrink, residents beyond the new capacity are
+          evicted-with-requeue (they replay from their host-owned
+          prompts — the documented recovery semantics).
         * **swap** — buckets/pools/identities rebind; a failure after
           migration started crash-heals onto the NEW slot count with
           zeroed pages and every resident requeued (``recovery``
@@ -619,14 +707,14 @@ class Server:
                 NS = self._pools[b.key].num_buffers
                 avals = list(engine.persist.sig_from_json(v["avals"]))
                 for i, a in enumerate(avals):
-                    # the slot dim is dim 0 of every state buffer and —
-                    # for decode — of the 4 per-slot extras (tok/off/
+                    # the slot dim is dim 0 of every pool buffer and —
+                    # for decode — of the 3 per-slot extras (off/
                     # active/temp); everything else (params, prefill
                     # extras, the RNG key and counter) is
                     # slot-count-independent
                     per_slot = (P <= i < P + NS) or (
                         kind == "decode" and
-                        P + NS <= i < P + NS + 4)
+                        P + NS <= i < P + NS + 3)
                     if per_slot and len(a) == 2 and a[0]:
                         avals[i] = ((new_slots,) + tuple(a[0][1:]),
                                     a[1])
@@ -642,12 +730,13 @@ class Server:
                     "suffix": new_suffix, "kind": kind, "k": k,
                     "donate": [int(i) for i in v["donate"]],
                     "avals": engine.persist.sig_to_json(tuple(avals))}
-            # DRAIN: the settled boundary (nothing in flight between
-            # rounds); the downtime clock starts here — after the
+            # DRAIN: read what the device owes, after which nothing is
+            # in flight; the downtime clock starts here — after the
             # pre-warm, whose compile time is NOT downtime
             phase = "drain"
             _faults.maybe_fire("resize_drain")
             t_drain = time.perf_counter()
+            self.settle()
         except Exception as e:
             # pre-migration failure: the server is untouched on the
             # old configuration — record the abort (the train
@@ -846,6 +935,9 @@ class Server:
             raise MXNetError(
                 "save_signature: serve at least one request first "
                 "(no compiled variants recorded)")
+        # a dispatch that failed on the device says so at its read: no
+        # manifest of programs whose last run is still unread
+        self.settle()
         manifest = {
             "format": 1, "kind": "mxtpu_serving_plane",
             "fingerprint": engine.persist.fingerprint(),
@@ -941,18 +1033,24 @@ class Server:
             bucket = self._bucket_for_suffix(str(v.get("suffix")))
             if bucket is None:
                 continue                    # named and refused below
-            have = len(v.get("avals", ()))
-            want = len(self._param_nds) + self._n_state(bucket) + _N_INPUTS
+            P, NS = len(self._param_nds), self._n_state(bucket)
+            have = (len(v.get("avals", ())),
+                    [int(i) for i in v.get("donate", ())])
+            want = (P + NS + _N_INPUTS.get(str(v.get("kind")), 0),
+                    list(range(P, P + NS)))
             if have != want:
-                # a manifest from before the key was resident: its
-                # programs are ones no dispatch calls any more, so
-                # none is pre-compiled
+                # a manifest of an older call shape: its programs are
+                # ones no dispatch calls any more, so none is
+                # pre-compiled
                 return _fail(
-                    f"variant {v.get('suffix')!r} records {have} inputs "
-                    f"where the program takes {want}: the RNG key input "
-                    "is now the server's resident base key and a "
-                    "dispatch counter, not a key made per dispatch "
-                    "(re-save the signature)")
+                    f"variant {v.get('suffix')!r} records {have[0]} "
+                    f"inputs, {len(have[1])} of them donated, where the "
+                    f"program takes {want[0]} and donates {NS}: the "
+                    "slots' last tokens are a buffer of the pool now "
+                    "(`last_token`, after the state: written by the "
+                    "programs, donated with it), and the RNG key input "
+                    "is the server's resident base key and a dispatch "
+                    "counter (re-save the signature)")
         try:
             import jax
             self._persist_base = m["persist_base"]
@@ -1045,9 +1143,13 @@ class Server:
             from ..gluon import block as block_mod
             from ..ndarray.ndarray import NDArray
             param_vals = list(flat[:P])
-            tok, off, active, temp, key_raw, counter = flat[P + NS:]
+            # the pool's last buffer is the slots' last tokens: this
+            # step's input, wherever the host is with its reads
+            tok = flat[P + NS - 1]
+            off, active, temp, key_raw, counter = flat[P + NS:]
             with block_mod.tracing_scope(params, param_vals):
-                shells = [NDArray(c, ctx=ctx) for c in flat[P:P + NS]]
+                shells = [NDArray(c, ctx=ctx)
+                          for c in flat[P:P + NS - 1]]
                 logits = lm.decode_step(
                     NDArray(tok, ctx=ctx), shells,
                     NDArray(off, ctx=ctx))._data
@@ -1056,7 +1158,9 @@ class Server:
             keys = jax.vmap(lambda i: jax.random.fold_in(k0, i))(
                 jnp.arange(N))
             nxt = self._pick(logits, temp, active, keys)
-            return (nxt,) + new_caches
+            # the tokens twice: an output of their own that the host
+            # may read late, and the pool's successor
+            return (nxt,) + new_caches + (nxt.reshape(N, 1),)
 
         return decode_pure
 
@@ -1073,8 +1177,9 @@ class Server:
             from ..gluon import block as block_mod
             from ..ndarray.ndarray import NDArray
             param_vals = list(flat[:P])
-            cache_vals = tuple(flat[P:P + NS])
-            tok, off, active, temp, key_raw, counter = flat[P + NS:]
+            cache_vals = tuple(flat[P:P + NS - 1])
+            tok = flat[P + NS - 1]
+            off, active, temp, key_raw, counter = flat[P + NS:]
             k0 = _dispatch_key(key_raw, counter)
 
             def body(carry, step_i):
@@ -1095,10 +1200,10 @@ class Server:
                 return (nxt.reshape(N, 1), off_c + active,
                         new_caches), nxt
 
-            (_, _, caches_f), toks = lax.scan(
+            (tok_f, _, caches_f), toks = lax.scan(
                 body, (tok, off, cache_vals),
                 jnp.arange(k_steps))
-            return (toks,) + caches_f          # toks: (K, N)
+            return (toks,) + caches_f + (tok_f,)   # toks: (K, N)
 
         return decode_multi_pure
 
@@ -1129,22 +1234,26 @@ class Server:
                 lax.dynamic_update_slice(
                     c, t._data.astype(c.dtype),
                     (slot_i,) + (zero,) * (c.ndim - 1))
-                for c, t in zip(flat[P:P + NS], tmp)]
+                for c, t in zip(flat[P:P + NS - 1], tmp)]
             k0 = _dispatch_key(key_raw, counter)
             keys = jax.vmap(lambda i: jax.random.fold_in(k0, i))(
                 slot_i.reshape(1))
             nxt = self._pick(logits, temp, jnp.ones((1,)), keys)
-            return (nxt,) + tuple(new_caches)
+            # the first token goes where the slot's next decode reads it
+            toks = lax.dynamic_update_slice(
+                flat[P + NS - 1], nxt.reshape(1, 1), (slot_i, zero))
+            return (nxt,) + tuple(new_caches) + (toks,)
 
         return prefill_pure
 
     # -- dispatch ----------------------------------------------------------
     def _dispatch(self, bucket, kind: str, extra, k: int = 0, **ids):
         """One engine dispatch of a bucket program with the pool
-        donated; returns the non-cache outputs with the successor pool
-        adopted.  Post-donation failures poison the bucket (the
-        recovery half lives in :meth:`recover`).  ``ids`` (``req`` of
-        an admission) go onto the dispatch's profiler span."""
+        donated; returns the tokens output (still on the device: the
+        caller owes its read) with the successor pool adopted.
+        Post-donation failures poison the bucket (the recovery half
+        lives in :meth:`recover`).  ``ids`` (``req`` of an admission)
+        go onto the dispatch's profiler span."""
         with _span("mxtpu.serving.dispatch", "serving", kind=kind, **ids):
             return self._dispatch_impl(bucket, kind, extra, k)
 
@@ -1191,23 +1300,7 @@ class Server:
                                              persist_name=persist_name)
             except Exception as e:
                 if pool.consumed():
-                    pool.poison(repr(e))
-                    self._poisoned = repr(e)
-                    telemetry.counter(
-                        "mxtpu_poisons_total",
-                        "post-donation failures (training state lost)"
-                        ).inc()
-                    telemetry.record_event(
-                        "poison", where="serving", name=name,
-                        error=repr(e)[:500])
-                    telemetry.auto_dump(
-                        reason=f"serving_poisoned:{name}")
-                    raise MXNetError(
-                        "serving dispatch failed AFTER the KV-cache "
-                        "pool was donated; call Server.recover() to "
-                        "rebuild the pages and requeue resident "
-                        "requests (docs/serving.md). Original error: "
-                        f"{e!r}") from e
+                    self._poison(pool, name, e)
                 raise
         with _span("mxtpu.serving.state_adopt", "serving"):
             n_out = len(res) - NS
@@ -1239,7 +1332,26 @@ class Server:
                 stats["steady_dispatches"] += 1
                 stats["steady_misses"] += m1 - m0
                 stats["steady_fresh_compiles"] += f1 - f0
-        return res[:n_out]
+        return res[0]
+
+    def _poison(self, pool, name: str, e: Exception):
+        """Latch the post-donation failure of the dispatch ``name`` and
+        raise: the pool's buffers went into a program that died, whether
+        that showed when it was enqueued or when its tokens were read."""
+        from .. import telemetry
+        pool.poison(repr(e))
+        self._poisoned = repr(e)
+        telemetry.counter(
+            "mxtpu_poisons_total",
+            "post-donation failures (training state lost)").inc()
+        telemetry.record_event(
+            "poison", where="serving", name=name, error=repr(e)[:500])
+        telemetry.auto_dump(reason=f"serving_poisoned:{name}")
+        raise MXNetError(
+            "serving dispatch failed AFTER the KV-cache pool was "
+            "donated; call Server.recover() to rebuild the pages and "
+            "requeue resident requests (docs/serving.md). Original "
+            f"error: {e!r}") from e
 
     def _rng_inputs(self):
         """The sampler's two inputs of one dispatch: the base key and
@@ -1267,6 +1379,8 @@ class Server:
         return self._key_base, np.asarray(n, np.uint32)
 
     def _admit(self, bucket, slot: int, req: Request):
+        """Enqueue one admission's prefill; its first token is read
+        after this round's decodes are enqueued (:meth:`_read`)."""
         with _span("mxtpu.serving.admit", "serving", req=req.id,
                    bucket=bucket.prompt_len, slot=slot):
             self._admit_impl(bucket, slot, req)
@@ -1291,76 +1405,137 @@ class Server:
         # propagate to step(), which releases THIS placement and the
         # ones behind it back to the queue in FIFO order
         out = self._dispatch(bucket, "prefill", extra, req=req.id)
-        with _span("mxtpu.serving.token_read", "serving", req=req.id):
-            tok = int(np.asarray(out[0])[0])  # host sync: TTFT is real
         with _span("mxtpu.serving.bookkeeping", "serving", req=req.id):
             telemetry.counter("mxtpu_serving_prefills_total",
                               "admission prefill dispatches").inc()
-            bucket.last_tokens[slot] = float(tok)
-            self._bucket_stats[bucket.key]["tokens"] += 1
-            telemetry.counter(
-                "mxtpu_serving_tokens_total",
-                "tokens generated by the serving plane").inc()
-            finished = req.push_token(tok)
-            telemetry.histogram(
-                "mxtpu_serving_ttft_seconds",
-                "submit -> first generated token (s)").observe(
-                req.first_token_t - req.submit_t)
-            telemetry.histogram(
-                "mxtpu_serving_prefill_seconds",
-                "one admission (prefill dispatch + first token) (s)"
-                ).observe(time.perf_counter() - t0)
-            if finished:
-                self._finish(req)
+            self._owe("prefill", bucket, out, 1, [(0, slot, req)], t0,
+                      {"req": req.id})
 
-    def _decode(self, bucket, decode_steps: int) -> int:
+    def _decode(self, bucket, decode_steps: int):
+        """Enqueue one decode of the bucket; nothing is read here."""
         with _span("mxtpu.serving.decode", "serving",
                    bucket=bucket.prompt_len, active=bucket.n_active()):
-            return self._decode_impl(bucket, decode_steps)
+            self._decode_impl(bucket, decode_steps)
 
-    def _decode_impl(self, bucket, decode_steps: int) -> int:
+    def _decode_impl(self, bucket, decode_steps: int):
         from .. import telemetry
         t0 = time.perf_counter()
         k = max(1, int(decode_steps))
         with _span("mxtpu.serving.build_inputs", "serving"):
-            active_snap = bucket.active.copy()
-            extra = [bucket.last_tokens.reshape(bucket.slots, 1).copy(),
-                     bucket.offsets.copy(), active_snap.copy(),
-                     bucket.temps.copy(),
+            active = bucket.active.copy()
+            extra = [bucket.offsets.copy(), active, bucket.temps.copy(),
                      *self._rng_inputs()]
+        ahead = any(rec.kind == "decode" and rec.bucket is bucket
+                    for rec in self._owed)
         out = self._dispatch(bucket, "decode", extra,
                              k=0 if k == 1 else k)
-        # the host WAITS for the device here: the one span of a round
-        # in which the chip is supposed to be busy
-        with _span("mxtpu.serving.token_read", "serving"):
-            toks = np.asarray(out[0])
         with _span("mxtpu.serving.bookkeeping", "serving"):
-            if toks.ndim == 1:
-                toks = toks[None, :]               # (K, N)
+            if ahead:
+                telemetry.counter(
+                    "mxtpu_serving_decodes_ahead_total",
+                    "decode dispatches enqueued while the same bucket's "
+                    "previous decode was unread").inc()
             # host bookkeeping mirrors the in-graph carry: offsets
             # advance K per slot ACTIVE AT DISPATCH (release() rewinds
-            # finishers)
-            bucket.offsets += k * active_snap
-            produced = 0
-            for row in toks:
-                for j in np.nonzero(active_snap > 0)[0]:
-                    req = bucket.requests[int(j)]
-                    if req is None or req.state != ACTIVE:
-                        continue           # finished mid-K: overrun rows
-                    tok = int(row[int(j)])
-                    bucket.last_tokens[int(j)] = float(tok)
+            # the ones that leave)
+            bucket.offsets += k * active
+            slots = [int(j) for j in np.nonzero(active > 0)[0]]
+            self._owe("decode", bucket, out, k,
+                      [(j, j, bucket.requests[j]) for j in slots], t0, {})
+
+    def _owe(self, kind, bucket, out, k, rows, t0, ids):
+        """The count half of a dispatch's bookkeeping, at dispatch time:
+        each ``(column, slot, request)`` row is owed as many of the
+        dispatch's ``k`` tokens as its budget still holds, and a request
+        whose budget is spent thereby leaves its slot NOW (the next
+        admission into it runs after this dispatch on the device
+        anyway); it is ``done`` when the tokens are read."""
+        take = []
+        for col, slot, req in rows:
+            n = min(k, req.room())
+            req.owed += n
+            take.append((col, req, n))
+            if req.room() == 0:
+                bucket.release(slot)
+        self._owed.append(_Owed(kind, bucket, out, k, take, t0, ids))
+
+    def _disown(self, req: Request):
+        """Strike ``req`` from the owed dispatches: whatever the device
+        still decodes for it is dropped at the read."""
+        dropped = 0
+        for rec in self._owed:
+            dropped += sum(n for _c, r, n in rec.take if r is req)
+            rec.take = [t for t in rec.take if t[1] is not req]
+        req.owed = 0
+        self._overrun(dropped)
+
+    def _overrun(self, n: int):
+        if n:
+            from .. import telemetry
+            telemetry.counter(
+                "mxtpu_serving_overrun_tokens_total",
+                "tokens decoded for a request that had already ended "
+                "(eos, eviction, a K-step dispatch past the budget): "
+                "dropped at the read").inc(n)
+
+    def _read(self, rec: _Owed) -> int:
+        """Read one owed dispatch's tokens and do the VALUE half of its
+        bookkeeping; returns the tokens delivered.  The host waits for
+        the device here and nowhere else."""
+        first = rec.kind == "prefill"
+        with _span("mxtpu.serving.admit" if first
+                   else "mxtpu.serving.decode", "serving",
+                   bucket=rec.bucket.prompt_len, **rec.ids):
+            return self._read_impl(rec, first)
+
+    def _read_impl(self, rec: _Owed, first: bool) -> int:
+        from .. import telemetry
+        # the bracket is the guardian plane's heartbeat, as around the
+        # dispatch: a device that hangs shows HERE, where the host waits
+        with _span("mxtpu.serving.token_read", "serving", **rec.ids), \
+                telemetry.step_owner(self, "serving_token_read"):
+            try:
+                toks = np.asarray(rec.out)      # host sync: TTFT is real
+            except Exception as e:
+                self._poison(
+                    self._pools[rec.bucket.key], self.name + self._suffix(
+                        rec.bucket, rec.kind, 0 if rec.k == 1 else rec.k), e)
+        with _span("mxtpu.serving.bookkeeping", "serving", **rec.ids):
+            toks = toks.reshape(rec.k, -1)                  # (K, columns)
+            produced = dropped = 0
+            for i, row in enumerate(toks):
+                for col, req, n in rec.take:
+                    if i >= n or req.state != ACTIVE:
+                        # past its budget, or ended by a token read
+                        # since the dispatch: overrun rows
+                        dropped += 1
+                        continue
+                    req.owed -= 1
                     produced += 1
-                    if req.push_token(tok):
+                    if req.push_token(int(row[col])):
                         self._finish(req)
-            dt = time.perf_counter() - t0
-            telemetry.histogram("mxtpu_serving_decode_seconds",
-                                "one decode dispatch wall clock (s)"
-                                ).observe(dt)
+                    if first:
+                        telemetry.histogram(
+                            "mxtpu_serving_ttft_seconds",
+                            "submit -> first generated token (s)"
+                            ).observe(req.first_token_t - req.submit_t)
+            self._overrun(dropped)
             if produced:
                 telemetry.counter(
                     "mxtpu_serving_tokens_total",
                     "tokens generated by the serving plane").inc(produced)
-            self._bucket_stats[bucket.key]["tokens"] += produced
+            self._bucket_stats[rec.bucket.key]["tokens"] += produced
+            # what this dispatch added to the round: from its enqueue,
+            # or from the read before it if the host was still busy
+            # with that one
+            now = time.perf_counter()
+            dt = now - max(rec.t0, self._read_mark)
+            self._read_mark = now
+            hist = ("mxtpu_serving_prefill_seconds",
+                    "one admission (prefill dispatch + first token) (s)") \
+                if first else ("mxtpu_serving_decode_seconds",
+                               "one decode dispatch wall clock (s)")
+            telemetry.histogram(*hist).observe(dt)
             return produced
 
     def _finish(self, req: Request):

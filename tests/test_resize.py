@@ -425,12 +425,15 @@ def test_serving_slot_grow_shrink_churn(lm):
     srv.step()
     srv.step()
     gen_before = (list(r1.generated), list(r2.generated))
+    assert srv._owed                          # a decode is outstanding
     rec = srv.resize_slots(4)
     assert (rec["slots_from"], rec["slots_to"]) == (2, 4)
     assert rec["migrated"] == 2 and rec["requeued"] == 0
     assert rec["prewarmed_variants"] == 2     # prefill + decode
-    # migrated residents kept their progress...
-    assert (list(r1.generated), list(r2.generated)) == gen_before
+    # migrated residents kept their progress, and the drain read the
+    # one token a request the device still owed...
+    assert not srv._owed
+    assert (r1.generated[:-1], r2.generated[:-1]) == gen_before
     # ...and finish bit-identical to the unresized run, under churn,
     # with ZERO compiles post-swap (the pre-warm contract)
     m0, f0 = engine.compile_counts()

@@ -100,8 +100,11 @@ def test_queue_bound():
 def test_kvcache_pool_contract(net):
     pool = KVCachePool(net, slots=2, cache_len=8)
     flat = pool.flat()
-    assert len(flat) == 2 * len(net.model.layers)
+    # K and V a layer, then the slots' last tokens (the pool's own)
+    assert len(flat) == 2 * len(net.model.layers) + 1 == pool.num_buffers
     assert flat[0].shape == (2, 8, 2, 16)    # tiny GQA: 2 kv heads, d 16
+    assert flat[-1].shape == (2, 1) and str(flat[-1].dtype) == "float32"
+    assert len(pool.spec) == len(flat) - 1   # no row of the model's spec
     with pytest.raises(MXNetError, match="adopt"):
         pool.adopt(flat[:1])
     pool.poison("boom")
@@ -251,13 +254,18 @@ def test_decode_multi_parity_and_bulking(net):
     s3 = Server(net, buckets=[(2, 8)], max_new_tokens=15)
     s3.generate([_prompt(15, 4)], decode_steps=7)  # warm all programs
     r = s3.submit(_prompt(16, 4))
-    s3.step(decode_steps=7)          # admit + first bulk: 8 tokens
-    assert len(r.generated) == 8
+    s3.step(decode_steps=7)          # admit + first bulk: the first
+    assert len(r.generated) == 1     # token is read, 7 are owed
     d0 = engine.dispatch_count()
-    s3.step(decode_steps=7)          # steady: 7 tokens, ONE dispatch
+    st = s3.step(decode_steps=7)     # steady: ONE dispatch, and the
+    assert engine.dispatch_count() - d0 == 1     # first bulk arrives
+    assert len(r.generated) == 8 and st["tokens"] == 7
+    # the budget was spent at that dispatch: the slot is free by COUNT
+    assert r.state == "active" and s3.sched.buckets[0].n_active() == 0
+    st = s3.step(decode_steps=7)     # nothing to enqueue: a read only
     assert engine.dispatch_count() - d0 == 1
-    assert len(r.generated) == 15
-    assert r.state == "done"
+    assert len(r.generated) == 15 and st["tokens"] == 7
+    assert r.state == "done" and s3.idle()
 
 
 # -- warm start (PR 5 acceptance applied to serving) --------------------------
@@ -439,29 +447,39 @@ def _inside(child, parent):
         child["ts"] + child["dur"] <= parent["ts"] + parent["dur"]
 
 
-@pytest.mark.time_limit(120)
-def test_round_spans_nest_and_share_the_request_id(net):
-    """One ``step()`` that admits one request and decodes another:
-    round > admit / decode > dispatch > engine.execute, every span of
-    the admission carries its one ``req`` id, and with the profiler
-    stopped (and no jax session) a round appends nothing."""
+def _recorded_spans(fn):
+    """Run ``fn`` with the profiler's sink on; its complete ('X') events."""
     from mxnet_tpu import profiler
-    srv = Server(net, buckets=[(2, 8)], max_new_tokens=6)
-    srv.submit(_prompt(30, 4))
-    srv.step()                          # compiles prefill + decode
-    req = srv.submit(_prompt(31, 5))
     profiler.set_state("run")
     try:
-        srv.step()
+        fn()
     finally:
         profiler.set_state("stop")
     with profiler._lock:
         events = [e for e in profiler._events if e["ph"] == "X"]
         profiler._events.clear()
+    return events
+
+
+@pytest.mark.time_limit(120)
+def test_round_spans_nest_and_share_the_request_id(net):
+    """One ``step()`` that admits one request and decodes another:
+    round > admit / decode > dispatch > engine.execute for what it
+    ENQUEUES, then round > decode / admit > token_read for what it
+    READS (the previous round's decode, this round's first token);
+    every span of the admission carries its one ``req`` id, and with
+    the profiler stopped (and no jax session) a round appends nothing."""
+    from mxnet_tpu import profiler
+    srv = Server(net, buckets=[(2, 8)], max_new_tokens=6)
+    srv.submit(_prompt(30, 4))
+    srv.step()                          # compiles prefill + decode
+    req = srv.submit(_prompt(31, 5))
+    events = _recorded_spans(srv.step)
 
     def named(name, within=None):
-        return [e for e in events if e["name"] == name
-                and (within is None or _inside(e, within))]
+        return sorted((e for e in events if e["name"] == name
+                       and (within is None or _inside(e, within))),
+                      key=lambda e: e["ts"])
 
     rnd, = named("mxtpu.serving.round")
     assert rnd["args"] == {"round": 2} and rnd["cat"] == "serving"
@@ -469,14 +487,20 @@ def test_round_spans_nest_and_share_the_request_id(net):
                for e in events if e is not rnd)
     assert len(named("mxtpu.serving.expire", rnd)) == 1
     assert len(named("mxtpu.serving.schedule", rnd)) == 1
-    admit, = named("mxtpu.serving.admit", rnd)
-    decode, = named("mxtpu.serving.decode", rnd)
+    admit, first = named("mxtpu.serving.admit", rnd)
+    decode, late = named("mxtpu.serving.decode", rnd)
     assert admit["args"] == {"req": req.id, "bucket": 8, "slot": 1}
     assert decode["args"] == {"bucket": 8, "active": 2}
-    assert admit["ts"] + admit["dur"] <= decode["ts"]
+    assert late["args"] == {"bucket": 8}
+    assert first["args"] == {"bucket": 8, "req": req.id}
+    # everything is enqueued before anything is read
+    order = [admit, decode, late, first]
+    assert all(a["ts"] + a["dur"] <= b["ts"]
+               for a, b in zip(order, order[1:]))
     for parent, kind in ((admit, "prefill"), (decode, "decode")):
-        for leaf in ("build_inputs", "token_read", "bookkeeping"):
+        for leaf in ("build_inputs", "bookkeeping"):
             assert len(named("mxtpu.serving." + leaf, parent)) == 1
+        assert named("mxtpu.serving.token_read", parent) == []
         dispatch, = named("mxtpu.serving.dispatch", parent)
         assert dispatch["args"]["kind"] == kind
         assert len(named("mxtpu.serving.flatten", dispatch)) == 1
@@ -486,13 +510,20 @@ def test_round_spans_nest_and_share_the_request_id(net):
         execute, = named("mxtpu.engine.execute", dispatch)
         assert lookup["args"]["op"] == execute["args"]["op"]
         assert execute["args"]["op"].endswith(kind)
-    for leaf in ("build_inputs", "dispatch", "token_read", "bookkeeping"):
-        assert named("mxtpu.serving." + leaf,
-                     admit)[0]["args"]["req"] == req.id
+    for parent in (late, first):
+        for leaf in ("token_read", "bookkeeping"):
+            assert len(named("mxtpu.serving." + leaf, parent)) == 1
+        assert named("mxtpu.serving.dispatch", parent) == []
+    for parent, leaves in ((admit, ("build_inputs", "dispatch",
+                                    "bookkeeping")),
+                           (first, ("token_read", "bookkeeping"))):
+        for leaf in leaves:
+            assert named("mxtpu.serving." + leaf,
+                         parent)[0]["args"]["req"] == req.id
     assert {e["args"]["req"] for e in events
             if "req" in e.get("args", {})} == {req.id}
     assert not any("req" in e.get("args", {}) for e in events
-                   if _inside(e, decode))
+                   if _inside(e, decode) or _inside(e, late))
 
     srv.step()                          # both sinks off
     assert profiler._events == []
@@ -512,7 +543,10 @@ def test_admit_stamp_and_queue_wait_histogram(net):
     assert second.admit_t is None
     assert first.submit_t <= first.admit_t <= first.first_token_t
     srv.run()
-    assert second.admit_t > first.done_t > first.admit_t
+    # the slot is free once the first request's LAST token is enqueued:
+    # the second is admitted before that token is read
+    assert first.done_t > second.admit_t > first.first_token_t
+    assert second.first_token_t > first.done_t
     hist = telemetry.histogram(
         "mxtpu_serving_queue_wait_seconds",
         "submit -> start of the admission (s)").summary()
@@ -642,7 +676,7 @@ def _reference_tokens(lm, prompt, new):
 
 def test_hybrid_pool_follows_the_models_spec(hybrid):
     pool = KVCachePool(hybrid, slots=3, cache_len=20, dtype="bfloat16")
-    assert pool.num_buffers == len(hybrid.state_spec(3, 20)) == 12
+    assert pool.num_buffers - 1 == len(hybrid.state_spec(3, 20)) == 12
     kinds = {k for _n, k, _s, _d in pool.spec}
     assert kinds == {"conv", "ssm", "kv_window", "kv_full"}
     for (_n, kind, shape, dtype), buf in zip(pool.spec, pool.flat()):
@@ -735,7 +769,7 @@ def test_hybrid_resize_slots_migrates_every_kind(hybrid):
     assert rec["migrated"] == 2 and rec["requeued"] == 0
     assert rec["prewarmed_variants"] == 2
     assert [tuple(b.shape)[0] for b in srv._pools[(3, 16)].flat()] \
-        == [3] * 12
+        == [3] * 13
     m0, f0 = engine.compile_counts()
     srv.run()
     assert engine.compile_counts() == (m0, f0)     # the pre-warm held
@@ -948,11 +982,14 @@ def test_mixed_batch_greedy_exact_sampled_seeded(lm_bucket, decode_steps):
 
 def test_warm_start_key_input_round_trip_and_old_manifest(
         lm_bucket, tmp_path):
-    """A manifest of today's call shape (state, four inputs, the base
-    key, the counter) warm-starts with 0 fresh compiles; one from before
-    the key was resident (a key made per dispatch as the LAST input)
-    fails open: False, a ``warm_start`` event that names the key input,
-    nothing pre-compiled, and the next step cold and correct."""
+    """A manifest of today's call shape (the pool: state and last
+    tokens, all donated; the kind's own inputs; the base key; the
+    counter) warm-starts with 0 fresh compiles.  One from before the key
+    was resident (a key made per dispatch as the LAST input) and one
+    from before the last tokens lived in the pool (a host-made ``tok``
+    input, the state alone donated) fail open: False, a ``warm_start``
+    event that names the key input and the new pool buffer, nothing
+    pre-compiled, and the next step cold and correct."""
     import json
     lm, bucket = lm_bucket
     prompts = [_prompt(67, 5), _prompt(68, 7)]
@@ -962,10 +999,15 @@ def test_warm_start_key_input_round_trip_and_old_manifest(
     man = str(tmp_path / "serving.json")
     srv.save_signature(man)
     m = json.load(open(man))
-    n_in = len(srv._param_nds) + srv._pools[bucket].num_buffers + 6
+    n_par, n_pool = len(srv._param_nds), srv._pools[bucket].num_buffers
     assert {v["kind"] for v in m["variants"]} == {"prefill", "decode"}
     for v in m["variants"]:
-        assert len(v["avals"]) == n_in
+        # prefill: prompt, last_pos, slot, temp; decode: off, active, temp
+        own = {"prefill": 4, "decode": 3}[v["kind"]]
+        assert len(v["avals"]) == n_par + n_pool + own + 2
+        assert v["donate"] == list(range(n_par, n_par + n_pool))
+        assert v["avals"][n_par + n_pool - 1] == \
+            [[bucket[0], 1], "float32"]                 # the last tokens
         assert v["avals"][-1] == [[], "uint32"]         # the counter
         assert v["avals"][-2][1] == "uint32"            # the base key
         assert v["avals"][-3][1] == "float32"           # temp
@@ -980,23 +1022,358 @@ def test_warm_start_key_input_round_trip_and_old_manifest(
     st = srv2.stats()["buckets"]["%dx%d" % bucket]
     assert st["steady_misses"] == 0 and st["steady_fresh_compiles"] == 0
 
-    for v in m["variants"]:
+    # before PR 32: the state alone was donated (decode then took a
+    # host-made ``tok`` of the token vector's very aval, so only the
+    # donation tells them apart); before PR 30: no counter either
+    before_32 = json.loads(json.dumps(m))
+    for v in before_32["variants"]:
+        v["donate"] = v["donate"][:-1]
+        if v["kind"] == "prefill":
+            del v["avals"][n_par + n_pool - 1]
+    before_30 = json.loads(json.dumps(before_32))
+    for v in before_30["variants"]:
         del v["avals"][-1]
-    old = str(tmp_path / "serving_old.json")
-    with open(old, "w") as f:
-        json.dump(m, f)
-    engine.clear_cache()
-    engine.reset_counters()
-    telemetry.clear_events()
-    srv3 = Server(lm, buckets=[bucket], max_new_tokens=5)
-    fresh = engine.cache_info()["fresh_compiles"]   # the pool's zeros
-    assert srv3.warm_start(old) is False
-    ev = telemetry.events("warm_start")[-1]
-    assert ev["ok"] is False and "RNG key input" in ev["reason"]
-    assert not srv3.warm_started and not srv3._warmed
-    assert engine.cache_info()["fresh_compiles"] == fresh
+    for name, manifest in (("b32", before_32), ("b30", before_30)):
+        old = str(tmp_path / f"serving_{name}.json")
+        with open(old, "w") as f:
+            json.dump(manifest, f)
+        engine.clear_cache()
+        engine.reset_counters()
+        telemetry.clear_events()
+        srv3 = Server(lm, buckets=[bucket], max_new_tokens=5)
+        fresh = engine.cache_info()["fresh_compiles"]   # the pool's zeros
+        assert srv3.warm_start(old) is False
+        ev = telemetry.events("warm_start")[-1]
+        assert ev["ok"] is False and "RNG key input" in ev["reason"]
+        assert "`last_token`" in ev["reason"]
+        assert not srv3.warm_started and not srv3._warmed
+        assert engine.cache_info()["fresh_compiles"] == fresh
     again = srv3.generate(prompts)
     assert engine.cache_info()["fresh_compiles"] >= fresh + 2
     for a, b, c in zip(cold, warm, again):
         np.testing.assert_array_equal(a, b)
         np.testing.assert_array_equal(a, c)
+
+
+# -- one decode ahead of the reads (docs/serving.md, "A round") ---------------
+# A slot's last token lives in the pool; a round enqueues its prefills and
+# its decodes, then reads the PREVIOUS round's decode tokens and its own
+# first tokens.  Each contract below holds for both state planes.
+
+def _counter(name):
+    return telemetry.counter(name).value
+
+
+def _ahead():
+    return _counter("mxtpu_serving_decodes_ahead_total")
+
+
+def _overrun():
+    return _counter("mxtpu_serving_overrun_tokens_total")
+
+
+def _tightest_parent(e, events):
+    around = [p for p in events if p is not e and p["tid"] == e["tid"]
+              and _inside(e, p)]
+    return min(around, key=lambda p: p["dur"]) if around else None
+
+
+@pytest.mark.time_limit(120)
+def test_next_decode_is_enqueued_before_the_last_is_read(lm_bucket):
+    """(a) and (f): round n+1's ``engine.execute`` begins before round
+    n's tokens are read; every decode but the first of a busy spell is
+    enqueued AHEAD; every ``token_read`` is a child of ``decode`` or
+    ``admit``, and every round that decodes has a ``decode`` child: the
+    nesting ``chipbench/harness/program_spans.py`` reads."""
+    lm, bucket = lm_bucket
+    srv = Server(lm, buckets=[bucket], max_new_tokens=6)
+    srv.generate([_prompt(70, 4)])                  # compile
+    a0 = _ahead()
+
+    def two_spells():
+        srv.generate([_prompt(71, 5), _prompt(72, 3)])
+        srv.generate([_prompt(73, 4)])
+
+    events = _recorded_spans(two_spells)
+
+    def named(name, within=None):
+        return sorted((e for e in events if e["name"] == name
+                       and (within is None or _inside(e, within))),
+                      key=lambda e: e["ts"])
+
+    rounds = named("mxtpu.serving.round")
+    decodes = [e for e in named("mxtpu.serving.dispatch")
+               if e["args"]["kind"] == "decode"]
+    # prefill 1 + 5 decodes a request, the requests of a spell in step
+    assert len(decodes) == 10
+    assert _ahead() - a0 == len(decodes) - 2        # two busy spells
+    reads = named("mxtpu.serving.token_read")
+    assert len(reads) == len(decodes) + 3           # + 3 first tokens
+    for r in reads:
+        assert _tightest_parent(r, events)["name"] in (
+            "mxtpu.serving.decode", "mxtpu.serving.admit")
+    enqueued_in = {}
+    for d in decodes:
+        rnd, = [r for r in rounds if _inside(d, r)]
+        enqueued_in[rnd["args"]["round"]] = d
+        assert named("mxtpu.serving.decode", rnd)
+    # a decode's tokens are read in the round AFTER the one that
+    # enqueued it, behind that round's own decode where it has one
+    late = [r for r in reads if _tightest_parent(r, events)["name"]
+            == "mxtpu.serving.decode"]
+    assert len(late) == len(decodes)
+    for d, r in zip(decodes, late):
+        rnd, = [x for x in rounds if _inside(r, x)]
+        n = rnd["args"]["round"]
+        assert _inside(d, [x for x in rounds
+                           if x["args"]["round"] == n - 1][0])
+        if n in enqueued_in:
+            execute, = named("mxtpu.engine.execute", enqueued_in[n])
+            assert execute["ts"] + execute["dur"] <= r["ts"]
+
+
+@pytest.mark.parametrize("decode_steps", [1, 4])
+def test_greedy_parity_with_midstream_admissions(lm_bucket, decode_steps):
+    """(b): requests of different budgets leave their slots at different
+    rounds and newcomers take the freed slots mid-stream; every one is
+    bit-equal to ``generate``, per step and K steps a dispatch."""
+    lm, bucket = lm_bucket
+    srv = Server(lm, buckets=[bucket], max_new_tokens=11)
+    plan = [(_prompt(74, 5), 11), (_prompt(75, 3), 4), (_prompt(76, 6), 7),
+            (_prompt(77, 2), 1), (_prompt(78, 4), 9), (_prompt(79, 7), 2)]
+    reqs = [srv.submit(p, max_new_tokens=n) for p, n in plan[:3]]
+    srv.step(decode_steps=decode_steps)
+    srv.step(decode_steps=decode_steps)
+    reqs += [srv.submit(p, max_new_tokens=n) for p, n in plan[3:]]
+    srv.run(decode_steps=decode_steps)
+    for r, (p, n) in zip(reqs, plan):
+        assert r.state == "done" and len(r.generated) == n
+        np.testing.assert_array_equal(r.tokens(),
+                                      _reference_tokens(lm, p, n))
+    assert srv.idle()
+
+
+def test_eos_overrun_is_dropped_and_leaves_no_trace(lm_bucket):
+    """(c): an ``eos_id`` is a token's VALUE: the host learns it one
+    dispatch late.  The request delivers nothing past it, the one token
+    decoded meanwhile is counted and dropped, and the request admitted
+    into the slot next gets exactly a fresh server's tokens."""
+    lm, (_slots, plen) = lm_bucket
+    pa, pb = _prompt(80, 5), _prompt(81, 4)
+    gen = _reference_tokens(lm, pa, 8)[len(pa):].astype(int)
+    eos = int(gen[1])
+    stop_at = int(np.nonzero(gen == eos)[0][0])     # 0 or 1: mid-budget
+    srv = Server(lm, buckets=[(1, plen)], max_new_tokens=8, eos_id=eos)
+    o0 = _overrun()
+    ra, rb = srv.submit(pa), srv.submit(pb, eos_id=-1)
+    srv.run()
+    assert ra.state == "done" and ra.generated == list(gen[:stop_at + 1])
+    assert _overrun() - o0 == 1
+    np.testing.assert_array_equal(rb.tokens(),
+                                  _reference_tokens(lm, pb, 8))
+
+
+def test_dispatch_count_invariant_over_a_run_with_a_drain(lm_bucket):
+    """(d): the benchmark's invariant (``chipbench/drivers/
+    serve_loop.py``): a bucket is decoded in a round if and only if it
+    holds an active slot when the round decodes, so dispatches ==
+    admissions + busy buckets, round by round, down to the last round,
+    which enqueues nothing; every request gets the tokens it asked for."""
+    lm, (slots, plen) = lm_bucket
+    srv = Server(lm, buckets=[(slots, plen // 2), (slots, plen)],
+                 max_new_tokens=7)
+    rng = np.random.RandomState(82)
+    asked = [(_prompt(83 + i, int(rng.randint(1, plen + 1))),
+              int(rng.randint(1, 8))) for i in range(9)]
+    srv.generate([_prompt(82, plen // 2), _prompt(82, plen)])  # compile
+    decoded, skipped = [], []
+    real_decode, real_collect = srv._decode, srv._collect
+
+    def decode(bucket, k):
+        assert bucket.n_active() > 0
+        decoded.append(bucket)
+        return real_decode(bucket, k)
+
+    def collect(n=None):
+        # a bucket this round did not decode has the mask it had then
+        skipped.extend(b for b in srv.sched.buckets
+                       if b not in decoded and b.n_active() > 0)
+        return real_collect(n)
+
+    srv._decode, srv._collect = decode, collect
+    reqs = [srv.submit(p, max_new_tokens=n) for p, n in asked[:5]]
+    rounds = 0
+    while not srv.idle():
+        if rounds == 3:
+            reqs += [srv.submit(p, max_new_tokens=n) for p, n in asked[5:]]
+        busy_before = sum(1 for b in srv.sched.buckets if b.n_active())
+        del decoded[:]
+        d0 = engine.dispatch_count()
+        st = srv.step()
+        d = engine.dispatch_count() - d0
+        assert d == st["admitted"] + len(decoded) and not skipped
+        if not st["admitted"]:
+            assert len(decoded) == busy_before
+        rounds += 1
+        assert rounds < 80
+    assert d == 0 and st["tokens"] > 0              # the drain's read
+    for r, (p, n) in zip(reqs, asked):
+        assert r.state == "done" and len(r.generated) == n
+        np.testing.assert_array_equal(r.tokens(),
+                                      _reference_tokens(lm, p, n))
+
+
+def test_evict_and_requeue_with_a_decode_outstanding(lm_bucket):
+    """(e): what the device still owes an evicted request is dropped,
+    not delivered, and a requeued one restarts clean: no stale token of
+    its first life reaches its second."""
+    lm, bucket = lm_bucket
+    pa, pb, pc = _prompt(90, 5), _prompt(91, 4), _prompt(92, 6)
+    srv = Server(lm, buckets=[bucket], max_new_tokens=8)
+    ra, rb = srv.submit(pa), srv.submit(pb)
+    srv.step()
+    srv.step()
+    assert srv._owed and rb.owed == 1 and len(rb.generated) == 2
+    o0 = _overrun()
+    assert srv.evict(rb, reason="user")
+    assert rb.state == "evicted" and len(rb.generated) == 2
+    assert _overrun() - o0 == 1
+    rc = srv.submit(pc)                             # takes rb's slot
+    srv.step()
+    assert len(rb.generated) == 2 and rb.owed == 0
+    o1 = _overrun()
+    srv.evict(ra, reason="preempt", requeue=True)   # decode outstanding
+    assert ra.state == "queued" and ra.generated == [] and ra.owed == 0
+    assert _overrun() - o1 == 1
+    srv.run()
+    np.testing.assert_array_equal(ra.tokens(), _reference_tokens(lm, pa, 8))
+    np.testing.assert_array_equal(rc.tokens(), _reference_tokens(lm, pc, 8))
+
+
+def test_deadline_expiry_with_a_decode_outstanding(lm_bucket):
+    import time
+    lm, bucket = lm_bucket
+    pa, pb = _prompt(93, 5), _prompt(94, 4)
+    srv = Server(lm, buckets=[bucket], max_new_tokens=8)
+    srv.generate([pa])                              # compile
+    ra = srv.submit(pa)
+    rb = srv.submit(pb, ttl_ms=60_000.0)
+    srv.step()
+    srv.step()
+    rb.deadline = time.perf_counter() - 1.0         # it expires NOW
+    had = len(rb.generated)
+    o0 = _overrun()
+    srv.step()                                      # the sweep runs first
+    assert rb.state == "evicted" and rb.evict_reason == "deadline"
+    assert len(rb.generated) == had and _overrun() - o0 == 1
+    ev = telemetry.events("deadline_evicted")[-1]
+    assert ev["request"] == rb.id and ev["generated"] == had
+    srv.run()
+    np.testing.assert_array_equal(ra.tokens(), _reference_tokens(lm, pa, 8))
+
+
+def test_resize_and_save_signature_drain_the_owed_reads(lm_bucket, tmp_path):
+    """(e): both look at the server from outside a round, so both first
+    read what the device owes (``settle``)."""
+    lm, (slots, plen) = lm_bucket
+    pa, pb = _prompt(95, 5), _prompt(96, 3)
+    srv = Server(lm, buckets=[(slots, plen)], max_new_tokens=8)
+    ra, rb = srv.submit(pa), srv.submit(pb)
+    srv.step()
+    srv.step()
+    assert srv._owed and len(ra.generated) == 2
+    srv.save_signature(str(tmp_path / "sig.json"))
+    assert not srv._owed and len(ra.generated) == 3 and ra.owed == 0
+    srv.step()
+    assert srv._owed
+    rec = srv.resize_slots(slots + 1)
+    assert rec["migrated"] == 2 and not srv._owed
+    assert len(ra.generated) == 4 and len(rb.generated) == 4
+    # the slots' last tokens moved with the state
+    srv.run()
+    np.testing.assert_array_equal(ra.tokens(), _reference_tokens(lm, pa, 8))
+    np.testing.assert_array_equal(rb.tokens(), _reference_tokens(lm, pb, 8))
+    assert srv.settle() == 0 and srv.idle()
+
+
+def test_poison_recover_with_a_decode_outstanding(lm_bucket):
+    """(e): a dispatch that dies with the pool donated loses what the
+    device owed too: ``recover`` requeues the residents AND the request
+    that had left its slot by count and was waiting for its last token."""
+    lm, bucket = lm_bucket
+    pa, pb = _prompt(97, 5), _prompt(98, 4)
+    srv = Server(lm, buckets=[bucket], max_new_tokens=8)
+    ra = srv.submit(pa)
+    rb = srv.submit(pb, max_new_tokens=2)
+    srv.step()                  # rb: first token read, its last one owed
+    assert rb.state == "active" and rb.bucket is None and rb.owed == 1
+    faults.configure("dispatch_post:nth=1")
+    try:
+        with pytest.raises(MXNetError, match="recover"):
+            srv.step()
+    finally:
+        faults.clear()
+    assert srv.stats()["poisoned"] and len(rb.generated) == 1
+    with pytest.raises(MXNetError, match="recover"):
+        srv.settle()                    # latched for every entry
+    assert srv.recover() == 2
+    assert not srv._owed and [r.id for r in srv.sched.queue] == \
+        [ra.id, rb.id]
+    srv.run()
+    np.testing.assert_array_equal(ra.tokens(), _reference_tokens(lm, pa, 8))
+    np.testing.assert_array_equal(rb.tokens(), _reference_tokens(lm, pb, 2))
+
+
+def test_a_failure_at_the_late_read_poisons_and_names_the_dispatch(
+        lm_bucket):
+    """(e): a program that fails ON the device says so when its output
+    is read, a round after its dispatch returned: the same latch, the
+    same event, the dispatch's own name."""
+    lm, bucket = lm_bucket
+    p = _prompt(99, 5)
+    srv = Server(lm, buckets=[bucket], max_new_tokens=8)
+    req = srv.submit(p)
+    srv.step()
+
+    class Dead:
+        def __array__(self, *a, **k):
+            raise RuntimeError("device halted")
+
+    rec, = srv._owed
+    assert rec.kind == "decode"
+    rec.out = Dead()
+    telemetry.clear_events()
+    p0 = _counter("mxtpu_poisons_total")
+    with pytest.raises(MXNetError, match="recover") as err:
+        srv.step()
+    assert "device halted" in str(err.value)
+    assert srv.stats()["poisoned"]
+    assert _counter("mxtpu_poisons_total") - p0 == 1
+    ev = telemetry.events("poison")[-1]
+    assert ev["where"] == "serving"
+    assert ev["name"] == srv.name + "_b%dx%d_decode" % bucket
+    with pytest.raises(MXNetError, match="recover"):
+        srv.step()
+    assert srv.recover() == 1
+    srv.run()
+    np.testing.assert_array_equal(req.tokens(), _reference_tokens(lm, p, 8))
+
+
+def test_preemption_drain_reads_what_is_owed(lm_bucket, tmp_path):
+    """(e): ``elastic.guardian.drain_server`` records "tokens generated
+    so far": with a decode outstanding that includes the owed ones, and
+    a request waiting for its last token is finished, not requeued."""
+    import json
+    from mxnet_tpu.elastic.guardian import drain_server
+    lm, bucket = lm_bucket
+    srv = Server(lm, buckets=[bucket], max_new_tokens=8)
+    ra = srv.submit(_prompt(100, 5))
+    rb = srv.submit(_prompt(101, 4), max_new_tokens=2)
+    srv.step()
+    assert srv._owed and srv.awaiting() == [rb]
+    out = drain_server(srv, str(tmp_path))
+    assert not srv._owed and rb.state == "done" and len(rb.generated) == 2
+    assert (out["requeued"], out["queued"]) == (1, 0)
+    row, = json.load(open(out["manifest"]))["requests"]
+    assert len(row["generated"]) == 2       # first token + the owed one
+    assert ra.state == "queued"
