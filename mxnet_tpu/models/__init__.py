@@ -14,6 +14,9 @@ from .llama import (LlamaModel, LlamaForCausalLM, get_llama,
 from . import sambay
 from .sambay import (SambaYModel, SambaYForCausalLM, get_sambay,
                      sambay_tiny, phi4_mini_flash)
+from . import afmoe
+from .afmoe import (AfmoeModel, AfmoeForCausalLM, get_afmoe, afmoe_tiny,
+                    trinity_large_ep8)
 from . import hf_loader
 from .hf_loader import (read_safetensors, write_safetensors,
                         load_hf_llama, export_hf_llama,
@@ -41,7 +44,8 @@ __all__ = ["hf_loader", "read_safetensors", "write_safetensors",
            "DeepAR", "TransformerForecaster", "llama", "LlamaModel",
            "LlamaForCausalLM", "get_llama", "llama_tiny", "llama3_8b", "sambay", "SambaYModel",
            "SambaYForCausalLM", "get_sambay", "sambay_tiny",
-           "phi4_mini_flash",
+           "phi4_mini_flash", "afmoe", "AfmoeModel", "AfmoeForCausalLM",
+           "get_afmoe", "afmoe_tiny", "trinity_large_ep8",
            "nmt", "TransformerNMT", "BeamSearchScorer",
            "BeamSearchSampler", "get_nmt", "nmt_tiny",
            "transformer_en_de_512", "segmentation", "FCN", "DeepLabV3",

@@ -97,15 +97,16 @@ class _LayerNorm(HybridBlock):
 class _MLP(HybridBlock):
     """``W2 (up * silu(gate))``, ``[gate, up] = W1 x``, no bias."""
 
-    def __init__(self, units, hidden, **kwargs):
+    def __init__(self, units, hidden, scope="mxtpu.mlp", **kwargs):
         super().__init__(**kwargs)
         self._hidden = hidden
+        self._scope_name = scope
         with self.name_scope():
             self.gateup = _dense(2 * hidden, units, False, "gateup_")
             self.down = _dense(units, hidden, False, "down_")
 
     def hybrid_forward(self, F, x):
-        with _scope("mxtpu.mlp"):
+        with _scope(self._scope_name):
             gate, up = _split(self.gateup(x), (self._hidden, self._hidden))
             return self.down(up * F.silu(gate))
 

@@ -132,7 +132,14 @@ class Server:
     """Continuously batched serving over a model-zoo decoder: anything
     exposing ``state_spec``/``prefill``/``decode_step`` over a flat state
     list and ``model.vocab_size`` (``LlamaForCausalLM``,
-    ``SambaYForCausalLM``).
+    ``SambaYForCausalLM``, ``AfmoeForCausalLM``).  A model may also
+    declare ``statistics`` (docs/serving.md, "Model statistics"):
+    ``(counter, help)`` rows whose per-call counts, left by ``prefill`` /
+    ``decode_step`` in ``lm.last_statistics``, ride out of every program
+    beside its tokens and are added to those counters at the read; what
+    the model leaves BEHIND those counts (what each row of the call
+    chose) rides out with them and goes, with the counts, to
+    ``statistics_listener`` if one is set.
 
     Args:
       lm: initialized causal LM.
@@ -198,6 +205,12 @@ class Server:
                 "Server needs an initialized model (run initialize() "
                 f"and one forward first): {e!r}") from e
         self.name = f"serving_{lm.name}_{next(_uid)}"
+        # what the model's programs count beside their tokens
+        self._stat_rows = tuple(
+            (str(n), str(h)) for n, h in getattr(lm, "statistics", ()))
+        #: ``fn(kind, columns, counts, rows)``, called at the read of
+        #: every dispatch of a model that declares ``statistics``
+        self.statistics_listener = None
         if self._decode_sharding is not None:
             # the slot dim is the decode spec's leading entry: every
             # bucket's slot count must divide its device fan-out, or
@@ -286,7 +299,12 @@ class Server:
                 # a plan exists so pre-planner hashes (and persisted
                 # executables) still serve
                 (self.plan.struct_hash(),)
-                if self.plan is not None else ())
+                if self.plan is not None else ()) + (
+                # the programs' first output is longer by these, and by
+                # the rows the model leaves behind them: appended only
+                # for a model that declares statistics
+                ("counts, rows", tuple(n for n, _h in self._stat_rows))
+                if self._stat_rows else ())
         return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
 
     def _spec_for(self, slots, cache_len):
@@ -1131,6 +1149,26 @@ class Server:
         nxt = jnp.where(temp > 0, sampled, greedy)
         return jnp.where(active > 0, nxt, jnp.zeros_like(nxt))
 
+    def _counted(self):
+        """What the model's last ``prefill`` / ``decode_step`` left in
+        ``last_statistics`` (traced), flat, in float32 (exact under
+        2**24): one count a row of ``statistics``, then whatever arrays
+        of per-row integers the model put behind them; empty for a model
+        that declares none."""
+        import jax.numpy as jnp
+        return jnp.concatenate(
+            [jnp.asarray(c._data, jnp.float32).reshape(-1)
+             for c in self.lm.last_statistics]) \
+            if self._stat_rows else jnp.zeros((0,), jnp.float32)
+
+    def _tokens_out(self, toks, counted):
+        """A program's first output: its tokens, and behind them what
+        the model counted, in ONE array that ONE read brings back."""
+        import jax.numpy as jnp
+        if not self._stat_rows:
+            return toks
+        return jnp.concatenate([toks.reshape(-1), counted])
+
     def _make_decode(self, bucket):
         lm, ctx = self.lm, self.ctx
         params = self._param_nds
@@ -1154,13 +1192,15 @@ class Server:
                     NDArray(tok, ctx=ctx), shells,
                     NDArray(off, ctx=ctx))._data
                 new_caches = tuple(s._data for s in shells)
+                counted = self._counted()
             k0 = _dispatch_key(key_raw, counter)
             keys = jax.vmap(lambda i: jax.random.fold_in(k0, i))(
                 jnp.arange(N))
             nxt = self._pick(logits, temp, active, keys)
             # the tokens twice: an output of their own that the host
             # may read late, and the pool's successor
-            return (nxt,) + new_caches + (nxt.reshape(N, 1),)
+            return (self._tokens_out(nxt, counted),) + new_caches \
+                + (nxt.reshape(N, 1),)
 
         return decode_pure
 
@@ -1190,6 +1230,7 @@ class Server:
                         NDArray(tok_c, ctx=ctx), shells,
                         NDArray(off_c, ctx=ctx))._data
                     new_caches = tuple(s._data for s in shells)
+                    counted = self._counted()
                 k_step = jax.random.fold_in(k0, step_i)
                 keys = jax.vmap(
                     lambda i: jax.random.fold_in(k_step, i))(
@@ -1198,12 +1239,18 @@ class Server:
                 # inactive slots hold position (offset AND token), so
                 # the in-graph carry matches the host's bookkeeping
                 return (nxt.reshape(N, 1), off_c + active,
-                        new_caches), nxt
+                        new_caches), (nxt, counted)
 
-            (tok_f, _, caches_f), toks = lax.scan(
+            (tok_f, _, caches_f), (toks, counted) = lax.scan(
                 body, (tok, off, cache_vals),
                 jnp.arange(k_steps))
-            return (toks,) + caches_f + (tok_f,)   # toks: (K, N)
+            # toks: (K, N); the K steps' counts add up, their rows
+            # follow one another
+            n = len(self._stat_rows)
+            counted = jnp.concatenate([counted[:, :n].sum(axis=0),
+                                       counted[:, n:].reshape(-1)])
+            return (self._tokens_out(toks, counted),) \
+                + caches_f + (tok_f,)
 
         return decode_multi_pure
 
@@ -1228,6 +1275,7 @@ class Server:
                 logits = lm.prefill(
                     NDArray(prompt, ctx=ctx), tmp,
                     last_pos=NDArray(last_pos, ctx=ctx))._data
+                counted = self._counted()
             slot_i = jnp.asarray(slot, jnp.int32)
             zero = jnp.int32(0)
             new_caches = [
@@ -1242,7 +1290,8 @@ class Server:
             # the first token goes where the slot's next decode reads it
             toks = lax.dynamic_update_slice(
                 flat[P + NS - 1], nxt.reshape(1, 1), (slot_i, zero))
-            return (nxt,) + tuple(new_caches) + (toks,)
+            return (self._tokens_out(nxt, counted),) \
+                + tuple(new_caches) + (toks,)
 
         return prefill_pure
 
@@ -1501,6 +1550,18 @@ class Server:
                     self._pools[rec.bucket.key], self.name + self._suffix(
                         rec.bucket, rec.kind, 0 if rec.k == 1 else rec.k), e)
         with _span("mxtpu.serving.bookkeeping", "serving", **rec.ids):
+            if self._stat_rows:
+                # the model's counts came back behind the tokens, and
+                # behind them what its rows chose
+                at = rec.k * (1 if first else rec.bucket.slots)
+                n = at + len(self._stat_rows)
+                toks, counted, rows = toks[:at], toks[at:n], toks[n:]
+                for (name, doc), c in zip(self._stat_rows, counted):
+                    telemetry.counter(name, doc).inc(int(c))
+                if self.statistics_listener is not None:
+                    self.statistics_listener(
+                        rec.kind, [col for col, _r, _n in rec.take],
+                        counted, rows)
             toks = toks.reshape(rec.k, -1)                  # (K, columns)
             produced = dropped = 0
             for i, row in enumerate(toks):

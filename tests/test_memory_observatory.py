@@ -135,6 +135,8 @@ def test_param_census_sums_to_total():
 
 
 def test_live_bytes_census():
+    import gc
+    gc.collect()        # an earlier file's garbage goes now, not mid-test
     info0 = engine.cache_info()
     a = nd.array(np.ones((64, 64), np.float32))
     b = a + 1.0
@@ -145,7 +147,11 @@ def test_live_bytes_census():
     assert info["live_bytes"] >= info0["live_bytes"] + 64 * 64 * 4
     c = memobs.census()
     assert c["total_bytes"] == info["live_bytes"]
-    assert c["count"] == info["live_buffers"]
+    # a buffer a donation deleted stays in the live set while anything
+    # holds it (an earlier file's Server): counted there, skipped here
+    deleted = sum(1 for arr in engine.live_arrays()
+                  if getattr(arr, "is_deleted", lambda: False)())
+    assert c["count"] == info["live_buffers"] - deleted
     assert sum(c["by_device"].values()) >= c["total_bytes"]
 
 

@@ -282,3 +282,149 @@ def test_pipeline_recreated_array_capture_hits_cache():
         parallel.pipeline_apply(lambda p, xx: xx * cap, params, x,
                                 n_microbatches=4, mesh=mesh)
     assert len(pl._EXEC_CACHE) == before + 1
+
+
+# -- _contrib_RoutedExperts: one chip's share of a no-drop expert layer --------
+# (ops/moe.py; the model around it is tests/test_afmoe.py)
+
+def _routed_inputs(seed, t=12, d=8, h=6, e=16):
+    rng = np.random.RandomState(seed)
+    return dict(
+        x=rng.randn(t, d).astype("f4"),
+        wr=rng.randn(e, d).astype("f4"),
+        b=(0.5 * rng.randn(e)).astype("f4"),
+        wg=(0.3 * rng.randn(e, d, h)).astype("f4"),
+        wu=(0.3 * rng.randn(e, d, h)).astype("f4"),
+        wd=(0.3 * rng.randn(e, h, d)).astype("f4"))
+
+
+def _routed_numpy(a, first, n, k, scale, valid=None, weigh_with_bias=False):
+    """(out, held assignments, held experts touched, picked): a loop over
+    rows and experts, float64."""
+    x = a["x"].astype("f8")
+    s = 1 / (1 + np.exp(-(x @ a["wr"].T.astype("f8"))))
+    out = np.zeros_like(x)
+    picked, held, touched = [], 0, set()
+    for t in range(len(x)):
+        pick = np.argsort(-(s[t] + a["b"]), kind="stable")[:k]
+        picked.append(pick)
+        if valid is not None and not valid[t]:
+            continue
+        w = (s[t] + a["b"] if weigh_with_bias else s[t])[pick]
+        w = w / (w.sum() + 1e-20) * scale
+        for g, e in zip(w, pick):
+            if first <= e < first + n:
+                held += 1
+                touched.add(e)
+                j = e - first
+                gate = x[t] @ a["wg"][j]
+                mid = gate / (1 + np.exp(-gate)) * (x[t] @ a["wu"][j])
+                out[t] += g * (mid @ a["wd"][j])
+    return out, held, len(touched), np.array(picked)
+
+
+def _routed_op(a, first, n, k, scale, valid=None):
+    extra = [] if valid is None else [nd.array(valid.astype("f4"))]
+    out, held, touched, picked = nd._contrib_RoutedExperts(
+        nd.array(a["x"]), nd.array(a["wr"]), nd.array(a["b"]),
+        nd.array(a["wg"][first:first + n]), nd.array(a["wu"][first:first + n]),
+        nd.array(a["wd"][first:first + n]), *extra, k=k, route_scale=scale,
+        first_held=first, use_valid=valid is not None)
+    return (out.asnumpy(), int(held.asnumpy()), int(touched.asnumpy()),
+            picked.asnumpy())
+
+
+def _share_of(a, first, n):
+    return dict(a, wg=a["wg"][first:first + n], wu=a["wu"][first:first + n],
+                wd=a["wd"][first:first + n])
+
+
+@pytest.mark.parametrize("first,n", [(0, 16), (0, 4), (4, 4), (12, 4),
+                                     (3, 9)])
+def test_routed_experts_match_a_loop_over_rows_and_experts(first, n):
+    """The held range's partial sum, its two counts and the picks, for
+    the uncut layer (the same path) and for shares of it."""
+    a = _routed_inputs(0)
+    want = _routed_numpy(_share_of(a, first, n), first, n, 4, 2.448)
+    got = _routed_op(a, first, n, 4, 2.448)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-6)
+    assert got[1:3] == want[1:3]
+    np.testing.assert_array_equal(got[3], want[3])
+    assert got[0].dtype == np.float32 and got[3].dtype == np.int32
+
+
+def test_routed_shares_add_up_to_the_uncut_layer():
+    a = _routed_inputs(1)
+    whole = _routed_op(a, 0, 16, 4, 2.448)
+    parts = [_routed_op(a, first, 4, 4, 2.448) for first in (0, 4, 8, 12)]
+    np.testing.assert_allclose(sum(p[0] for p in parts), whole[0],
+                               rtol=1e-5, atol=1e-6)
+    assert sum(p[1] for p in parts) == whole[1] == 12 * 4
+    assert sum(p[2] for p in parts) == whole[2]
+
+
+def test_routed_bias_selects_and_never_weighs():
+    a = _routed_inputs(2)
+    biased = _routed_op(a, 0, 16, 4, 1.0)
+    unbiased = _routed_op(dict(a, b=np.zeros_like(a["b"])), 0, 16, 4, 1.0)
+    # the bias changes who is picked (top4(s + b) != top4(s)) ...
+    assert (np.sort(biased[3], -1) != np.sort(unbiased[3], -1)).any()
+    # ... and the picked are weighed by their scores alone
+    np.testing.assert_allclose(
+        biased[0], _routed_numpy(a, 0, 16, 4, 1.0)[0], rtol=1e-5, atol=1e-6)
+    wrong = _routed_numpy(a, 0, 16, 4, 1.0, weigh_with_bias=True)[0]
+    assert np.abs(biased[0] - wrong).max() > 1e-2
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_routed_experts_drop_nothing_under_total_imbalance(k):
+    """Every one of 96 rows picks the same ``k`` experts (a bias of 50 on
+    them): whatever a capacity would have been, nothing is dropped."""
+    a = _routed_inputs(3, t=96)
+    a["b"] = np.zeros(16, "f4")
+    a["b"][[5, 6, 9, 11][:k]] = 50.0
+    got = _routed_op(a, 4, 8, k, 2.448)
+    want = _routed_numpy(_share_of(a, 4, 8), 4, 8, k, 2.448)
+    assert (np.sort(got[3], -1) == sorted([5, 6, 9, 11][:k])).all()
+    assert got[1] == want[1] == 96 * k and got[2] == want[2] == k
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-6)
+    assert np.abs(got[0]).min(axis=1).min() >= 0 and \
+        (np.abs(got[0]).sum(axis=1) > 0).all()          # no row left out
+
+
+def test_routed_padding_rows_go_nowhere_and_count_nowhere():
+    a = _routed_inputs(4)
+    valid = np.arange(12) < 7
+    got = _routed_op(a, 2, 10, 4, 2.448, valid)
+    want = _routed_numpy(_share_of(a, 2, 10), 2, 10, 4, 2.448, valid)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-6)
+    assert got[1:3] == want[1:3]
+    assert np.abs(got[0][7:]).max() == 0.0
+
+
+def test_routed_selection_is_float32_whatever_the_inputs_are():
+    """bfloat16 rows and weights: the scores, the top-k and the counts
+    run in float32 / int32 (bfloat16 cannot tell 256 scores apart, nor
+    count past 256), the partial sum comes back float32."""
+    a = _routed_inputs(5, t=300)
+    bf = {k: nd.array(v).astype("bfloat16") for k, v in a.items()}
+    out, held, touched, picked = nd._contrib_RoutedExperts(
+        bf["x"], bf["wr"], bf["b"], bf["wg"], bf["wu"], bf["wd"], k=4,
+        route_scale=2.448, first_held=0)
+    assert str(out.dtype) == "float32" and int(held.asnumpy()) == 1200
+    rounded = {k: v.astype("float32").asnumpy() for k, v in bf.items()}
+    want = _routed_numpy(rounded, 0, 16, 4, 2.448)
+    agree = (picked.asnumpy() == want[3]).all(axis=1)
+    assert agree.mean() > 0.97          # a rounding apart at a near tie
+    np.testing.assert_allclose(out.asnumpy()[agree], want[0][agree],
+                               rtol=0.05, atol=0.02)
+
+
+def test_routed_experts_refuse_a_range_outside_the_router():
+    a = {k: nd.array(v) for k, v in _routed_inputs(6).items()}
+    held = [a[k][:8] for k in ("wg", "wu", "wd")]
+    with pytest.raises(Exception, match="not among the router's 16"):
+        nd._contrib_RoutedExperts(a["x"], a["wr"], a["b"], *held, k=4,
+                                  first_held=12)
+    with pytest.raises(Exception, match="exceeds the router's"):
+        nd._contrib_RoutedExperts(a["x"], a["wr"], a["b"], *held, k=17)
