@@ -60,8 +60,10 @@ __all__ = ["enabled", "cache_dir", "fingerprint", "aval_sig",
 
 #: bump to invalidate every existing entry (format or semantics change
 #: in the programs we serialize — the tier-1 suite asserts a salt bump
-#: misses cleanly)
-LIBRARY_SALT = "mxtpu-compile-cache-1"
+#: misses cleanly).  2: PR 34 rewrote the per-row page write inside
+#: every served decode program; no aval moved, so only the salt keeps
+#: an older checkout's executable out (ROADMAP D16)
+LIBRARY_SALT = "mxtpu-compile-cache-2"
 
 _MAGIC = b"MXTPUCC1"
 #: header layout version; 2 added ``devices`` (an entry without it

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .page_write import write_rows
 from .registry import register, alias
 
 # ---------------------------------------------------------------------------
@@ -926,10 +927,15 @@ def _cache_update(cache, new, offset=0):
     (KV-cache decode).  ``offset`` is a dynamic scalar attr so every
     decode step reuses ONE compiled scatter instead of compiling a new
     program per position.  A (B,)-shaped offset scatters each batch
-    row at its OWN position (per-slot decode in the serving plane)."""
+    row at its OWN position (per-slot decode in the serving plane):
+    one position a row is ONE in-place write a page
+    (``page_write.write_rows``); the vmap below, which the TPU
+    compiler turns into a serial loop over the rows, is left for
+    several positions a row, which nothing served asks for."""
     off = jnp.asarray(offset, jnp.int32)
     if off.ndim:
-        import jax
+        if new.shape[1] == 1:
+            return write_rows(cache, new, off.reshape(-1))
         return jax.vmap(
             lambda c, n, o: lax.dynamic_update_slice_in_dim(
                 c, n.astype(c.dtype), o, axis=0)

@@ -168,6 +168,134 @@ def test_model_level_row_isolation(net):
     assert np.abs(l_zero[1] - l_garb[1]).max() > 0   # sanity: row 1 DID change
 
 
+def _write_rows_by_hand(cache, new, off):
+    """``_cache_update`` with a (B,) offset as a plain loop: each row
+    at its own position, brought into the page as
+    ``lax.dynamic_update_slice`` brings it (below zero counts from the
+    end, then clamped), cast on store (both arrays float32 here; the
+    caller rounds)."""
+    out = cache.copy()
+    c, n = cache.shape[1], new.shape[1]
+    for b in range(cache.shape[0]):
+        o = int(off[b])
+        o = int(np.clip(o + c if o < 0 else o, 0, c - n))
+        out[b, o:o + n] = new[b]
+    return out
+
+
+# name -> (page dtype, offsets (B = 4, C = 8), positions a row, how)
+_ROW_WRITES = {
+    "each_its_own": ("float32", [0, 7, 3, 4], 1, "op"),
+    "below_zero_and_past_the_page": ("float32", [-1, 8, -300, 4000], 1,
+                                     "op"),
+    "every_row_the_same": ("float32", [5, 5, 5, 5], 1, "op"),
+    "float_offsets_mod_len": ("float32", [9.0 % 8.0, 7.0, 16.0 % 8.0,
+                                          3.0], 1, "op"),
+    "float32_into_bfloat16": ("bfloat16", [2, 0, 7, 7], 1, "op"),
+    "two_positions_a_row": ("float32", [0, 6, 7, 3], 2, "op"),
+    "two_positions_bfloat16": ("bfloat16", [1, -2, 9, 3], 2, "op"),
+    "through_nd": ("float32", [1, 2, 6, 0], 1, "nd"),
+    "inside_a_scan": ("float32", [0, 5, 2, 7], 1, "scan"),
+    "inside_a_scan_bfloat16": ("bfloat16", [6, 1, 1, 3], 1, "scan"),
+    "grad": ("float32", [3, 3, 0, 7], 1, "grad"),
+    "lane_block_kernel": ("bfloat16", [0, 255, 128, 127], 1, "kernel"),
+    "lane_block_kernel_ragged_page": ("bfloat16", [199, 128, 0, 150], 1,
+                                      "kernel"),
+    "lane_block_routing": ("bfloat16", [130, 2, 255, 0], 1, "routed"),
+    "lane_block_kernel_dp4": ("bfloat16", [64, 1, 200, 255], 1, "sharded"),
+    "lane_block_grad": ("float32", [77, 0, 255, 128], 1, "grad_routed"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_ROW_WRITES))
+def test_cache_update_per_row_matches_a_loop(name, monkeypatch):
+    """``_cache_update`` with a (B,) offset is ONE in-place write a
+    page (``ops/page_write.py``), not a loop over the rows; whatever
+    the spelling, the result is the loop's."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import page_write
+    from mxnet_tpu.ops.tensor import _cache_update
+    dtype, off, n, how = _ROW_WRITES[name]
+    # the lane-block kernel moves 128-lane blocks of positions; a page
+    # of 200 ends in a partial one
+    c = 8 if not name.startswith("lane_block") else \
+        200 if "ragged" in name else 256
+    rng = np.random.RandomState(len(name))
+    shape = (4, c, 2, 16)
+
+    def rounded(a):
+        return np.asarray(jnp.asarray(a).astype(dtype).astype("float32"))
+
+    cache = rounded(rng.randn(*shape).astype("f4"))
+    new = rng.randn(4, n, 2, 16).astype("f4")
+    off = np.asarray(off, "f4" if isinstance(off[0], float) else "i4")
+    page = jnp.asarray(cache).astype(dtype)
+    want = _write_rows_by_hand(cache, rounded(new), off)
+    if how == "op":
+        got = _cache_update(page, jnp.asarray(new), jnp.asarray(off))
+    elif how == "nd":
+        buf = nd.array(cache)
+        nd._cache_update(buf, nd.array(new), offset=nd.array(off),
+                         out=buf)
+        got = buf.asnumpy()
+    elif how == "scan":
+        # K steps, every row one position on a step, wrapping
+        def body(pg, i):
+            return _cache_update(pg, jnp.asarray(new) + i,
+                                 (jnp.asarray(off) + i) % c), None
+        got, _ = jax.lax.scan(body, page, jnp.arange(3))
+        want = cache
+        for i in range(3):
+            want = _write_rows_by_hand(want, rounded(new + i),
+                                       (off + i) % c)
+    elif how in ("grad", "grad_routed"):
+        if how == "grad_routed":
+            monkeypatch.setattr(page_write, "_positions_on_lanes",
+                                lambda shape, dtype: True)
+
+        def loss(pg, nw):
+            return jnp.sum(_cache_update(pg, nw, jnp.asarray(off))
+                           * jnp.asarray(cache))
+        g_page, g_new = jax.grad(loss, argnums=(0, 1))(
+            page, jnp.asarray(new))
+        # a written row's old value has no say; the new value's
+        # cotangent is the weight that sits where it landed
+        hole = _write_rows_by_hand(cache, np.zeros_like(new), off)
+        np.testing.assert_array_equal(np.asarray(g_page), hole)
+        np.testing.assert_array_equal(
+            np.asarray(g_new)[:, 0],
+            cache[np.arange(4), off.astype(int)])
+        return
+    elif how == "kernel":
+        monkeypatch.setattr(page_write, "_INTERPRET", True)
+        got = page_write._lane_block_call(
+            page, jnp.asarray(new)[:, 0].astype(dtype), jnp.asarray(off))
+    elif how == "sharded":
+        # under a dp plan each shard's kernel writes its own rows: the
+        # partitioner is told so and gathers nothing
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+        monkeypatch.setattr(page_write, "_INTERPRET", True)
+        by_rows = NamedSharding(Mesh(np.array(jax.devices()[:4]), ("dp",)),
+                                PartitionSpec("dp"))
+        args = [jax.device_put(a, by_rows) for a in (
+            page, jnp.asarray(new)[:, 0].astype(dtype), jnp.asarray(off))]
+        fn = jax.jit(page_write._lane_block_rows, out_shardings=by_rows)
+        text = fn.lower(*args).compile().as_text()
+        assert "all-gather" not in text and "all-reduce" not in text
+        got = fn(*args)
+    else:
+        # a page the TPU would store positions-minor takes the kernel
+        # on the TPU and the scatter anywhere else: same program text
+        monkeypatch.setattr(page_write, "_positions_on_lanes",
+                            lambda shape, dtype: True)
+        got = jax.jit(_cache_update)(page, jnp.asarray(new),
+                                     jnp.asarray(off))
+    assert str(got.dtype) == dtype
+    np.testing.assert_array_equal(
+        np.asarray(jnp.asarray(got).astype("float32")), want)
+
+
 def test_sampling_seeded_and_in_range(net):
     """Temperature/top-k sampling threads the fold_in scheme off the
     global stream: same seed -> same tokens; all tokens valid."""

@@ -5,7 +5,8 @@ Interpret mode cannot see what Mosaic refuses (a slice off the tiling,
 too much VMEM, a kernel that cannot be partitioned); this file can, at
 about two seconds a kernel and no chip time.  Shapes are the main
 path's real widths: BERT-base, a Mistral prefill, 4096-causal, the
-``window=`` band, the ``kmask=`` variant, and one ``rtc.PallasKernel``.
+``window=`` band, the ``kmask=`` variant, one ``rtc.PallasKernel``, and
+the per-row K,V page write of every serving cell's decode program.
 Nothing runs, so nothing here says a kernel is RIGHT or FAST — that is
 ``chip_smoke.py`` and ``tests/test_on_tpu.py``.
 
@@ -108,3 +109,63 @@ def test_rtc_user_kernel_compiles_for_v5e(one_chip):
     fn = k._build([(1024, 256)], ["float32"], (8,), [spec], [spec], ())
     x = jax.ShapeDtypeStruct((1024, 256), jnp.float32, sharding=one_chip)
     assert _mosaic_calls(fn.lower(x).compile()) == 1
+
+
+# the K or V page of one layer in each serving cell's decode program
+_PAGES = {
+    "trinity_160x1536": (160, 1536, 8, 128),
+    "phi4_window_96x512": (96, 512, 20, 64),
+    "phi4_full_96x1536": (96, 1536, 20, 64),
+    "mistral_48x384": (48, 384, 8, 128),
+    "mistral_24x1152": (24, 1152, 8, 128),
+    # no cell's: a positions-minor page that ends in a partial lane block
+    "ragged_96x600": (96, 600, 20, 64),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_PAGES) + ["scalar_offset"])
+def test_page_write_is_one_in_place_op_for_v5e(one_chip, name,
+                                               monkeypatch):
+    """``_cache_update`` with a (B,) offset, the page donated: NO
+    ``while`` over the rows (what a vmap of ``dynamic_update_slice``
+    compiles to: 20% of a Trinity decode round, PERF.md section 6,
+    PR 34), no copy of anything page-sized (what the scatter compiles
+    to on a page the TPU stores positions-minor, phi4's), the page
+    aliased to the output.  The scalar offset (prefill, ``generate``)
+    keeps its ``dynamic-update-slice``."""
+    import re
+    from mxnet_tpu.ops import page_write
+    from mxnet_tpu.ops.tensor import _cache_update
+    # the program asks the runtime how the TPU stores a page; here the
+    # described chip answers
+    chip, = one_chip.device_set
+    monkeypatch.setattr(page_write, "_tpu_device", lambda: chip)
+    shape = _PAGES.get(name, _PAGES["mistral_48x384"])
+    per_row = name in _PAGES
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    page = sds(shape, jnp.bfloat16)
+    new = sds((shape[0], 1 if per_row else 128) + shape[2:],
+              jnp.bfloat16)
+    # offsets as ``_decode_step_slots`` hands them: float32
+    off = sds((shape[0],) if per_row else (), jnp.float32)
+    compiled = jax.jit(_cache_update, donate_argnums=0).lower(
+        page, new, off).compile()
+    text = compiled.as_text()
+    assert not re.search(r" while\(", text)
+    mem = compiled.memory_analysis()
+    page_bytes = 2 * shape[0] * shape[1] * shape[2] * shape[3]
+    # the device's bytes: a page off the tiling is padded up to it
+    assert page_bytes <= mem.alias_size_in_bytes < page_bytes * 1.1
+    assert mem.temp_size_in_bytes < page_bytes // 8
+    dims = ",".join(map(str, shape))
+    assert not re.search(r"= bf16\[%s\]\S* copy\(" % dims, text)
+    if not per_row:
+        assert "dynamic-update-slice" in text
+    elif shape[2:] == (8, 128):
+        assert re.search(r" scatter\(", text)         # row-major
+        assert _mosaic_calls(compiled) == 0
+    else:
+        assert _mosaic_calls(compiled) == 1           # positions-minor
