@@ -17,6 +17,9 @@ from .sambay import (SambaYModel, SambaYForCausalLM, get_sambay,
 from . import afmoe
 from .afmoe import (AfmoeModel, AfmoeForCausalLM, get_afmoe, afmoe_tiny,
                     trinity_large_ep8)
+from . import pangu_moe
+from .pangu_moe import (PanguMoeModel, PanguMoeForCausalLM, get_pangu_moe,
+                        pangu_moe_tiny, pangu_ultra_moe_ep16)
 from . import hf_loader
 from .hf_loader import (read_safetensors, write_safetensors,
                         load_hf_llama, export_hf_llama,
@@ -46,6 +49,8 @@ __all__ = ["hf_loader", "read_safetensors", "write_safetensors",
            "SambaYForCausalLM", "get_sambay", "sambay_tiny",
            "phi4_mini_flash", "afmoe", "AfmoeModel", "AfmoeForCausalLM",
            "get_afmoe", "afmoe_tiny", "trinity_large_ep8",
+           "pangu_moe", "PanguMoeModel", "PanguMoeForCausalLM",
+           "get_pangu_moe", "pangu_moe_tiny", "pangu_ultra_moe_ep16",
            "nmt", "TransformerNMT", "BeamSearchScorer",
            "BeamSearchSampler", "get_nmt", "nmt_tiny",
            "transformer_en_de_512", "segmentation", "FCN", "DeepLabV3",

@@ -39,6 +39,7 @@ needs the picks that WERE made).
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import numpy as np
 
@@ -147,7 +148,7 @@ class _RoutedFFN(HybridBlock):
     expert whole."""
 
     def __init__(self, units, hidden, num_experts, experts_held, top_k,
-                 route_scale, **kwargs):
+                 route_scale, selection_bias=True, **kwargs):
         super().__init__(**kwargs)
         first, count = experts_held
         self._attrs = dict(k=int(top_k), route_scale=float(route_scale),
@@ -158,10 +159,11 @@ class _RoutedFFN(HybridBlock):
                 "router_weight", shape=(num_experts, units),
                 init=init.Normal(math.sqrt(2.0 / (units + num_experts))))
             # picks, never weighs: a buffer the training loop's balancer
-            # moves, no gradient
+            # moves, no gradient; a family without one selects on the
+            # scores alone
             self.bias = self.params.get(
                 "router_bias", shape=(num_experts,), init="zeros",
-                grad_req="null")
+                grad_req="null") if selection_bias else None
             self.gate = self.params.get(
                 "experts_gate_weight", shape=(count, units, hidden),
                 init=init.Normal(sigma))
@@ -182,10 +184,12 @@ class _RoutedFFN(HybridBlock):
         ctx = m.context
         b, s, u = m.shape
         extra = [] if valid is None else [valid.reshape((b * s,))]
+        bias = nd.zeros(self.router.shape[:1], ctx=ctx) \
+            if self.bias is None else self.bias.data(ctx)
         # the op names its own halves mxtpu.moe.router / mxtpu.moe.experts
         out, held, touched, selected = nd._contrib_RoutedExperts(
             m.reshape((b * s, u)), self.router.data(ctx),
-            self.bias.data(ctx), self.gate.data(ctx), self.up.data(ctx),
+            bias, self.gate.data(ctx), self.up.data(ctx),
             self.down.data(ctx), *extra, use_valid=valid is not None,
             **self._attrs)
         out = out.reshape((b, s, u)) + self.shared(m).astype("float32")
@@ -193,16 +197,18 @@ class _RoutedFFN(HybridBlock):
 
 
 class _Layer(HybridBlock):
-    def __init__(self, kind, dense, units, hidden, moe_hidden, num_heads,
-                 num_kv_heads, head_dim, window, eps, rope_base, moe,
+    """``h + RMS_2(Mixer(RMS_1(h)))`` then ``h + RMS_4(FFN(RMS_3(h)))``;
+    ``mixer(prefix=...)`` builds the attention block (``pangu_moe.py``
+    gives another), ``moe`` the arguments of an expert layer's
+    ``_RoutedFFN``."""
+
+    def __init__(self, mixer, dense, units, hidden, moe_hidden, eps, moe,
                  **kwargs):
         super().__init__(**kwargs)
-        self.kind, self.dense = kind, dense
+        self.dense = dense
         with self.name_scope():
             self.ln1 = _RMSNorm(units, eps, prefix="ln1_")
-            self.attn = _GatedAttention(
-                units, num_heads, num_kv_heads, head_dim, eps, rope_base,
-                window=window if kind == SLIDING else None, prefix="attn_")
+            self.attn = mixer(prefix="attn_")
             self.ln2 = _RMSNorm(units, eps, prefix="ln2_")
             self.ln3 = _RMSNorm(units, eps, prefix="ln3_")
             if dense:
@@ -259,10 +265,14 @@ class AfmoeModel(HybridBlock):
             self.embed = nn.Embedding(vocab_size, units, prefix="embed_")
             self.layers = []
             for i, kind in enumerate(layer_types):
-                layer = _Layer(kind, i < num_dense_layers, units, hidden,
-                               moe_hidden, num_heads, num_kv_heads,
-                               head_dim, sliding_window, rms_norm_eps,
-                               rope_base, moe, prefix=f"layer{i}_")
+                mixer = partial(
+                    _GatedAttention, units, num_heads, num_kv_heads,
+                    head_dim, rms_norm_eps, rope_base,
+                    window=sliding_window if kind == SLIDING else None)
+                layer = _Layer(mixer, i < num_dense_layers, units, hidden,
+                               moe_hidden, rms_norm_eps, moe,
+                               prefix=f"layer{i}_")
+                layer.kind = kind
                 self.register_child(layer, f"layer{i}")
                 self.layers.append(layer)
             self.final_norm = _RMSNorm(units, rms_norm_eps,

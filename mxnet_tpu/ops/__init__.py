@@ -10,6 +10,7 @@ from . import nn      # noqa: F401  (registers NN ops)
 from . import random_ops  # noqa: F401
 from . import optimizer_ops  # noqa: F401
 from . import attention  # noqa: F401  (fused SDPA + contrib transformer)
+from . import latent_attention  # noqa: F401  (MLA: expanded + absorbed)
 from . import det     # noqa: F401  (roi_align / box_nms / box_iou)
 from . import moe     # noqa: F401  (expert-parallel MoE FFN)
 from . import ssm     # noqa: F401  (selective scan + causal conv)

@@ -11,6 +11,10 @@ labels gauges and manifests; the shape is what the programs use):
   kv_heads, head_dim)``: grows with the request;
 * ``kv_window`` K or V of a sliding-window layer, as long as the model's
   window asks (a rolling buffer, or a full page behind a banded mask);
+* ``kv_latent`` the rows of a latent-attention layer, ``(slots,
+  cache_len, kv_rank + rope_dim)``: ONE buffer a layer holds the normed
+  latent and the rotated shared key of each position, all heads' keys
+  and values in one row (``models/pangu_moe.py``);
 * ``ssm``       a recurrent state, ``(slots, d_state, d_inner)``;
 * ``conv``      the tail of inputs a causal convolution continues from.
 
@@ -45,7 +49,7 @@ from ..base import MXNetError
 
 __all__ = ["KVCachePool", "STATE_KINDS", "check_spec"]
 
-STATE_KINDS = ("kv_full", "kv_window", "ssm", "conv")
+STATE_KINDS = ("kv_full", "kv_window", "kv_latent", "ssm", "conv")
 
 
 def check_spec(spec, slots: int):

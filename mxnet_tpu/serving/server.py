@@ -132,7 +132,8 @@ class Server:
     """Continuously batched serving over a model-zoo decoder: anything
     exposing ``state_spec``/``prefill``/``decode_step`` over a flat state
     list and ``model.vocab_size`` (``LlamaForCausalLM``,
-    ``SambaYForCausalLM``, ``AfmoeForCausalLM``).  A model may also
+    ``SambaYForCausalLM``, ``AfmoeForCausalLM``,
+    ``PanguMoeForCausalLM``).  A model may also
     declare ``statistics`` (docs/serving.md, "Model statistics"):
     ``(counter, help)`` rows whose per-call counts, left by ``prefill`` /
     ``decode_step`` in ``lm.last_statistics``, ride out of every program

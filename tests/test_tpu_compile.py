@@ -5,8 +5,9 @@ Interpret mode cannot see what Mosaic refuses (a slice off the tiling,
 too much VMEM, a kernel that cannot be partitioned); this file can, at
 about two seconds a kernel and no chip time.  Shapes are the main
 path's real widths: BERT-base, a Mistral prefill, 4096-causal, the
-``window=`` band, the ``kmask=`` variant, one ``rtc.PallasKernel``, and
-the per-row K,V page write of every serving cell's decode program.
+``window=`` band, the ``kmask=`` variant, one ``rtc.PallasKernel``, the
+per-row K,V page write of every serving cell's decode program, and the
+absorbed latent attention of a Pangu decode round.
 Nothing runs, so nothing here says a kernel is RIGHT or FAST — that is
 ``chip_smoke.py`` and ``tests/test_on_tpu.py``.
 
@@ -16,6 +17,7 @@ in a ``skipif`` or in ``parametrize``; every compile happens in this
 process; jax's persistent compilation cache is off around them (an
 entry written for a described device cannot be read back without one).
 """
+import math
 import os
 
 import jax
@@ -118,6 +120,8 @@ _PAGES = {
     "phi4_full_96x1536": (96, 1536, 20, 64),
     "mistral_48x384": (48, 384, 8, 128),
     "mistral_24x1152": (24, 1152, 8, 128),
+    # a latent page: one 576-wide row a position, positions-minor
+    "pangu_latent_160x3072": (160, 3072, 576),
     # no cell's: a positions-minor page that ends in a partial lane block
     "ragged_96x600": (96, 600, 20, 64),
 }
@@ -156,7 +160,7 @@ def test_page_write_is_one_in_place_op_for_v5e(one_chip, name,
     text = compiled.as_text()
     assert not re.search(r" while\(", text)
     mem = compiled.memory_analysis()
-    page_bytes = 2 * shape[0] * shape[1] * shape[2] * shape[3]
+    page_bytes = 2 * math.prod(shape)
     # the device's bytes: a page off the tiling is padded up to it
     assert page_bytes <= mem.alias_size_in_bytes < page_bytes * 1.1
     assert mem.temp_size_in_bytes < page_bytes // 8
@@ -169,3 +173,33 @@ def test_page_write_is_one_in_place_op_for_v5e(one_chip, name,
         assert _mosaic_calls(compiled) == 0
     else:
         assert _mosaic_calls(compiled) == 1           # positions-minor
+
+
+def test_absorbed_latent_decode_expands_no_key_or_value_for_v5e(one_chip):
+    """The decode mode of ``_contrib_LatentAttention`` at the Pangu cell's
+    shapes (160 slots x 3,072 positions, 128 heads of 128 + 64 / 128 over
+    576-wide rows): nothing shaped like a per-head key or value of the
+    cached positions (``(slots, positions, 128, ...)``) exists in the
+    compiled program, the page is read as it is stored (no page-sized
+    copy), and the temporaries are the scores' (float32 and, rounded,
+    bfloat16), not an expansion's 25 GB."""
+    import re
+    from mxnet_tpu.ops.latent_attention import latent_attention
+    slots, positions, heads, row = 160, 3072, 128, 576
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, page, w, off: latent_attention(
+            q, page, w, off, nope_dim=128, v_dim=128, use_offset=True)
+    ).lower(sds((slots, 1, heads, 192)), sds((slots, positions, row)),
+            sds((heads * 256, 512)), sds((slots,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert not re.search(r"\[%d,%d,%d,\d+\]" % (slots, positions, heads),
+                         text)
+    assert not re.search(r"= bf16\[%d,%d,%d\]\S* copy\(" % (
+        slots, positions, row), text)
+    scores = slots * heads * positions
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * (4 + 2) * scores
+    assert _mosaic_calls(compiled) == 0

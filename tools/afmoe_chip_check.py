@@ -1,6 +1,8 @@
 #!/usr/bin/env python3
-"""The limits of ``correct`` in ``trinity_large_ep8.decode_closed``, read
-ON THE CHIP through the harness's own comparison.
+"""The limits of ``correct`` in a routed-expert cell
+(``trinity_large_ep8.decode_closed``, or ``--cell
+pangu_ultra_moe_ep16.longgen_closed``), read ON THE CHIP through the
+harness's own comparison.
 
     chiprun -- python tools/afmoe_chip_check.py --seeds 2147491201,...
 
@@ -15,8 +17,12 @@ Then, on the SAME served tokens, the readings the limits were set from,
 one JSON line each: the reference picking for itself (``own``) and given
 the program's picks (``given``), at the stated precision and with the
 ``float8`` control, each with the worst served token's regret and, for
-``given``, where the picks differ and by what margin.  PERF.md section 6
-(PR 33) has the readings.  ``--rehearse`` runs the config's toy shapes on
+``given``, where the picks differ and by what margin; then every further
+control the builder's reference knows (a ``PRECISIONS`` key beyond those
+three: Pangu's ``softmax_bfloat16``), and for a latent-attention model a
+reference whose shared rotated key is zero (the ``q_r . k_r`` term left
+out), each as the cell would run it.  PERF.md section 6 (PR 33, PR 35)
+has the readings.  ``--rehearse`` runs the config's toy shapes on
 any backend.
 """
 import argparse
@@ -45,6 +51,7 @@ def main():
     ap.add_argument("--rehearse", action="store_true",
                     help="the config's toy rehearsal shapes, any backend")
     ap.add_argument("--seeds", default="2147491201")
+    ap.add_argument("--cell", default=CELL)
     args = ap.parse_args()
 
     from tools import jax_cache
@@ -54,7 +61,8 @@ def main():
     from chipbench.drivers import serve_loop
     from chipbench.harness import resolve, runtime
 
-    workload, config, traffic = resolve.cell(resolve.load_benchmark(), CELL)
+    workload, config, traffic = resolve.cell(resolve.load_benchmark(),
+                                             args.cell)
     seeds = [int(s) for s in args.seeds.split(",")]
     run = runtime.Run(
         types.SimpleNamespace(seed=seeds[0], seconds=0.0, trace=0,
@@ -104,6 +112,28 @@ def main():
             flush=True)
         weights, cfg, held = builder._weights_and_config(net, ctx)
         chosen = builder.served_picks(net, len(tokens))
+        limit = float(run.traffic["probe"]["gap_share"])
+        controls = [name for name in builder.PRECISIONS
+                    if name not in ("float32", "stated", "float8")]
+        if any(k.endswith("attn_dkv_weight") for k in weights):
+            controls.append("no_k_r_term")
+        for name in controls:
+            w, precision = weights, name
+            if name == "no_k_r_term":
+                # latent attention without its shared rotated key: the
+                # rows of W_dkv that make k_r are zero, so q_r . k_r adds
+                # nothing
+                rope = int(run.shapes["qk_rope_head_dim"])
+                w, precision = {
+                    k: (v.at[-rope:].set(0) if k.endswith("attn_dkv_weight")
+                        else v) for k, v in weights.items()}, "stated"
+            worst = max(_regrets(builder.forward_logits(
+                w, tokens, cfg, precision, held, selections=chosen), req))
+            del w       # nothing may hold the old weights at a re-draw
+            print(json.dumps({
+                "seed": seed, "rule": f"the {name} control, given the "
+                "served picks", "probe_worst_regret_share": worst,
+                "gap_share": limit, "held": worst <= limit}), flush=True)
         for precision in ("stated", "float8"):
             for name, given in (("own", None), ("given", chosen)):
                 routing = {}
