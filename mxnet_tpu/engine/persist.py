@@ -62,8 +62,9 @@ __all__ = ["enabled", "cache_dir", "fingerprint", "aval_sig",
 #: in the programs we serialize — the tier-1 suite asserts a salt bump
 #: misses cleanly).  2: PR 34 rewrote the per-row page write inside
 #: every served decode program; no aval moved, so only the salt keeps
-#: an older checkout's executable out (ROADMAP D16)
-LIBRARY_SALT = "mxtpu-compile-cache-2"
+#: an older checkout's executable out (ROADMAP D16).  3: PR 36 gave the
+#: absorbed latent attention inside Pangu's decode program a kernel
+LIBRARY_SALT = "mxtpu-compile-cache-3"
 
 _MAGIC = b"MXTPUCC1"
 #: header layout version; 2 added ``devices`` (an entry without it

@@ -165,8 +165,8 @@ class PanguMoeModel(HybridBlock):
     @staticmethod
     def count_attention(stats, live, attended):
         """One latent-attention call: ``live`` positions a request had
-        written among the ``attended`` ones (the last three rows of
-        ``PanguMoeForCausalLM.statistics``)."""
+        written among the ``attended`` ones the call ran over (the last
+        three rows of ``PanguMoeForCausalLM.statistics``)."""
         for i, c in zip((-3, -2, -1), (live, attended, 1)):
             stats[i] = stats[i] + c
 
@@ -197,8 +197,10 @@ class PanguMoeForCausalLM(AfmoeForCausalLM):
          "positions a request had written among those its latent "
          "attention ran over, summed over attention-layer calls"),
         ("mxtpu_mla_page_positions_total",
-         "positions the latent attention ran over (rows x page or "
-         "prompt length), summed over attention-layer calls"),
+         "positions the latent attention ran over (a prompt's length a "
+         "row; in decode the blocks its kernel walked, or rows x page "
+         "where the dense lowering ran), summed over attention-layer "
+         "calls"),
         ("mxtpu_mla_layer_calls_total", "latent-attention layer calls"),
     )
 
@@ -268,7 +270,8 @@ class PanguMoeForCausalLM(AfmoeForCausalLM):
         picked = []
         for layer, page in zip(m.layers, state):
             mix = layer.attn.step(layer.ln1(h).astype(wdt), page, offset)
-            m.count_attention(stats, live, b * page.shape[1])
+            m.count_attention(
+                stats, live, nd._contrib_LatentAttentionWalked(page, offset))
             h = layer.finish(h, mix, wdt, None, stats, picked)
         self.last_statistics = stats + self._picks(picked)
         return self._head(h)

@@ -127,17 +127,64 @@ def test_full_forward_matches_the_reference(net):
     _close(net(nd.array(toks[None])).asnumpy()[0], want, "full forward")
 
 
-@pytest.mark.parametrize("stale", ["zeros", "an_evicted_requests_rows"])
+@pytest.fixture
+def take_the_walk(monkeypatch):
+    """``take(blk)``: from here to the test's end the decode attention is
+    lowered as a TPU with a positions-minor page lowers it: the kernel,
+    through the Pallas interpreter, ``blk`` positions a block.  The
+    engine keeps an op's executable by name and avals, so what either
+    lowering compiled is dropped on the way in and on the way out."""
+    from mxnet_tpu import engine
+    from mxnet_tpu.ops import latent_attention as la
+    from mxnet_tpu.ops import page_write
+    from mxnet_tpu.ops.registry import get_op
+
+    def forget():
+        la._walk_rows.cache_clear()
+        for op in ("_contrib_LatentAttention",
+                   "_contrib_LatentAttentionWalked"):
+            engine.drop_cached(op)
+
+    def take(blk):
+        # jax keeps a trace by the traced function's identity: the count
+        # (no attrs, so the engine jits the registered function itself)
+        # is traced through a stand-in that dies with the test
+        count = get_op("_contrib_LatentAttentionWalked")
+        monkeypatch.setattr(count, "fcompute", lambda *a, f=count.fcompute:
+                            f(*a))
+        monkeypatch.setattr(page_write, "_positions_on_lanes",
+                            lambda shape, dtype: True)
+        monkeypatch.setattr(la, "_LANES", min(blk, 16))     # toy tiles
+        monkeypatch.setattr(la, "_BLK", blk)
+        monkeypatch.setattr(la, "_INTERPRET", True)
+        forget()
+        return la
+
+    yield take
+    monkeypatch.undo()
+    forget()
+
+
+@pytest.mark.parametrize("stale", ["zeros", "an_evicted_requests_rows",
+                                   "kernel"])
 def test_expanded_prefill_then_absorbed_decode_rows_at_their_own_offsets(
-        net, stale):
+        net, stale, take_the_walk, monkeypatch):
     """The call shapes the server's programs make: a right-padded batch
     prefilled (EXPANDED) at each row's ``last_pos``, then one token a row
     (ABSORBED) at (B,) offsets, against the reference's one full forward.
     A page that an evicted request filled to its end reads the same: rows
-    past a slot's offset are never attended."""
+    past a slot's offset are never attended.  ``kernel``: the same over
+    an evicted request's rows with the decode attention walking the page
+    in blocks of 8 positions (the rows cross three block edges), the
+    float32 softmax held at ``TOL`` THROUGH the kernel."""
     lens, new = (6, 14), 18
     seqs = [_tokens(10 + i, n + new) for i, n in enumerate(lens)]
     wants = [_reference(net, s)[0] for s in seqs]
+    if stale == "kernel":
+        la = take_the_walk(8)
+        walks = []
+        monkeypatch.setattr(la, "_walk_call", lambda *a, f=la._walk_call:
+                            walks.append(1) or f(*a))
     state = net.init_cache(2, max(lens) + new)
     assert [b.shape for b in state] == [(2, S, ROW)] * LAYERS
     if stale != "zeros":
@@ -159,6 +206,8 @@ def test_expanded_prefill_then_absorbed_decode_rows_at_their_own_offsets(
         got = net.decode_step(nd.array(tok), state, off).asnumpy()
         for i, n in enumerate(lens):
             _close(got[i], wants[i][n + step], f"row {i} step {step}")
+    if stale == "kernel":
+        assert walks, "the decode steps never reached the kernel"
 
 
 def test_served_tokens_are_the_references_argmax_and_generates(net):
@@ -210,6 +259,109 @@ def test_absorbed_equals_expanded(h, dn, dr, dv, rkv, c):
             **attrs).asnumpy()
         for i in range(b):
             _close(got[i, 0], want[i, int(offs[i])], f"t={t} row {i}")
+
+
+def _walk_case(dtype, c, offs, seed=0):
+    import jax.numpy as jnp
+    rng = np.random.RandomState(seed)
+    b, h, rkv, dr = len(offs), 4, 16, 8
+    qq = jnp.asarray(rng.randn(b, h, rkv + dr), dtype)
+    page = jnp.asarray(rng.randn(b, c, rkv + dr), dtype)
+    return qq, page, jnp.asarray(np.asarray(offs, "i4")), rkv, 24 ** -0.5
+
+
+# blk 32: offsets at 0, blk - 1, blk, the page's end, and mixed in one
+# batch; 80 is no multiple of blk (its last block starts early)
+@pytest.mark.parametrize("c,offs", [
+    (64, (0,) * 3), (64, (31,) * 3), (64, (32,) * 3), (64, (63,) * 3),
+    (64, (0, 31, 32, 63, 17, 40)), (80, (0, 31, 32, 79, 64, 63)),
+    (16, (0, 15, 7))])
+@pytest.mark.parametrize("dtype,tol", [("float32", TOL), ("bfloat16", 1e-2)])
+def test_the_walk_is_the_definition(take_the_walk, dtype, tol, c, offs):
+    """The kernel (Pallas interpreter) against ``_attend_dense`` on the
+    same operands: float32 at the model's own ``TOL`` (scores, running
+    maximum, sum and accumulator are float32 in the kernel), bfloat16 at
+    its rounding."""
+    la = take_the_walk(32)
+    qq, page, off, rkv, scale = _walk_case(dtype, c, offs)
+    want = np.asarray(la._attend_dense(qq, page, off, rkv, scale)
+                      .astype("float32"))
+    got = la._walk_call(qq, page, off, rkv, scale)
+    assert got.shape == want.shape and str(got.dtype) == dtype
+    err = _err(got.astype("float32"), want)
+    assert err <= tol, f"{err:.2e} of the largest value"
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("c", [64, 80])
+def test_the_walk_reads_nothing_past_an_offset(take_the_walk, dtype, c):
+    """What an evicted request left past a row's offset (large, finite)
+    against zeros there: bit-equal outputs."""
+    import jax.numpy as jnp
+    la = take_the_walk(32)
+    offs = (0, 31, 32, c - 2, 17, 40)
+    qq, page, off, rkv, scale = _walk_case(dtype, c, offs, seed=3)
+    past = np.arange(c)[None, :, None] > np.asarray(offs)[:, None, None]
+    stale = jnp.where(past, jnp.asarray(1e4, page.dtype) * page, page)
+    clean = jnp.where(past, jnp.zeros((), page.dtype), page)
+    np.testing.assert_array_equal(
+        np.asarray(la._walk_call(qq, stale, off, rkv, scale)
+                   .astype("float32")),
+        np.asarray(la._walk_call(qq, clean, off, rkv, scale)
+                   .astype("float32")))
+
+
+@pytest.mark.parametrize("path", ["dense", "walk", "walk_ragged_page",
+                                  "page_off_the_lane_tiles"])
+def test_walked_positions_are_those_of_the_path_taken(take_the_walk,
+                                                      monkeypatch, path):
+    """``_contrib_LatentAttentionWalked`` and the op share one decision:
+    rows x page where the definition runs, the walked blocks x ``blk``
+    where the kernel does (a count that said "walked" over a dense pass
+    would be a false reading), and the op's output is the definition's
+    either way."""
+    from mxnet_tpu.ops import latent_attention as la
+    c = {"walk_ragged_page": 80, "page_off_the_lane_tiles": 72}.get(path, 64)
+    offs = np.array([0, 31, 32, c - 1, 40], "f4")
+    rng = np.random.RandomState(1)
+    ins = [nd.array(rng.randn(5, 1, 4, 24).astype("f4")),
+           nd.array(rng.randn(5, c, 24).astype("f4")),
+           nd.array((rng.randn(4 * 32, 16) / 4).astype("f4")),
+           nd.array(offs)]
+    attrs = dict(nope_dim=16, v_dim=16, use_offset=True)
+    want = nd._contrib_LatentAttention(*ins, **attrs).asnumpy()
+    if path != "dense":
+        take_the_walk(32)
+    calls = []
+    monkeypatch.setattr(la, "_walk_call", lambda *a, f=la._walk_call:
+                        calls.append(1) or f(*a))
+    got = nd._contrib_LatentAttention(*ins, **attrs).asnumpy()
+    walked = int(nd._contrib_LatentAttentionWalked(ins[1], ins[3]).asnumpy())
+    if path in ("dense", "page_off_the_lane_tiles"):
+        # 72 positions are no whole tiles of (here) 32 lanes
+        assert not calls and walked == 5 * c
+    else:
+        assert calls and walked == 32 * sum(int(o) // 32 + 1 for o in offs)
+    _close(got, want, path)
+
+
+def test_the_walk_under_a_dp_plan_gathers_no_page(take_the_walk):
+    """Four devices, the rows split over them: the partitioner is told
+    rows are independent, each shard's kernel walks its own rows, and
+    the compiled program holds no all-gather (of the page or of anything
+    else)."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    la = take_the_walk(32)
+    offs = (0, 31, 32, 63, 17, 40, 5, 62)
+    qq, page, off, rkv, scale = _walk_case("float32", 64, offs)
+    by_rows = NamedSharding(Mesh(np.array(jax.devices()[:4]), ("dp",)),
+                            PartitionSpec("dp"))
+    args = [jax.device_put(a, by_rows) for a in (qq, page, off)]
+    fn = jax.jit(la._walk_rows(rkv, scale), out_shardings=by_rows)
+    text = fn.lower(*args).compile().as_text()
+    assert "all-gather" not in text and "all-reduce" not in text
+    _close(fn(*args), np.asarray(la._attend_dense(qq, page, off, rkv, scale)))
 
 
 def test_the_op_refuses_shapes_that_do_not_fit():
@@ -351,7 +503,10 @@ def test_statistics_are_a_numpy_count():
     lm.decode_step(nd.array(tok), state, nd.array(np.array(lens, "f4")))
     got = [int(c.asnumpy()) for c in lm.last_statistics[:7]]
     assert got[:4] == count_of([picks[i][n] for i, n in enumerate(lens)])
-    # each row attends its whole page of 24; offset + 1 of them are live
+    # each row attends its whole page of 24 (the dense lowering: no TPU
+    # here; the kernel's count is
+    # ``test_walked_positions_are_those_of_the_path_taken``); offset + 1
+    # of them are live
     assert got[4:] == [LAYERS * (7 + 14), LAYERS * 2 * 24, LAYERS]
     assert [n for n, _doc in lm.statistics] == [
         "mxtpu_moe_assignments_held_total",

@@ -175,17 +175,28 @@ def test_page_write_is_one_in_place_op_for_v5e(one_chip, name,
         assert _mosaic_calls(compiled) == 1           # positions-minor
 
 
-def test_absorbed_latent_decode_expands_no_key_or_value_for_v5e(one_chip):
+@pytest.mark.parametrize("lowering", ["walk", "dense"])
+def test_absorbed_latent_decode_expands_no_key_or_value_for_v5e(
+        one_chip, lowering, monkeypatch):
     """The decode mode of ``_contrib_LatentAttention`` at the Pangu cell's
     shapes (160 slots x 3,072 positions, 128 heads of 128 + 64 / 128 over
     576-wide rows): nothing shaped like a per-head key or value of the
     cached positions (``(slots, positions, 128, ...)``) exists in the
-    compiled program, the page is read as it is stored (no page-sized
-    copy), and the temporaries are the scores' (float32 and, rounded,
-    bfloat16), not an expansion's 25 GB."""
+    compiled program and the page is read as it is stored (no page-sized
+    copy).  ``walk``: the described chip answers for how a page is
+    stored (positions-minor), as an attached one does: the attention is
+    exactly ONE Mosaic call, no float32 score array exists anywhere and
+    the temporaries stay under 64 MB.  ``dense``: what a host with no
+    TPU attached lowers for one: the definition, whose temporaries are
+    the scores' (float32 and, rounded, bfloat16), not an expansion's
+    25 GB."""
     import re
+    from mxnet_tpu.ops import page_write
     from mxnet_tpu.ops.latent_attention import latent_attention
     slots, positions, heads, row = 160, 3072, 128, 576
+    if lowering == "walk":
+        chip, = one_chip.device_set
+        monkeypatch.setattr(page_write, "_tpu_device", lambda: chip)
 
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -201,5 +212,15 @@ def test_absorbed_latent_decode_expands_no_key_or_value_for_v5e(one_chip):
     assert not re.search(r"= bf16\[%d,%d,%d\]\S* copy\(" % (
         slots, positions, row), text)
     scores = slots * heads * positions
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 * (4 + 2) * scores
-    assert _mosaic_calls(compiled) == 0
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if lowering == "walk":
+        assert _mosaic_calls(compiled) == 1
+        # the page seen positions-minor is the stored bytes
+        assert not re.search(r"= bf16\[%d,%d,%d\]\S* (copy|transpose)\(" % (
+            slots, row, positions), text)
+        assert not re.search(r"f32\[%d,%d,%d\]" % (slots, heads, positions),
+                             text)
+        assert temp < 64 * 2**20
+    else:
+        assert _mosaic_calls(compiled) == 0
+        assert temp < 2 * (4 + 2) * scores
