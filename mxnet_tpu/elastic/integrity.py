@@ -60,6 +60,7 @@ import threading
 from typing import List, Optional, Sequence, Tuple
 
 from ..base import MXNetError
+from ..profiler import device_scope as _device_scope
 
 __all__ = ["IntegritySpec", "enabled", "action", "trace_signature",
            "build_spec", "fingerprint", "body_rows", "jit_block",
@@ -299,10 +300,16 @@ def jit_block(spec: IntegritySpec, mesh, dp_axis: str, param_leaves,
     Returns ``(grads, rows)`` — ``grads`` unchanged (and NOT routed
     through the block) when no drill is armed, so the production
     program carries only the sampled fingerprint reductions."""
-    from jax.sharding import PartitionSpec as P
-    from jax import shard_map
     if spec is None:
         return grad_leaves, None
+    with _device_scope("mxtpu.step.integrity"):
+        return _jit_block(spec, mesh, dp_axis, param_leaves, grad_leaves,
+                          due, ictl)
+
+
+def _jit_block(spec, mesh, dp_axis, param_leaves, grad_leaves, due, ictl):
+    from jax.sharding import PartitionSpec as P
+    from jax import shard_map
     other = tuple(a for a in mesh.axis_names if a != dp_axis)
     n_p, n_g = len(param_leaves), len(grad_leaves)
 

@@ -63,8 +63,11 @@ __all__ = ["enabled", "cache_dir", "fingerprint", "aval_sig",
 #: misses cleanly).  2: PR 34 rewrote the per-row page write inside
 #: every served decode program; no aval moved, so only the salt keeps
 #: an older checkout's executable out (ROADMAP D16).  3: PR 36 gave the
-#: absorbed latent attention inside Pangu's decode program a kernel
-LIBRARY_SALT = "mxtpu-compile-cache-3"
+#: absorbed latent attention inside Pangu's decode program a kernel.
+#: 4: PR 37 put ``mxtpu.*`` device scopes on the fused train step and on
+#: BERT's and Llama's blocks: metadata only, but an older entry's
+#: executable reads ``(no scope)`` in ``profiler.device_dumps``
+LIBRARY_SALT = "mxtpu-compile-cache-4"
 
 _MAGIC = b"MXTPUCC1"
 #: header layout version; 2 added ``devices`` (an entry without it

@@ -13,6 +13,7 @@ import math
 import numpy as np
 
 from ...base import MXNetError
+from ...profiler import device_scope
 from ..block import HybridBlock
 from .. import nn
 
@@ -53,18 +54,20 @@ class MultiHeadAttention(HybridBlock):
         s_k = key.shape[1]
         h = self._num_heads
         d = self._units // h
-        q = self.query_proj(query).reshape((b, s_q, h, d))
-        k = self.key_proj(key).reshape((b, s_k, h, d))
-        v = self.value_proj(value).reshape((b, s_k, h, d))
-        if mask is not None:
-            out = F.dot_product_attention(q, k, v, mask, use_mask=True)
-        else:
-            out = F.dot_product_attention(q, k, v)
-        out = out.reshape((b, s_q, self._units))
-        out = self.out_proj(out)
-        if self.drop is not None:
-            out = self.drop(out)
-        return out
+        with device_scope("mxtpu.mixer.full"):
+            q = self.query_proj(query).reshape((b, s_q, h, d))
+            k = self.key_proj(key).reshape((b, s_k, h, d))
+            v = self.value_proj(value).reshape((b, s_k, h, d))
+            if mask is not None:
+                out = F.dot_product_attention(q, k, v, mask,
+                                              use_mask=True)
+            else:
+                out = F.dot_product_attention(q, k, v)
+            out = out.reshape((b, s_q, self._units))
+            out = self.out_proj(out)
+            if self.drop is not None:
+                out = self.drop(out)
+            return out
 
 
 class PositionwiseFFN(HybridBlock):
@@ -79,15 +82,16 @@ class PositionwiseFFN(HybridBlock):
         self._activation = activation
 
     def hybrid_forward(self, F, x):
-        h = self.ffn_1(x)
-        if self._activation == "gelu":
-            h = F.LeakyReLU(h, act_type="gelu")
-        else:
-            h = F.Activation(h, act_type=self._activation)
-        h = self.ffn_2(h)
-        if self.drop is not None:
-            h = self.drop(h)
-        return h
+        with device_scope("mxtpu.mlp"):
+            h = self.ffn_1(x)
+            if self._activation == "gelu":
+                h = F.LeakyReLU(h, act_type="gelu")
+            else:
+                h = F.Activation(h, act_type=self._activation)
+            h = self.ffn_2(h)
+            if self.drop is not None:
+                h = self.drop(h)
+            return h
 
 
 # trace-time count of rematerialized encoder stacks (tests assert the
@@ -116,18 +120,22 @@ class TransformerEncoderCell(HybridBlock):
             self.drop = nn.Dropout(dropout) if dropout else None
 
     def hybrid_forward(self, F, x, mask=None):
-        # Block.__call__ is positional: (query, key, value, mask)
+        # Block.__call__ is positional: (query, key, value, mask).  A
+        # sub-layer's norm and residual add carry the sub-layer's
+        # device scope: they are passes over its output
         if self._pre_norm:
-            att = self.attention(self.layer_norm_att(x), None, None, mask)
-            x = x + att
-            out = self.ffn(self.layer_norm_ffn(x))
-            return x + out
-        att = self.attention(x, None, None, mask)
-        if self.drop is not None:
-            att = self.drop(att)
-        x = self.layer_norm_att(x + att)
-        out = self.ffn(x)
-        return self.layer_norm_ffn(x + out)
+            with device_scope("mxtpu.mixer.full"):
+                x = x + self.attention(self.layer_norm_att(x), None, None,
+                                       mask)
+            with device_scope("mxtpu.mlp"):
+                return x + self.ffn(self.layer_norm_ffn(x))
+        with device_scope("mxtpu.mixer.full"):
+            att = self.attention(x, None, None, mask)
+            if self.drop is not None:
+                att = self.drop(att)
+            x = self.layer_norm_att(x + att)
+        with device_scope("mxtpu.mlp"):
+            return self.layer_norm_ffn(x + self.ffn(x))
 
 
 class TransformerEncoder(HybridBlock):

@@ -47,7 +47,8 @@ from .. import initializer as init
 from ..base import MXNetError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
-from .sambay import _MLP, _dense, _rows, _scope, _split
+from ..profiler import device_scope
+from .sambay import _MLP, _dense, _rows, _split
 
 __all__ = ["AfmoeModel", "AfmoeForCausalLM", "get_afmoe", "afmoe_tiny",
            "trinity_large_ep8"]
@@ -85,7 +86,8 @@ class _GatedAttention(HybridBlock):
         self._h, self._kv, self._d = num_heads, num_kv_heads, head_dim
         self._base = float(rope_base)
         self.window = window                 # None: a full layer, no RoPE
-        self._name = "mxtpu.mixer.swa" if window else "mxtpu.mixer.full"
+        #: the device scope; the layer's norms around it carry it too
+        self.scope = "mxtpu.mixer.swa" if window else "mxtpu.mixer.full"
         h, kv, d = num_heads, num_kv_heads, head_dim
         with self.name_scope():
             # [q, k, v, gate] rows of ONE projection
@@ -123,7 +125,7 @@ class _GatedAttention(HybridBlock):
         k, v) with k as stored (rotated on a window layer); causal,
         banded by the layer's window."""
         from .. import ndarray as nd
-        with _scope(self._name):
+        with device_scope(self.scope):
             q, k, v, g = self._qkvg(a, 0)
             o = nd.dot_product_attention(q, k, v, causal=True,
                                          window=self.window)
@@ -133,7 +135,7 @@ class _GatedAttention(HybridBlock):
         """One token a row at its own ``offset`` (B,): write K,V at
         ``slot`` (B,), attend the buffer under the key mask."""
         from .. import ndarray as nd
-        with _scope(self._name):
+        with device_scope(self.scope):
             q, k, v, g = self._qkvg(a, offset)
             nd._cache_update(cache_k, k, offset=slot, out=cache_k)
             nd._cache_update(cache_v, v, offset=slot, out=cache_v)
@@ -186,12 +188,16 @@ class _RoutedFFN(HybridBlock):
         extra = [] if valid is None else [valid.reshape((b * s,))]
         bias = nd.zeros(self.router.shape[:1], ctx=ctx) \
             if self.bias is None else self.bias.data(ctx)
-        # the op names its own halves mxtpu.moe.router / mxtpu.moe.experts
-        out, held, touched, selected = nd._contrib_RoutedExperts(
-            m.reshape((b * s, u)), self.router.data(ctx),
-            bias, self.gate.data(ctx), self.up.data(ctx),
-            self.down.data(ctx), *extra, use_valid=valid is not None,
-            **self._attrs)
+        # the op names its own halves mxtpu.moe.router / mxtpu.moe.experts.
+        # The TPU compiler gives a grouped product an ``op_name`` of its
+        # own (``ragged-dot-none``) and keeps only the CALL SITE's
+        # prefix: the call says ``.experts`` so that they do
+        with device_scope("mxtpu.moe.experts"):
+            out, held, touched, selected = nd._contrib_RoutedExperts(
+                m.reshape((b * s, u)), self.router.data(ctx),
+                bias, self.gate.data(ctx), self.up.data(ctx),
+                self.down.data(ctx), *extra, use_valid=valid is not None,
+                **self._attrs)
         out = out.reshape((b, s, u)) + self.shared(m).astype("float32")
         return out, [held, touched], selected.reshape((b, s, -1))
 
@@ -218,23 +224,32 @@ class _Layer(HybridBlock):
                                       **moe)
             self.ln4 = _RMSNorm(units, eps, prefix="ln4_")
 
+    def pre(self, h, wdt):
+        """The mixer's input: the first norm, in the weights' dtype."""
+        with device_scope(self.attn.scope):
+            return self.ln1(h).astype(wdt)
+
     def finish(self, h, mix, wdt, valid, stats, picked=None):
         """The attention branch's residual add, then the FFN sublayer;
         an expert layer adds what it counted to ``stats`` (the order of
         ``AfmoeForCausalLM.statistics``) and the experts its rows picked
-        to ``picked``."""
-        h = h + self.ln2(mix)
-        m = self.ln3(h).astype(wdt)
-        if self.dense:
-            return h + self.ln4(self.ffn(m))
-        out, counted, selected = self.ffn.route(m, valid)
-        rows = m.shape[0] * m.shape[1] if valid is None \
-            else valid.sum().astype("int32")
-        for i, c in enumerate(counted + [rows, 1]):
-            stats[i] = stats[i] + c
-        if picked is not None:
-            picked.append(selected)
-        return h + self.ln4(out)
+        to ``picked``.  The norms and adds around a sub-layer carry its
+        device scope (``mxtpu.moe`` around an expert layer, whose router,
+        experts and shared expert name themselves inside it)."""
+        with device_scope(self.attn.scope):
+            h = h + self.ln2(mix)
+        with device_scope("mxtpu.mlp" if self.dense else "mxtpu.moe"):
+            m = self.ln3(h).astype(wdt)
+            if self.dense:
+                return h + self.ln4(self.ffn(m))
+            out, counted, selected = self.ffn.route(m, valid)
+            rows = m.shape[0] * m.shape[1] if valid is None \
+                else valid.sum().astype("int32")
+            for i, c in enumerate(counted + [rows, 1]):
+                stats[i] = stats[i] + c
+            if picked is not None:
+                picked.append(selected)
+            return h + self.ln4(out)
 
 
 class AfmoeModel(HybridBlock):
@@ -284,8 +299,9 @@ class AfmoeModel(HybridBlock):
 
     def embed_scaled(self, tokens):
         """``E[token] * sqrt(units)`` (``mup_enabled``), float32."""
-        return self.embed(tokens).astype("float32") \
-            * math.sqrt(self._units)
+        with device_scope("mxtpu.embed"):
+            return self.embed(tokens).astype("float32") \
+                * math.sqrt(self._units)
 
     def new_statistics(self, ctx):
         from .. import ndarray as nd
@@ -298,13 +314,14 @@ class AfmoeModel(HybridBlock):
         h = self.embed_scaled(tokens)
         wdt = self.compute_dtype()
         for layer in self.layers:
-            mix, _k, _v = layer.attn.seq(layer.ln1(h).astype(wdt))
+            mix, _k, _v = layer.attn.seq(layer.pre(h, wdt))
             h = layer.finish(h, mix, wdt, None, stats)
         return h
 
     def hybrid_forward(self, F, tokens):
-        return self.final_norm(
-            self.run(tokens, self.new_statistics(tokens.context)))
+        h = self.run(tokens, self.new_statistics(tokens.context))
+        with device_scope("mxtpu.head"):
+            return self.final_norm(h)
 
 
 class AfmoeForCausalLM(HybridBlock):
@@ -344,7 +361,7 @@ class AfmoeForCausalLM(HybridBlock):
     def _head(self, h):
         from .. import ndarray as nd
         m = self.model
-        with _scope("mxtpu.head"):
+        with device_scope("mxtpu.head"):
             return nd._head_logits(
                 m.final_norm(h).reshape((-1, m._units))
                 .astype(m.compute_dtype()),
@@ -400,22 +417,25 @@ class AfmoeForCausalLM(HybridBlock):
         if last_pos is None:
             last_pos = _rows(b, s - 1, ctx)
         wdt = m.compute_dtype()
-        pos = nd.arange(s, ctx=ctx).reshape((1, s))
-        valid = pos <= last_pos.reshape((-1, 1))
-        stats = m.new_statistics(ctx)
+        with device_scope("mxtpu.moe"):
+            pos = nd.arange(s, ctx=ctx).reshape((1, s))
+            valid = pos <= last_pos.reshape((-1, 1))
+            stats = m.new_statistics(ctx)
         h = m.embed_scaled(tokens)
         bufs = iter(state)
         picked = []
         for layer in m.layers:
-            mix, k, v = layer.attn.seq(layer.ln1(h).astype(wdt))
-            for buf, new in ((next(bufs), k), (next(bufs), v)):
-                if buf.shape[1] < s:    # a window shorter than the prompt
-                    new = nd._rolling_window_fill(new, last_pos,
-                                                  length=buf.shape[1])
-                nd._cache_update(buf, new, offset=0, out=buf)
+            mix, k, v = layer.attn.seq(layer.pre(h, wdt))
+            with device_scope(layer.attn.scope):
+                for buf, new in ((next(bufs), k), (next(bufs), v)):
+                    if buf.shape[1] < s:    # a window shorter than the prompt
+                        new = nd._rolling_window_fill(
+                            new, last_pos, length=buf.shape[1])
+                    nd._cache_update(buf, new, offset=0, out=buf)
             h = layer.finish(h, mix, wdt, valid, stats, picked)
         self.last_statistics = stats + self._picks(picked, valid)
-        return self._head(nd._take_positions(h, last_pos))
+        with device_scope("mxtpu.head"):
+            return self._head(nd._take_positions(h, last_pos))
 
     @staticmethod
     def _picks(picked, valid=None):
@@ -426,10 +446,11 @@ class AfmoeForCausalLM(HybridBlock):
         from .. import ndarray as nd
         if not picked:
             return []
-        rows = nd.concat(*picked, dim=2)
-        if valid is not None:
-            keep = valid.reshape(valid.shape + (1,)).astype("int32")
-            rows = rows * keep + (keep - 1)
+        with device_scope("mxtpu.moe"):
+            rows = nd.concat(*picked, dim=2)
+            if valid is not None:
+                keep = valid.reshape(valid.shape + (1,)).astype("int32")
+                rows = rows * keep + (keep - 1)
         return [rows]
 
     # -- decode -----------------------------------------------------------
@@ -448,7 +469,8 @@ class AfmoeForCausalLM(HybridBlock):
             offset = offset.reshape((1,)) + nd.zeros((b,), ctx=ctx)
         offv = offset.reshape((-1, 1))
         wdt = m.compute_dtype()
-        stats = m.new_statistics(ctx)
+        with device_scope("mxtpu.moe"):
+            stats = m.new_statistics(ctx)
         h = m.embed_scaled(token)
         masks = {}
 
@@ -465,9 +487,12 @@ class AfmoeForCausalLM(HybridBlock):
         for layer in m.layers:
             ck, cv = next(bufs), next(bufs)
             n = ck.shape[1]
-            slot = offset % float(n) if layer.kind == SLIDING else offset
-            mix = layer.attn.step(layer.ln1(h).astype(wdt), ck, cv, offset,
-                                  slot, key_mask(n))
+            with device_scope(layer.attn.scope):
+                slot = offset % float(n) if layer.kind == SLIDING \
+                    else offset
+                mask = key_mask(n)
+            mix = layer.attn.step(layer.pre(h, wdt), ck, cv, offset, slot,
+                                  mask)
             h = layer.finish(h, mix, wdt, None, stats, picked)
         self.last_statistics = stats + self._picks(picked)
         return self._head(h)

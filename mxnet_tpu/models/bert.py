@@ -17,6 +17,7 @@ from ..base import MXNetError
 from ..gluon.block import HybridBlock
 from ..gluon import nn
 from ..gluon.contrib.nn import TransformerEncoder
+from ..profiler import device_scope
 
 __all__ = ["BERTModel", "BERTForPretrain", "bert_base", "bert_small",
            "bert_large", "get_bert"]
@@ -51,23 +52,28 @@ class BERTModel(HybridBlock):
     def hybrid_forward(self, F, inputs, token_types, valid_length=None,
                        position_embed=None):
         b, s = inputs.shape[0], inputs.shape[1]
-        x = self.word_embed(inputs) + self.token_type_embed(token_types)
-        x = x + F.expand_dims(
-            F.slice_axis(position_embed, axis=0, begin=0, end=s), axis=0)
-        x = self.embed_layer_norm(x)
-        if self.embed_dropout is not None:
-            x = self.embed_dropout(x)
+        with device_scope("mxtpu.embed"):
+            x = self.word_embed(inputs) \
+                + self.token_type_embed(token_types)
+            x = x + F.expand_dims(
+                F.slice_axis(position_embed, axis=0, begin=0, end=s),
+                axis=0)
+            x = self.embed_layer_norm(x)
+            if self.embed_dropout is not None:
+                x = self.embed_dropout(x)
         mask = None
         if valid_length is not None:
             # (B, 1, 1, S) key-padding mask broadcast over heads & queries
-            steps = F.arange(0, s, ctx=inputs.context)
-            mask = F.broadcast_lesser(
-                F.expand_dims(steps, axis=0),
-                F.expand_dims(valid_length.astype("float32"), axis=1))
-            mask = F.expand_dims(F.expand_dims(mask, axis=1), axis=1)
+            with device_scope("mxtpu.mixer.full"):
+                steps = F.arange(0, s, ctx=inputs.context)
+                mask = F.broadcast_lesser(
+                    F.expand_dims(steps, axis=0),
+                    F.expand_dims(valid_length.astype("float32"), axis=1))
+                mask = F.expand_dims(F.expand_dims(mask, axis=1), axis=1)
         seq = self.encoder(x, mask)
-        pooled = self.pooler(F.slice_axis(seq, axis=1, begin=0,
-                                          end=1).reshape((b, -1)))
+        with device_scope("mxtpu.head"):
+            pooled = self.pooler(F.slice_axis(seq, axis=1, begin=0,
+                                              end=1).reshape((b, -1)))
         return seq, pooled
 
 
@@ -103,22 +109,23 @@ class BERTForPretrain(HybridBlock):
     def hybrid_forward(self, F, inputs, token_types, valid_length,
                        masked_positions, mlm_bias=None):
         seq, pooled = self.bert(inputs, token_types, valid_length)
-        mlm_in = _gather_positions(F, seq, masked_positions)
-        h = self.mlm_dense(mlm_in)
-        h = F.LeakyReLU(h, act_type="gelu")
-        h = self.mlm_norm(h)
-        # decode with TIED word-embedding weights: under CachedOp tracing
-        # the weight's buffer holds the trace-time tracer, so gradients
-        # flow to the embedding from both uses
-        word_w = self.bert.word_embed.weight.data(h.context)
-        nsp_scores = self.nsp_classifier(pooled)
-        h2 = h.reshape((-1, h.shape[-1]))
-        if not self._decode_mlm:
-            # fused-CE contract: (hidden, nsp, tied weight, bias) —
-            # feed the first/last two to chunked_softmax_ce_bias
-            return h2, nsp_scores, word_w, mlm_bias
-        mlm_scores = F.dot(h2, word_w, transpose_b=True) + mlm_bias
-        return mlm_scores, nsp_scores
+        with device_scope("mxtpu.head"):
+            mlm_in = _gather_positions(F, seq, masked_positions)
+            h = self.mlm_dense(mlm_in)
+            h = F.LeakyReLU(h, act_type="gelu")
+            h = self.mlm_norm(h)
+            # decode with TIED word-embedding weights: under CachedOp
+            # tracing the weight's buffer holds the trace-time tracer,
+            # so gradients flow to the embedding from both uses
+            word_w = self.bert.word_embed.weight.data(h.context)
+            nsp_scores = self.nsp_classifier(pooled)
+            h2 = h.reshape((-1, h.shape[-1]))
+            if not self._decode_mlm:
+                # fused-CE contract: (hidden, nsp, tied weight, bias) —
+                # feed the first/last two to chunked_softmax_ce_bias
+                return h2, nsp_scores, word_w, mlm_bias
+            mlm_scores = F.dot(h2, word_w, transpose_b=True) + mlm_bias
+            return mlm_scores, nsp_scores
 
 
 def _gather_positions(F, seq, positions):

@@ -21,6 +21,7 @@ from __future__ import annotations
 from ..base import MXNetError
 from ..gluon.block import HybridBlock
 from ..gluon import nn
+from ..profiler import device_scope
 
 __all__ = ["LlamaModel", "LlamaForCausalLM", "RMSNormBlock",
            "get_llama", "llama_tiny", "llama3_8b"]
@@ -63,6 +64,10 @@ class _LlamaAttention(HybridBlock):
         self._impl = attn_impl
         self._sp_axis = sp_axis
         self._window = sliding_window
+        #: the device scope of this mixer and of what the layer and the
+        #: model do on its behalf (its norm, its residual add, its mask)
+        self.scope = "mxtpu.mixer.swa" if sliding_window is not None \
+            else "mxtpu.mixer.full"
         with self.name_scope():
             self.q_proj = nn.Dense(num_heads * self._d, flatten=False,
                                    use_bias=False, in_units=units,
@@ -90,22 +95,23 @@ class _LlamaAttention(HybridBlock):
         from .. import ndarray as nd
         b, s = x.shape[0], x.shape[1]
         h, kv, d = self._h, self._kv, self._d
-        q = nd.rope(self.q_proj(x).reshape((b, s, h, d)),
-                    base=self._base)
-        k = nd.rope(self.k_proj(x).reshape((b, s, kv, d)),
-                    base=self._base)
-        v = self.v_proj(x).reshape((b, s, kv, d))
-        if perm is None:
-            nd._cache_update(cache_k, k, offset=0, out=cache_k)
-            nd._cache_update(cache_v, v, offset=0, out=cache_v)
-        else:
-            nd._cache_update(cache_k, nd.take(k, perm, axis=1),
-                             offset=0, out=cache_k)
-            nd._cache_update(cache_v, nd.take(v, perm, axis=1),
-                             offset=0, out=cache_v)
-        out = nd.dot_product_attention(q, k, v, causal=True,
-                                       window=self._window)
-        return self.o_proj(out.reshape((b, s, h * d)))
+        with device_scope(self.scope):
+            q = nd.rope(self.q_proj(x).reshape((b, s, h, d)),
+                        base=self._base)
+            k = nd.rope(self.k_proj(x).reshape((b, s, kv, d)),
+                        base=self._base)
+            v = self.v_proj(x).reshape((b, s, kv, d))
+            if perm is None:
+                nd._cache_update(cache_k, k, offset=0, out=cache_k)
+                nd._cache_update(cache_v, v, offset=0, out=cache_v)
+            else:
+                nd._cache_update(cache_k, nd.take(k, perm, axis=1),
+                                 offset=0, out=cache_k)
+                nd._cache_update(cache_v, nd.take(v, perm, axis=1),
+                                 offset=0, out=cache_v)
+            out = nd.dot_product_attention(q, k, v, causal=True,
+                                           window=self._window)
+            return self.o_proj(out.reshape((b, s, h * d)))
 
     def step(self, x, cache_k, cache_v, offset, mask, slot=None):
         """Incremental decode: x (B, 1, units), caches
@@ -117,41 +123,46 @@ class _LlamaAttention(HybridBlock):
         from .. import ndarray as nd
         b = x.shape[0]
         h, kv, d = self._h, self._kv, self._d
-        q = nd.rope(self.q_proj(x).reshape((b, 1, h, d)),
-                    offset=offset, base=self._base)
-        k_t = nd.rope(self.k_proj(x).reshape((b, 1, kv, d)),
-                      offset=offset, base=self._base)
-        v_t = self.v_proj(x).reshape((b, 1, kv, d))
-        # dynamic-offset scatter: one compiled program for every step
-        if slot is None:
-            slot = offset
-        nd._cache_update(cache_k, k_t, offset=slot, out=cache_k)
-        nd._cache_update(cache_v, v_t, offset=slot, out=cache_v)
-        # GQA is native in dot_product_attention: the unrepeated cache
-        # is attended directly (no (B, max_len, H, D) materialization)
-        out = nd.dot_product_attention(q, cache_k, cache_v, mask,
-                                       use_mask=True)
-        return self.o_proj(out.reshape((b, 1, h * d)))
+        with device_scope(self.scope):
+            q = nd.rope(self.q_proj(x).reshape((b, 1, h, d)),
+                        offset=offset, base=self._base)
+            k_t = nd.rope(self.k_proj(x).reshape((b, 1, kv, d)),
+                          offset=offset, base=self._base)
+            v_t = self.v_proj(x).reshape((b, 1, kv, d))
+            # dynamic-offset scatter: one compiled program for every step
+            if slot is None:
+                slot = offset
+            nd._cache_update(cache_k, k_t, offset=slot, out=cache_k)
+            nd._cache_update(cache_v, v_t, offset=slot, out=cache_v)
+            # GQA is native in dot_product_attention: the unrepeated
+            # cache is attended directly (no (B, max_len, H, D)
+            # materialization)
+            out = nd.dot_product_attention(q, cache_k, cache_v, mask,
+                                           use_mask=True)
+            return self.o_proj(out.reshape((b, 1, h * d)))
 
     def hybrid_forward(self, F, x):
         b, s = x.shape[0], x.shape[1]
         h, kv, d = self._h, self._kv, self._d
-        q = F.rope(self.q_proj(x).reshape((b, s, h, d)),
-                   base=self._base)
-        k = F.rope(self.k_proj(x).reshape((b, s, kv, d)),
-                   base=self._base)
-        v = self.v_proj(x).reshape((b, s, kv, d))
-        if self._impl == "ring":
-            # the ring kernel groups query heads per KV head internally,
-            # so only the small KV tensors travel the ICI ring
-            from ..parallel.ring_attention import ring_attention_sharded
-            out = ring_attention_sharded(q, k, v, axis=self._sp_axis,
-                                         causal=True)
-        else:
-            # GQA is native in the attention op (grouped einsum)
-            out = F.dot_product_attention(q, k, v, causal=True,
-                                          window=self._window)
-        return self.o_proj(out.reshape((b, s, h * d)))
+        with device_scope(self.scope):
+            q = F.rope(self.q_proj(x).reshape((b, s, h, d)),
+                       base=self._base)
+            k = F.rope(self.k_proj(x).reshape((b, s, kv, d)),
+                       base=self._base)
+            v = self.v_proj(x).reshape((b, s, kv, d))
+            if self._impl == "ring":
+                # the ring kernel groups query heads per KV head
+                # internally, so only the small KV tensors travel the
+                # ICI ring
+                from ..parallel.ring_attention import \
+                    ring_attention_sharded
+                out = ring_attention_sharded(q, k, v, axis=self._sp_axis,
+                                             causal=True)
+            else:
+                # GQA is native in the attention op (grouped einsum)
+                out = F.dot_product_attention(q, k, v, causal=True,
+                                              window=self._window)
+            return self.o_proj(out.reshape((b, s, h * d)))
 
 
 class _LlamaMLP(HybridBlock):
@@ -171,8 +182,9 @@ class _LlamaMLP(HybridBlock):
                                       prefix="down_")
 
     def hybrid_forward(self, F, x):
-        return self.down_proj(F.silu(self.gate_proj(x))
-                              * self.up_proj(x))
+        with device_scope("mxtpu.mlp"):
+            return self.down_proj(F.silu(self.gate_proj(x))
+                                  * self.up_proj(x))
 
 
 class _LlamaLayer(HybridBlock):
@@ -190,19 +202,28 @@ class _LlamaLayer(HybridBlock):
             self.post_norm = RMSNormBlock(units, prefix="postnorm_")
             self.mlp = _LlamaMLP(units, hidden, prefix="mlp_")
 
+    # a sub-layer's norm and residual add carry its device scope
+
+    def _ffn(self, x):
+        with device_scope("mxtpu.mlp"):
+            return x + self.mlp(self.post_norm(x))
+
     def hybrid_forward(self, F, x):
-        x = x + self.attn(self.input_norm(x))
-        return x + self.mlp(self.post_norm(x))
+        with device_scope(self.attn.scope):
+            x = x + self.attn(self.input_norm(x))
+        return self._ffn(x)
 
     def prefill(self, x, cache_k, cache_v, perm=None):
-        x = x + self.attn.prefill(self.input_norm(x), cache_k, cache_v,
-                                  perm=perm)
-        return x + self.mlp(self.post_norm(x))
+        with device_scope(self.attn.scope):
+            x = x + self.attn.prefill(self.input_norm(x), cache_k,
+                                      cache_v, perm=perm)
+        return self._ffn(x)
 
     def step(self, x, cache_k, cache_v, offset, mask, slot=None):
-        x = x + self.attn.step(self.input_norm(x), cache_k, cache_v,
-                               offset, mask, slot=slot)
-        return x + self.mlp(self.post_norm(x))
+        with device_scope(self.attn.scope):
+            x = x + self.attn.step(self.input_norm(x), cache_k, cache_v,
+                                   offset, mask, slot=slot)
+        return self._ffn(x)
 
 
 class LlamaModel(HybridBlock):
@@ -229,11 +250,16 @@ class LlamaModel(HybridBlock):
                 self.layers.append(layer)
             self.final_norm = RMSNormBlock(units, prefix="finalnorm_")
 
+    def embed_tokens(self, tokens):
+        with device_scope("mxtpu.embed"):
+            return self.embed(tokens)
+
     def hybrid_forward(self, F, tokens):
-        x = self.embed(tokens)
+        x = self.embed_tokens(tokens)
         for layer in self.layers:
             x = layer(x)
-        return self.final_norm(x)
+        with device_scope("mxtpu.head"):
+            return self.final_norm(x)
 
 
 class LlamaForCausalLM(HybridBlock):
@@ -266,13 +292,14 @@ class LlamaForCausalLM(HybridBlock):
 
     def hybrid_forward(self, F, tokens):
         h = self.model(tokens)
-        if self._tied:
-            w = self._head_weight(h.context)
-            b, s, u = h.shape
-            return F.dot(h.reshape((b * s, u)), w,
-                         transpose_b=True).reshape(
-                             (b, s, self.model.vocab_size))
-        return self.lm_head(h)
+        with device_scope("mxtpu.head"):
+            if self._tied:
+                w = self._head_weight(h.context)
+                b, s, u = h.shape
+                return F.dot(h.reshape((b * s, u)), w,
+                             transpose_b=True).reshape(
+                                 (b, s, self.model.vocab_size))
+            return self.lm_head(h)
 
     @staticmethod
     def _check_cache_dtype(dtype):
@@ -342,11 +369,12 @@ class LlamaForCausalLM(HybridBlock):
     def _head(self, h):
         """LM-head projection shared by full-forward and decode paths."""
         from .. import ndarray as nd
-        if self._tied:
-            return nd.dot(h.reshape((-1, self.model._units)),
-                          self._head_weight(h.context),
-                          transpose_b=True)
-        return self.lm_head(h).reshape((-1, self.model.vocab_size))
+        with device_scope("mxtpu.head"):
+            if self._tied:
+                return nd.dot(h.reshape((-1, self.model._units)),
+                              self._head_weight(h.context),
+                              transpose_b=True)
+            return self.lm_head(h).reshape((-1, self.model.vocab_size))
 
     def prefill(self, tokens, caches, last_pos=None):
         """Batched prompt pass filling the caches; returns the LAST
@@ -361,7 +389,7 @@ class LlamaForCausalLM(HybridBlock):
         ``last_pos``, as ``models/sambay.py`` does)."""
         import numpy as np
         from .. import ndarray as nd
-        x = self.model.embed(tokens)
+        x = self.model.embed_tokens(tokens)
         s = tokens.shape[1]
         c = caches[0].shape[1]
         perm = None
@@ -375,17 +403,18 @@ class LlamaForCausalLM(HybridBlock):
                 ctx=tokens.context)
         for layer, ck, cv in self._layer_caches(caches):
             x = layer.prefill(x, ck, cv, perm=perm)
-        h = self.model.final_norm(x)
-        if last_pos is None:
-            return self._head(h[:, -1:])
-        b = tokens.shape[0]
-        # per-row gather as a one-hot contraction (hybridizable: no
-        # host-side indices, positions ride as a dynamic input)
-        pos = nd.arange(s, ctx=tokens.context).reshape((1, s))
-        lp = last_pos.reshape((-1, 1))
-        onehot = (pos <= lp) * (pos >= lp)             # (B, S) {0,1}
-        sel = (h * onehot.reshape((b, s, 1))).sum(axis=1)
-        return self._head(sel.reshape((b, 1, self.model._units)))
+        with device_scope("mxtpu.head"):
+            h = self.model.final_norm(x)
+            if last_pos is None:
+                return self._head(h[:, -1:])
+            b = tokens.shape[0]
+            # per-row gather as a one-hot contraction (hybridizable: no
+            # host-side indices, positions ride as a dynamic input)
+            pos = nd.arange(s, ctx=tokens.context).reshape((1, s))
+            lp = last_pos.reshape((-1, 1))
+            onehot = (pos <= lp) * (pos >= lp)             # (B, S) {0,1}
+            sel = (h * onehot.reshape((b, s, 1))).sum(axis=1)
+            return self._head(sel.reshape((b, 1, self.model._units)))
 
     def decode_step(self, token, caches, offset):
         """One incremental step: token (B, 1) → logits (B, vocab).
@@ -398,7 +427,7 @@ class LlamaForCausalLM(HybridBlock):
         specialize per row through the same dynamic-input path, so the
         mixed-depth batch still reuses ONE compiled program)."""
         from .. import ndarray as nd
-        x = self.model.embed(token)
+        x = self.model.embed_tokens(token)
         # key-validity mask (pos <= offset), shared across all layers;
         # offset rides the dynamic-scalar path (nd.full would bake it
         # into static attrs and compile a fresh program per step)
@@ -409,11 +438,23 @@ class LlamaForCausalLM(HybridBlock):
         # per-step path) or a 0-d NDArray (the fused on-device
         # generation loop carries it through lax.scan).
         off = offset if isinstance(offset, nd.NDArray) else float(offset)
-        pos = nd.arange(max_len, ctx=token.context)
         w = self.model.sliding_window
-        if isinstance(off, nd.NDArray) and off.ndim == 1:
-            return self._decode_step_slots(x, caches, off, pos, w,
-                                           max_len)
+        with device_scope(self.model.layers[0].attn.scope):
+            pos = nd.arange(max_len, ctx=token.context)
+            if isinstance(off, nd.NDArray) and off.ndim == 1:
+                slot, mask = self._slot_masks(x.shape[0], off, pos, w,
+                                              max_len)
+            else:
+                slot, mask = self._shared_mask(off, pos, w, max_len)
+        for layer, ck, cv in self._layer_caches(caches):
+            x = layer.step(x, ck, cv, offset, mask, slot=slot)
+        with device_scope("mxtpu.head"):
+            return self._head(self.model.final_norm(x))
+
+    @staticmethod
+    def _shared_mask(off, pos, w, max_len):
+        """(cache write slot or None, key-validity mask) for ONE
+        position every row shares."""
         slot = None
         if w is not None and max_len <= int(w):
             # ROLLING buffer (cache holds exactly the window): slot
@@ -435,20 +476,16 @@ class LlamaForCausalLM(HybridBlock):
                 # entries are live — (off-W, off], same band the
                 # prefill kernels apply
                 mask = mask * (pos > off - float(w))
-        mask = mask.reshape((1, 1, 1, max_len))
-        for layer, ck, cv in self._layer_caches(caches):
-            x = layer.step(x, ck, cv, offset, mask, slot=slot)
-        h = self.model.final_norm(x)
-        return self._head(h)
+        return slot, mask.reshape((1, 1, 1, max_len))
 
-    def _decode_step_slots(self, x, caches, off, pos, w, max_len):
-        """Per-slot decode body: ``off`` is (B,) absolute positions.
+    @staticmethod
+    def _slot_masks(b, off, pos, w, max_len):
+        """The per-slot form: ``off`` is (B,) absolute positions.
         Same math as the shared-offset path, with the mask, rope
         offsets, and cache-scatter slots specialized PER ROW (rope and
         ``_cache_update`` broadcast a (B,)-shaped dynamic offset).
         Rows are independent in attention, so one slot's cache garbage
         (an evicted request) can never reach another's logits."""
-        b = x.shape[0]
         posr = pos.reshape((1, max_len))
         offv = off.reshape((-1, 1))
         slot = None
@@ -461,11 +498,7 @@ class LlamaForCausalLM(HybridBlock):
             mask = posr <= offv
             if w is not None:
                 mask = mask * (posr > offv - float(w))
-        mask = mask.reshape((b, 1, 1, max_len))
-        for layer, ck, cv in self._layer_caches(caches):
-            x = layer.step(x, ck, cv, off, mask, slot=slot)
-        h = self.model.final_norm(x)
-        return self._head(h)
+        return slot, mask.reshape((b, 1, 1, max_len))
 
     def generate(self, tokens, max_new_tokens, temperature=0.0,
                  top_k=0, seed=0, rolling=False,

@@ -37,8 +37,9 @@ from functools import partial
 from ..base import MXNetError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
+from ..profiler import device_scope
 from .afmoe import AfmoeForCausalLM, _Layer, _RMSNorm
-from .sambay import _dense, _rows, _scope, _split
+from .sambay import _dense, _rows, _split
 
 __all__ = ["PanguMoeModel", "PanguMoeForCausalLM", "get_pangu_moe",
            "pangu_moe_tiny", "pangu_ultra_moe_ep16"]
@@ -54,6 +55,8 @@ class _LatentAttention(HybridBlock):
         self._h, self._rkv = num_heads, kv_rank
         self._dn, self._dr, self._dv = nope_dim, rope_dim, v_dim
         self._base = float(rope_base)
+        #: the device scope; the layer's norms around it carry it too
+        self.scope = "mxtpu.mixer.mla"
         h = num_heads
         with self.name_scope():
             self.dq_proj = _dense(q_rank, units, False, "dq_")
@@ -76,7 +79,7 @@ class _LatentAttention(HybridBlock):
         from .. import ndarray as nd
         b, s = a.shape[0], a.shape[1]
         h, dn, dr = self._h, self._dn, self._dr
-        with _scope("mxtpu.mixer.mla.project"):
+        with device_scope("mxtpu.mixer.mla.project"):
             c_q = self.q_norm(self.dq_proj(a)).astype(a.dtype)
             q_n, q_r = _split(
                 self.uq_proj(c_q).reshape((b, s, h, dn + dr)), (dn, dr))
@@ -93,13 +96,13 @@ class _LatentAttention(HybridBlock):
         o = nd._contrib_LatentAttention(
             q, rows, self.ukv.data(q.context), *offset, nope_dim=self._dn,
             v_dim=self._dv, use_offset=bool(offset))
-        with _scope("mxtpu.mixer.mla.project"):
+        with device_scope("mxtpu.mixer.mla.project"):
             return self.o_proj(o)
 
     def seq(self, a):
         """Self-attention over a whole (right-padded) sequence, EXPANDED
         -> (out, the positions' rows as a page stores them)."""
-        with _scope("mxtpu.mixer.mla"):
+        with device_scope(self.scope):
             q, rows = self._inputs(a, 0)
             return self._attend(q, rows), rows
 
@@ -107,7 +110,7 @@ class _LatentAttention(HybridBlock):
         """One token a row at its own ``offset`` (B,): write the row
         into ``page``, attend the page ABSORBED."""
         from .. import ndarray as nd
-        with _scope("mxtpu.mixer.mla"):
+        with device_scope(self.scope):
             q, row = self._inputs(a, offset)
             nd._cache_update(page, row, offset=offset, out=page)
             return self._attend(q, page, offset)
@@ -155,7 +158,8 @@ class PanguMoeModel(HybridBlock):
 
     def embedded(self, tokens):
         """``E[token]``, float32 (the config has no key for a scale)."""
-        return self.embed(tokens).astype("float32")
+        with device_scope("mxtpu.embed"):
+            return self.embed(tokens).astype("float32")
 
     def new_statistics(self, ctx):
         from .. import ndarray as nd
@@ -167,8 +171,9 @@ class PanguMoeModel(HybridBlock):
         """One latent-attention call: ``live`` positions a request had
         written among the ``attended`` ones the call ran over (the last
         three rows of ``PanguMoeForCausalLM.statistics``)."""
-        for i, c in zip((-3, -2, -1), (live, attended, 1)):
-            stats[i] = stats[i] + c
+        with device_scope("mxtpu.mixer.mla"):
+            for i, c in zip((-3, -2, -1), (live, attended, 1)):
+                stats[i] = stats[i] + c
 
     def run(self, tokens, stats):
         """Every layer over the whole sequence: (B, S) -> (B, S, units)
@@ -177,14 +182,15 @@ class PanguMoeModel(HybridBlock):
         h = self.embedded(tokens)
         wdt = self.compute_dtype()
         for layer in self.layers:
-            mix, _rows = layer.attn.seq(layer.ln1(h).astype(wdt))
+            mix, _rows = layer.attn.seq(layer.pre(h, wdt))
             self.count_attention(stats, b * s, b * s)
             h = layer.finish(h, mix, wdt, None, stats)
         return h
 
     def hybrid_forward(self, F, tokens):
-        return self.final_norm(
-            self.run(tokens, self.new_statistics(tokens.context)))
+        h = self.run(tokens, self.new_statistics(tokens.context))
+        with device_scope("mxtpu.head"):
+            return self.final_norm(h)
 
 
 class PanguMoeForCausalLM(AfmoeForCausalLM):
@@ -234,19 +240,22 @@ class PanguMoeForCausalLM(AfmoeForCausalLM):
         if last_pos is None:
             last_pos = _rows(b, s - 1, ctx)
         wdt = m.compute_dtype()
-        pos = nd.arange(s, ctx=ctx).reshape((1, s))
-        valid = pos <= last_pos.reshape((-1, 1))
-        live = valid.sum().astype("int32")
-        stats = m.new_statistics(ctx)
+        with device_scope("mxtpu.moe"):
+            pos = nd.arange(s, ctx=ctx).reshape((1, s))
+            valid = pos <= last_pos.reshape((-1, 1))
+            live = valid.sum().astype("int32")
+            stats = m.new_statistics(ctx)
         h = m.embedded(tokens)
         picked = []
         for layer, page in zip(m.layers, state):
-            mix, rows = layer.attn.seq(layer.ln1(h).astype(wdt))
-            nd._cache_update(page, rows, offset=0, out=page)
+            mix, rows = layer.attn.seq(layer.pre(h, wdt))
+            with device_scope(layer.attn.scope):
+                nd._cache_update(page, rows, offset=0, out=page)
             m.count_attention(stats, live, b * s)
             h = layer.finish(h, mix, wdt, valid, stats, picked)
         self.last_statistics = stats + self._picks(picked, valid)
-        return self._head(nd._take_positions(h, last_pos))
+        with device_scope("mxtpu.head"):
+            return self._head(nd._take_positions(h, last_pos))
 
     # -- decode -----------------------------------------------------------
     def decode_step(self, token, state, offset):
@@ -264,14 +273,16 @@ class PanguMoeForCausalLM(AfmoeForCausalLM):
         elif offset.ndim == 0:
             offset = offset.reshape((1,)) + nd.zeros((b,), ctx=ctx)
         wdt = m.compute_dtype()
-        stats = m.new_statistics(ctx)
-        live = (offset + 1).sum().astype("int32")
+        with device_scope("mxtpu.mixer.mla"):
+            stats = m.new_statistics(ctx)
+            live = (offset + 1).sum().astype("int32")
         h = m.embedded(token)
         picked = []
         for layer, page in zip(m.layers, state):
-            mix = layer.attn.step(layer.ln1(h).astype(wdt), page, offset)
-            m.count_attention(
-                stats, live, nd._contrib_LatentAttentionWalked(page, offset))
+            mix = layer.attn.step(layer.pre(h, wdt), page, offset)
+            with device_scope(layer.attn.scope):
+                walked = nd._contrib_LatentAttentionWalked(page, offset)
+            m.count_attention(stats, live, walked)
             h = layer.finish(h, mix, wdt, None, stats, picked)
         self.last_statistics = stats + self._picks(picked)
         return self._head(h)

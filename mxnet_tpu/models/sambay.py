@@ -34,6 +34,7 @@ from .. import initializer as init
 from ..base import MXNetError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
+from ..profiler import device_scope
 
 __all__ = ["SambaYModel", "SambaYForCausalLM", "get_sambay",
            "sambay_tiny", "phi4_mini_flash", "layer_kind"]
@@ -46,11 +47,6 @@ def layer_kind(l, n):
     if l < n // 2:
         return "swa"
     return "full" if l == n // 2 + 1 else "cross"
-
-
-def _scope(name):
-    import jax
-    return jax.named_scope(name)
 
 
 def _rows(b, value, ctx):
@@ -106,7 +102,7 @@ class _MLP(HybridBlock):
             self.down = _dense(units, hidden, False, "down_")
 
     def hybrid_forward(self, F, x):
-        with _scope(self._scope_name):
+        with device_scope(self._scope_name):
             gate, up = _split(self.gateup(x), (self._hidden, self._hidden))
             return self.down(up * F.silu(gate))
 
@@ -153,7 +149,7 @@ class _Mamba(HybridBlock):
         (B, K-1, Di), state (B, N, Di) float32)."""
         from .. import ndarray as nd
         ctx = u.context
-        with _scope("mxtpu.mixer.mamba"):
+        with device_scope("mxtpu.mixer.mamba"):
             x, z = _split(self.in_proj(u), (self._di, self._di))
             x, tail = nd._causal_conv1d(x, self.conv_weight.data(ctx),
                                         self.conv_bias.data(ctx), last_pos)
@@ -168,7 +164,7 @@ class _Mamba(HybridBlock):
         from .. import ndarray as nd
         ctx = u.context
         b = u.shape[0]
-        with _scope("mxtpu.mixer.mamba"):
+        with device_scope("mxtpu.mixer.mamba"):
             x, z = _split(self.in_proj(u).reshape((b, 2 * self._di)),
                                (self._di, self._di))
             x, new_tail = nd._causal_conv1d_step(
@@ -244,7 +240,7 @@ class _DiffAttention(HybridBlock):
     def seq(self, u):
         """Self-attention over a whole (right-padded) sequence ->
         (out, k, v); causal, banded by the layer's window."""
-        with _scope("mxtpu.mixer.swa" if self.window else
+        with device_scope("mxtpu.mixer.swa" if self.window else
                     "mxtpu.mixer.full"):
             q, k, v = self.qkv(u)
             return self._attend(q, k, v, causal=True), k, v
@@ -253,7 +249,7 @@ class _DiffAttention(HybridBlock):
         """One token: write K,V at ``slot`` (B,), attend the buffer under
         the key mask (B, 1, 1, C)."""
         from .. import ndarray as nd
-        with _scope("mxtpu.mixer.swa" if self.window else
+        with device_scope("mxtpu.mixer.swa" if self.window else
                     "mxtpu.mixer.full"):
             q, k, v = self.qkv(u)
             nd._cache_update(cache_k, k, offset=slot, out=cache_k)
@@ -263,7 +259,7 @@ class _DiffAttention(HybridBlock):
     def cross(self, u, k, v, mask=None, causal=False):
         """Cross layer: Q from ``u``, the full layer's K,V as given."""
         b, s = u.shape[0], u.shape[1]
-        with _scope("mxtpu.mixer.cross"):
+        with device_scope("mxtpu.mixer.cross"):
             q = self.q_proj(u).reshape((b, s, self._h, self._d))
             return self._attend(q, k, v, mask=mask, causal=causal)
 
@@ -278,7 +274,7 @@ class _GMU(HybridBlock):
             self.out_proj = _dense(units, d_inner, False, "out_")
 
     def hybrid_forward(self, F, u, m):
-        with _scope("mxtpu.mixer.gmu"):
+        with device_scope("mxtpu.mixer.gmu"):
             return self.out_proj(F.silu(self.in_proj(u)) * m)
 
 
@@ -287,6 +283,8 @@ class _Layer(HybridBlock):
                  window, eps, mamba, **kwargs):
         super().__init__(**kwargs)
         self.kind = kind
+        #: the mixer's device scope; its norm and residual add carry it
+        self.scope = "mxtpu.mixer." + kind
         with self.name_scope():
             self.ln1 = _LayerNorm(units, eps, prefix="ln1_")
             if kind == "mamba":
@@ -301,11 +299,18 @@ class _Layer(HybridBlock):
             self.ln2 = _LayerNorm(units, eps, prefix="ln2_")
             self.mlp = _MLP(units, hidden, prefix="mlp_")
 
+    def pre(self, h, dtype):
+        """The mixer's input: the first norm, in the weights' dtype."""
+        with device_scope(self.scope):
+            return self.ln1(h).astype(dtype)
+
     def finish(self, h, mix):
         """Residual add of the mixer's output, then the MLP sublayer."""
-        h = h + mix.astype("float32")
-        return h + self.mlp(self.ln2(h).astype(mix.dtype)) \
-            .astype("float32")
+        with device_scope(self.scope):
+            h = h + mix.astype("float32")
+        with device_scope("mxtpu.mlp"):
+            return h + self.mlp(self.ln2(h).astype(mix.dtype)) \
+                .astype("float32")
 
 
 class SambaYModel(HybridBlock):
@@ -345,15 +350,19 @@ class SambaYModel(HybridBlock):
         """What enters the matrix products: the weights' dtype."""
         return self.embed.weight.dtype
 
+    def embed_tokens(self, tokens):
+        with device_scope("mxtpu.embed"):
+            return self.embed(tokens).astype("float32")
+
     def hybrid_forward(self, F, tokens):
         """Every layer over the whole sequence: (B, S) -> (B, S, h)."""
         from .. import ndarray as nd
-        h = self.embed(tokens).astype("float32")
+        h = self.embed_tokens(tokens)
         last = _rows(tokens.shape[0], tokens.shape[1] - 1, tokens.context)
         wdt = self.compute_dtype()
         mem = k = v = None
         for layer in self.layers:
-            u = layer.ln1(h).astype(wdt)
+            u = layer.pre(h, wdt)
             if layer.kind == "mamba":
                 mix, mem, _, _ = layer.mixer.seq(u, last)
             elif layer.kind == "gmu":
@@ -365,7 +374,8 @@ class SambaYModel(HybridBlock):
                 if layer.kind == "full":
                     k, v = k_l, v_l
             h = layer.finish(h, mix)
-        return self.final_norm(h)
+        with device_scope("mxtpu.head"):
+            return self.final_norm(h)
 
 
 class SambaYForCausalLM(HybridBlock):
@@ -381,7 +391,7 @@ class SambaYForCausalLM(HybridBlock):
     def _head(self, h):
         from .. import ndarray as nd
         w = self.model.embed.weight.data(h.context)
-        with _scope("mxtpu.head"):
+        with device_scope("mxtpu.head"):
             return nd._head_logits(
                 h.reshape((-1, self.model._units))
                 .astype(self.model.compute_dtype()), w)
@@ -443,11 +453,11 @@ class SambaYForCausalLM(HybridBlock):
         if last_pos is None:
             last_pos = _rows(b, s - 1, ctx)
         wdt = m.compute_dtype()
-        h = m.embed(tokens).astype("float32")
+        h = m.embed_tokens(tokens)
         bufs = iter(state)
         mem = k = v = None
         for layer in m.layers[:m.tail_from]:
-            u = layer.ln1(h).astype(wdt)
+            u = layer.pre(h, wdt)
             if layer.kind == "mamba":
                 mix, mem, tail, ssm = layer.mixer.seq(u, last_pos)
                 for buf, new in ((next(bufs), tail), (next(bufs), ssm)):
@@ -461,18 +471,22 @@ class SambaYForCausalLM(HybridBlock):
                     nd._cache_update(buf, new, offset=0, out=buf)
             h = layer.finish(h, mix)
         # the cross-decoder sees one position a row: its own last token
-        h = nd._take_positions(h, last_pos)
-        mem = nd._take_positions(mem, last_pos)
-        pos = nd.arange(s, ctx=ctx).reshape((1, s))
-        mask = (pos <= last_pos.reshape((-1, 1))).reshape((b, 1, 1, s))
+        with device_scope("mxtpu.mixer.cross"):
+            h = nd._take_positions(h, last_pos)
+            pos = nd.arange(s, ctx=ctx).reshape((1, s))
+            mask = (pos <= last_pos.reshape((-1, 1))) \
+                .reshape((b, 1, 1, s))
+        with device_scope("mxtpu.mixer.gmu"):
+            mem = nd._take_positions(mem, last_pos)
         for layer in m.layers[m.tail_from:]:
-            u = layer.ln1(h).astype(wdt)
+            u = layer.pre(h, wdt)
             if layer.kind == "gmu":
                 mix = layer.mixer(u, mem)
             else:
                 mix = layer.mixer.cross(u, k, v, mask=mask)
             h = layer.finish(h, mix)
-        return self._head(m.final_norm(h))
+        with device_scope("mxtpu.head"):
+            return self._head(m.final_norm(h))
 
     # -- decode -----------------------------------------------------------
     def decode_step(self, token, state, offset):
@@ -489,37 +503,43 @@ class SambaYForCausalLM(HybridBlock):
             offset = offset.reshape((1,)) + nd.zeros((b,), ctx=ctx)
         offv = offset.reshape((-1, 1))
         wdt = m.compute_dtype()
-        h = m.embed(token).astype("float32")
+        h = m.embed_tokens(token)
         masks = {}
 
-        def key_mask(n):
+        def key_mask(n, scope):
             # slot j of an n-slot buffer is live once written: j <= offset
             # (a rolling window buffer holds only positions inside the
             # window, so every written slot is visible)
             if n not in masks:
-                pos = nd.arange(n, ctx=ctx).reshape((1, n))
-                masks[n] = (pos <= offv).reshape((b, 1, 1, n))
+                with device_scope(scope):
+                    pos = nd.arange(n, ctx=ctx).reshape((1, n))
+                    masks[n] = (pos <= offv).reshape((b, 1, 1, n))
             return masks[n]
 
         bufs = iter(state)
         mem = k = v = None
         for layer in m.layers:
-            u = layer.ln1(h).astype(wdt)
+            u = layer.pre(h, wdt)
             if layer.kind == "mamba":
                 mix, mem = layer.mixer.step(u, next(bufs), next(bufs))
             elif layer.kind == "gmu":
                 mix = layer.mixer(u, mem)
             elif layer.kind == "cross":
-                mix = layer.mixer.cross(u, k, v, mask=key_mask(k.shape[1]))
+                mix = layer.mixer.cross(
+                    u, k, v, mask=key_mask(k.shape[1], layer.scope))
             else:
                 ck, cv = next(bufs), next(bufs)
                 n = ck.shape[1]
-                slot = offset % float(n) if layer.kind == "swa" else offset
-                mix = layer.mixer.step(u, ck, cv, slot, key_mask(n))
+                with device_scope(layer.scope):
+                    slot = offset % float(n) if layer.kind == "swa" \
+                        else offset
+                mix = layer.mixer.step(u, ck, cv, slot,
+                                       key_mask(n, layer.scope))
                 if layer.kind == "full":
                     k, v = ck, cv
             h = layer.finish(h, mix)
-        return self._head(m.final_norm(h))
+        with device_scope("mxtpu.head"):
+            return self._head(m.final_norm(h))
 
     def generate(self, tokens, max_new_tokens, cache_dtype="float32"):
         """Greedy generation through the cache: (B, S) -> (B, S + new)."""

@@ -64,6 +64,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental.custom_partitioning import custom_partitioning
 
+from ..profiler import device_scope
 from . import page_write
 from .registry import register
 
@@ -90,14 +91,14 @@ def _expanded(q, latent, w, nope_dim, scale):
     -> (B, S, H, dv): causal, position t sees [0, t]."""
     b, s, h, _ = q.shape
     rkv = w.shape[-1]
-    with jax.named_scope("mxtpu.mixer.mla.expand"):
+    with device_scope("mxtpu.mixer.mla.expand"):
         kv = jnp.einsum("bsr,hnr->bshn", latent[..., :rkv], w,
                         preferred_element_type=_F32).astype(q.dtype)
         k_r = jnp.broadcast_to(latent[:, :, None, rkv:],
                                (b, s, h, latent.shape[-1] - rkv))
         k = jnp.concatenate([kv[..., :nope_dim], k_r], axis=-1)
         v = kv[..., nope_dim:]
-    with jax.named_scope("mxtpu.mixer.mla.attend"):
+    with device_scope("mxtpu.mixer.mla.attend"):
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                             preferred_element_type=_F32) * _F32(scale)
         probs = _softmax_rows(scores, jnp.tril(jnp.ones((s, s), bool)))
@@ -298,7 +299,7 @@ def _absorbed(q, page, w, offset, nope_dim, scale):
     offset[b]] are written, w (H, dn + dv, rkv) -> (B, 1, H, dv)."""
     rkv = w.shape[-1]
     w_uk, w_uv = w[:, :nope_dim], w[:, nope_dim:]
-    with jax.named_scope("mxtpu.mixer.mla.absorb"):
+    with device_scope("mxtpu.mixer.mla.absorb"):
         # heads lead (XLA's CPU backend has no bfloat16 product for the
         # "bhn,hnr->bhr" order; the TPU's lays both out itself)
         qt = jnp.einsum("hbn,hnr->hbr",
@@ -306,12 +307,12 @@ def _absorbed(q, page, w, offset, nope_dim, scale):
                         preferred_element_type=_F32).astype(q.dtype)
         qq = jnp.concatenate([jnp.swapaxes(qt, 0, 1),
                               q[:, 0, :, nope_dim:]], axis=-1)
-    with jax.named_scope("mxtpu.mixer.mla.attend"):
+    with device_scope("mxtpu.mixer.mla.attend"):
         off = offset.astype(jnp.int32).reshape(-1)
         dense = functools.partial(_attend_dense, rkv=rkv, scale=scale)
         u = _walk_or_dense(page, _walk_rows(rkv, scale), dense, qq, page,
                            off)
-    with jax.named_scope("mxtpu.mixer.mla.absorb"):
+    with device_scope("mxtpu.mixer.mla.absorb"):
         out = jnp.einsum("bhr,hvr->bhv", u, w_uv,
                          preferred_element_type=_F32)
     return out[:, None]

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..profiler import device_scope
 from .registry import register
 
 
@@ -130,7 +131,7 @@ def routed_experts(x, scores_w, bias, w_gate, w_up, w_down, *rest, k=1,
         raise ValueError(
             f"RoutedExperts: held experts {first_held}.."
             f"{first_held + n - 1} are not among the router's {e}")
-    with jax.named_scope("mxtpu.moe.router"):
+    with device_scope("mxtpu.moe.router"):
         scores = jax.nn.sigmoid(jnp.einsum(
             "td,ed->te", x, scores_w, preferred_element_type=f32))
         _, selected = jax.lax.top_k(scores + bias.astype(f32), k)
@@ -151,7 +152,7 @@ def routed_experts(x, scores_w, bias, w_gate, w_up, w_down, *rest, k=1,
                              jnp.take(gates.reshape(-1), order), 0.0)
         back = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=order.dtype))
-    with jax.named_scope("mxtpu.moe.experts"):
+    with device_scope("mxtpu.moe.experts"):
         xs = jnp.take(x, order // k, axis=0)
         grouped = dict(group_sizes=sizes, preferred_element_type=f32)
         mid = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, **grouped)) \

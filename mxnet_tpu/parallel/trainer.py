@@ -30,7 +30,7 @@ import numpy as np
 from ..base import MXNetError
 from ..ndarray.ndarray import NDArray
 from ..ops.registry import get_op
-from ..profiler import span as _span
+from ..profiler import device_scope as _device_scope, span as _span
 from .mesh import current_mesh
 
 __all__ = ["DataParallelTrainer"]
@@ -141,17 +141,18 @@ def _apply_rule(rule, opt, tr_count, n_scalars, get_param, tstate_vals,
     """Apply the fused optimizer rule to every trainable param (shared
     by the two-phase update program and the fully-fused step)."""
     new_params, new_states = [], []
-    for j in range(tr_count):
-        scal = tuple(scalar_vals[j * n_scalars + k]
-                     for k in range(n_scalars))
-        st = tstate_vals[j]
-        res = rule.apply(opt, get_param(j), grads[j], st, *scal)
-        if isinstance(res, tuple) and isinstance(res[1], tuple):
-            w, new_st = res
-        else:
-            w, new_st = res[0], tuple(res[1:])
-        new_params.append(w)
-        new_states.append(new_st if new_st else st)
+    with _device_scope("mxtpu.step.optimizer"):
+        for j in range(tr_count):
+            scal = tuple(scalar_vals[j * n_scalars + k]
+                         for k in range(n_scalars))
+            st = tstate_vals[j]
+            res = rule.apply(opt, get_param(j), grads[j], st, *scal)
+            if isinstance(res, tuple) and isinstance(res[1], tuple):
+                w, new_st = res
+            else:
+                w, new_st = res[0], tuple(res[1:])
+            new_params.append(w)
+            new_states.append(new_st if new_st else st)
     return tuple(new_params), tuple(new_states)
 
 
@@ -632,7 +633,9 @@ class DataParallelTrainer:
                         shells = [NDArray(v, ctx=ctx)
                                   for v in input_vals]
                         out = block._call_unhybridized(*shells)
-                        l = loss_fn(out, NDArray(label_val, ctx=ctx))
+                        with _device_scope("mxtpu.loss"):
+                            l = loss_fn(out, NDArray(label_val, ctx=ctx))
+                            loss = jnp.mean(l._data)
                         mutated_idx.clear()
                         mutated_idx.extend(
                             i for i, (r, v0) in enumerate(
@@ -640,7 +643,7 @@ class DataParallelTrainer:
                             if r._version != v0)
                         aux = tuple(param_nds[i]._buf
                                     for i in mutated_idx)
-                        return jnp.mean(l._data), aux
+                        return loss, aux
 
                     tvals = tuple(param_vals[i] for i in tr_idx)
                     (loss, aux), grads = jax.value_and_grad(
@@ -785,6 +788,12 @@ class DataParallelTrainer:
                     aux, tuple(param_vals[i] for i in mutated_idx))
             return loss, new_params, new_states, aux, hvec
 
+        # the compiled module's name, ``jit_full_step``.  It was
+        # ``jit_full`` before the step's phases carried device scopes:
+        # jax's compilation cache leaves metadata out of its key, so
+        # under the old name a cache an older checkout filled would
+        # hand back an executable WITHOUT them
+        full.__name__ = "full_step"
         self._full_fn = full          # unjitted: reused by step_multi
         batch = NamedSharding(self.mesh, P(self.dp_axis))
         repl = NamedSharding(self.mesh, P())

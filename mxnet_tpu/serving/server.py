@@ -67,7 +67,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..base import MXNetError
-from ..profiler import span as _span
+from ..profiler import device_scope as _device_scope, span as _span
 from .kvcache import KVCachePool, check_spec
 from .scheduler import ACTIVE, BucketScheduler, Request
 
@@ -1129,6 +1129,11 @@ class Server:
                 fn = self._make_decode_multi(bucket, k)
             else:
                 raise MXNetError(f"unknown serving variant {kind!r}")
+            # the compiled module's name: a device trace then reads
+            # ``jit_decode_b48x256`` where two buckets' programs were
+            # both ``jit_decode_pure``
+            fn.__name__ = f"{kind}_b{bucket.slots}x{bucket.prompt_len}" \
+                + (f"k{k}" if k else "")
             self._pure_cache[key] = fn
         return fn
 
@@ -1193,15 +1198,16 @@ class Server:
                     NDArray(tok, ctx=ctx), shells,
                     NDArray(off, ctx=ctx))._data
                 new_caches = tuple(s._data for s in shells)
+            with _device_scope("mxtpu.serving.pick"):
                 counted = self._counted()
-            k0 = _dispatch_key(key_raw, counter)
-            keys = jax.vmap(lambda i: jax.random.fold_in(k0, i))(
-                jnp.arange(N))
-            nxt = self._pick(logits, temp, active, keys)
-            # the tokens twice: an output of their own that the host
-            # may read late, and the pool's successor
-            return (self._tokens_out(nxt, counted),) + new_caches \
-                + (nxt.reshape(N, 1),)
+                k0 = _dispatch_key(key_raw, counter)
+                keys = jax.vmap(lambda i: jax.random.fold_in(k0, i))(
+                    jnp.arange(N))
+                nxt = self._pick(logits, temp, active, keys)
+                # the tokens twice: an output of their own that the host
+                # may read late, and the pool's successor
+                return (self._tokens_out(nxt, counted),) + new_caches \
+                    + (nxt.reshape(N, 1),)
 
         return decode_pure
 
@@ -1221,7 +1227,8 @@ class Server:
             cache_vals = tuple(flat[P:P + NS - 1])
             tok = flat[P + NS - 1]
             off, active, temp, key_raw, counter = flat[P + NS:]
-            k0 = _dispatch_key(key_raw, counter)
+            with _device_scope("mxtpu.serving.pick"):
+                k0 = _dispatch_key(key_raw, counter)
 
             def body(carry, step_i):
                 tok_c, off_c, caches = carry
@@ -1231,27 +1238,30 @@ class Server:
                         NDArray(tok_c, ctx=ctx), shells,
                         NDArray(off_c, ctx=ctx))._data
                     new_caches = tuple(s._data for s in shells)
+                with _device_scope("mxtpu.serving.pick"):
                     counted = self._counted()
-                k_step = jax.random.fold_in(k0, step_i)
-                keys = jax.vmap(
-                    lambda i: jax.random.fold_in(k_step, i))(
-                    jnp.arange(N))
-                nxt = self._pick(logits, temp, active, keys)
-                # inactive slots hold position (offset AND token), so
-                # the in-graph carry matches the host's bookkeeping
-                return (nxt.reshape(N, 1), off_c + active,
-                        new_caches), (nxt, counted)
+                    k_step = jax.random.fold_in(k0, step_i)
+                    keys = jax.vmap(
+                        lambda i: jax.random.fold_in(k_step, i))(
+                        jnp.arange(N))
+                    nxt = self._pick(logits, temp, active, keys)
+                    # inactive slots hold position (offset AND token),
+                    # so the in-graph carry matches the host's
+                    # bookkeeping
+                    return (nxt.reshape(N, 1), off_c + active,
+                            new_caches), (nxt, counted)
 
             (tok_f, _, caches_f), (toks, counted) = lax.scan(
                 body, (tok, off, cache_vals),
                 jnp.arange(k_steps))
-            # toks: (K, N); the K steps' counts add up, their rows
-            # follow one another
-            n = len(self._stat_rows)
-            counted = jnp.concatenate([counted[:, :n].sum(axis=0),
-                                       counted[:, n:].reshape(-1)])
-            return (self._tokens_out(toks, counted),) \
-                + caches_f + (tok_f,)
+            with _device_scope("mxtpu.serving.pick"):
+                # toks: (K, N); the K steps' counts add up, their rows
+                # follow one another
+                n = len(self._stat_rows)
+                counted = jnp.concatenate([counted[:, :n].sum(axis=0),
+                                           counted[:, n:].reshape(-1)])
+                return (self._tokens_out(toks, counted),) \
+                    + caches_f + (tok_f,)
 
         return decode_multi_pure
 
@@ -1276,7 +1286,6 @@ class Server:
                 logits = lm.prefill(
                     NDArray(prompt, ctx=ctx), tmp,
                     last_pos=NDArray(last_pos, ctx=ctx))._data
-                counted = self._counted()
             slot_i = jnp.asarray(slot, jnp.int32)
             zero = jnp.int32(0)
             new_caches = [
@@ -1284,15 +1293,18 @@ class Server:
                     c, t._data.astype(c.dtype),
                     (slot_i,) + (zero,) * (c.ndim - 1))
                 for c, t in zip(flat[P:P + NS - 1], tmp)]
-            k0 = _dispatch_key(key_raw, counter)
-            keys = jax.vmap(lambda i: jax.random.fold_in(k0, i))(
-                slot_i.reshape(1))
-            nxt = self._pick(logits, temp, jnp.ones((1,)), keys)
-            # the first token goes where the slot's next decode reads it
-            toks = lax.dynamic_update_slice(
-                flat[P + NS - 1], nxt.reshape(1, 1), (slot_i, zero))
-            return (self._tokens_out(nxt, counted),) \
-                + tuple(new_caches) + (toks,)
+            with _device_scope("mxtpu.serving.pick"):
+                counted = self._counted()
+                k0 = _dispatch_key(key_raw, counter)
+                keys = jax.vmap(lambda i: jax.random.fold_in(k0, i))(
+                    slot_i.reshape(1))
+                nxt = self._pick(logits, temp, jnp.ones((1,)), keys)
+                # the first token goes where the slot's next decode
+                # reads it
+                toks = lax.dynamic_update_slice(
+                    flat[P + NS - 1], nxt.reshape(1, 1), (slot_i, zero))
+                return (self._tokens_out(nxt, counted),) \
+                    + tuple(new_caches) + (toks,)
 
         return prefill_pure
 

@@ -51,6 +51,8 @@ import math
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from ..profiler import device_scope as _device_scope
+
 __all__ = ["enabled", "every", "action", "trace_signature", "build_spec",
            "HealthSpec", "compute", "gate", "due_flags", "Sentinel",
            "get_sentinel",
@@ -302,16 +304,17 @@ def compute(spec: HealthSpec, loss_val, old_tvals, grads, new_tvals,
     nonfinite count), so ``spec.skip`` computes unconditionally; a
     ``None`` due does too (callers without a sampling schedule).
     """
-    if due is None or spec.skip:
-        return _compute_full(spec, loss_val, old_tvals, grads,
-                             new_tvals)
-    import jax.numpy as jnp
-    from jax import lax
-    return lax.cond(
-        due > 0,
-        lambda: _compute_full(spec, loss_val, old_tvals, grads,
-                              new_tvals),
-        lambda: jnp.zeros((spec.base_n,), jnp.float32))
+    with _device_scope("mxtpu.step.health"):
+        if due is None or spec.skip:
+            return _compute_full(spec, loss_val, old_tvals, grads,
+                                 new_tvals)
+        import jax.numpy as jnp
+        from jax import lax
+        return lax.cond(
+            due > 0,
+            lambda: _compute_full(spec, loss_val, old_tvals, grads,
+                                  new_tvals),
+            lambda: jnp.zeros((spec.base_n,), jnp.float32))
 
 
 def compute_sharded(spec: HealthSpec, loss_val, old_tvals, g_sq,
@@ -325,16 +328,17 @@ def compute_sharded(spec: HealthSpec, loss_val, old_tvals, g_sq,
     nonfinite flags, attribution) matches the replicated computation
     while the gradient wire stays reduce-scatter.  Same ``due``/skip
     semantics as :func:`compute`."""
-    if due is None or spec.skip:
-        return _compute_from_sq(spec, loss_val, old_tvals, g_sq,
-                                new_tvals)
-    import jax.numpy as jnp
-    from jax import lax
-    return lax.cond(
-        due > 0,
-        lambda: _compute_from_sq(spec, loss_val, old_tvals, g_sq,
-                                 new_tvals),
-        lambda: jnp.zeros((spec.base_n,), jnp.float32))
+    with _device_scope("mxtpu.step.health"):
+        if due is None or spec.skip:
+            return _compute_from_sq(spec, loss_val, old_tvals, g_sq,
+                                    new_tvals)
+        import jax.numpy as jnp
+        from jax import lax
+        return lax.cond(
+            due > 0,
+            lambda: _compute_from_sq(spec, loss_val, old_tvals, g_sq,
+                                     new_tvals),
+            lambda: jnp.zeros((spec.base_n,), jnp.float32))
 
 
 def due_flags(base: int, k: int):
@@ -367,11 +371,12 @@ def gate_update(health_vec, new_params, old_params, new_states,
     per-param optimizer-state tuples, and forward-mutated aux — so
     both SPMD step bodies carry the invariant from ONE place (the
     compressed variant adds residual gating on top)."""
-    new_params = gate(health_vec, new_params, old_params)
-    new_states = tuple(
-        tuple(gate(health_vec, sn, so))
-        for sn, so in zip(new_states, old_states))
-    aux = gate(health_vec, aux, old_aux)
+    with _device_scope("mxtpu.step.health"):
+        new_params = gate(health_vec, new_params, old_params)
+        new_states = tuple(
+            tuple(gate(health_vec, sn, so))
+            for sn, so in zip(new_states, old_states))
+        aux = gate(health_vec, aux, old_aux)
     return new_params, new_states, aux
 
 

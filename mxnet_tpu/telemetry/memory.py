@@ -46,7 +46,8 @@ from .metrics import gauge
 from .recorder import record_event
 
 __all__ = [
-    "harvest_compiled", "programs", "collective_stats", "census",
+    "harvest_compiled", "programs", "executables", "collective_stats",
+    "census",
     "param_census", "note_param_tree", "param_trees",
     "opt_state_census", "note_opt_state", "opt_state_trees", "report",
     "dump_report", "device_capacity", "reset",
@@ -352,6 +353,15 @@ def harvest_compiled(name: str, compiled, args=(), donate=(),
     reused so a warm-start reload never re-renders HLO text (which can
     be tens of MB for a large fused step) on the path the persistent
     cache exists to make fast.
+
+    The record HOLDS ``compiled`` (key ``executable``; what
+    ``profiler.device_scopes()`` asks for its text, later and only if a
+    device trace is read).  That ties an executable's life to this
+    table and no longer to its owner's: the newest executable of each
+    program name, with the device memory of its code, stays until a
+    newer one of that name is harvested or :func:`reset` is called,
+    also after the engine has dropped it (``engine.drop_cached``) or
+    its trainer is gone.  Nothing is held with telemetry disabled.
     """
     if not _switch.enabled:
         return None
@@ -409,6 +419,17 @@ def harvest_compiled(name: str, compiled, args=(), donate=(),
                 (coll or {}).get("total_wire_bytes", 0),
             "in_avals": in_avals, "donated_idx": sorted(donated),
             "out_avals": out_sig,
+            # the executable itself (an ``export`` reload is a plain
+            # jit function and has no text to ask for): what
+            # ``profiler.device_scopes()`` asks for its HLO text when a
+            # device trace is read, and never before.  A plain
+            # reference, one a program name like the record: a weak one
+            # was dead by the time the benchmark read its trace (a
+            # freshly compiled train step is owned by its trainer, which
+            # the train driver drops before the readers run; the chip
+            # showed it, PERF.md section 6, PR 37)
+            "executable": compiled if hasattr(compiled, "as_text")
+            else None,
         }
         with _lock:
             prev = _programs.get(name)
@@ -441,6 +462,19 @@ def programs() -> Dict[str, dict]:
     """Snapshot of every harvested program record (name -> record)."""
     with _lock:
         return {k: dict(v) for k, v in _programs.items()}
+
+
+def executables() -> List[Tuple[str, Any]]:
+    """``(program name, compiled executable)`` of every harvested
+    program, in harvest order: the newest executable of each name, kept
+    with its record until :func:`reset`.  Nothing is rendered here: the
+    caller decides what to ask an executable
+    (``profiler.device_scopes`` asks for ``as_text()``, once, when a
+    device trace is read)."""
+    with _lock:
+        recs = sorted(_programs.values(), key=lambda r: r["seq"])
+    return [(r["name"], r["executable"]) for r in recs
+            if r.get("executable") is not None]
 
 
 # -- live-buffer + param census ----------------------------------------------
@@ -657,10 +691,12 @@ def opt_state_trees() -> Dict[str, dict]:
 # -- reporting ---------------------------------------------------------------
 
 def _compact(rec: dict) -> dict:
-    """A program record without its aval lists (the report/cache_info
-    face; the full record stays in :func:`programs`)."""
+    """A program record without its aval lists and its executable (the
+    report/cache_info face, which is written out as JSON; the full
+    record stays in :func:`programs`)."""
     return {k: v for k, v in rec.items()
-            if k not in ("in_avals", "out_avals", "donated_idx")}
+            if k not in ("in_avals", "out_avals", "donated_idx",
+                         "executable")}
 
 
 def _latest_per_base(recs) -> List[dict]:

@@ -480,3 +480,164 @@ def test_self_check_includes_memory_pass():
     findings, ok = analysis.self_check()
     assert ok
     assert not any(f.rule in ("MXL308", "MXL309") for f in findings)
+
+
+# ---------------------------------------------------------------------------
+# the scope map: executables kept weakly at the harvest seam, HLO text
+# rendered by ``profiler.device_scopes()`` and by nothing before it
+# ---------------------------------------------------------------------------
+
+def _toy_step():
+    """A step with the fused step's own pieces: the optimizer rule and
+    the health plane's reductions, each under its scope."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import optimizer as opt_mod
+    from mxnet_tpu.parallel import trainer as trainer_mod
+    from mxnet_tpu.telemetry import health
+
+    opt = opt_mod.create("sgd", learning_rate=0.1, momentum=0.9)
+    rule = trainer_mod._FUSED_RULES["SGD"]
+    spec = health.build_spec("toy_", ["toy_w"], [0])
+
+    def toy_step(w, mom, x, scal):
+        def loss_of(w):
+            with mx.profiler.device_scope("mxtpu.mlp"):
+                return jnp.sum(jnp.tanh(x @ w) ** 2)
+
+        loss, g = jax.value_and_grad(loss_of)(w)
+        (new_w,), (new_mom,) = trainer_mod._apply_rule(
+            rule, opt, 1, 2, lambda j: w, ((mom,),), (g,), scal)
+        hvec = health.compute(spec, loss, (w,), (g,), (new_w,))
+        return new_w, new_mom[0], hvec
+
+    rng = np.random.RandomState(0)
+    args = (rng.rand(8, 8).astype("f4"), np.zeros((8, 8), "f4"),
+            rng.rand(4, 8).astype("f4"),
+            np.asarray([0.1, 0.0], "f4"))
+    return toy_step, args
+
+
+def _scopes_found(module):
+    table = mx.profiler.device_scopes().get(module, {})
+    return {scope for scope, _bwd, _inh in table.values()}, table
+
+
+def test_device_scopes_maps_the_step_pieces_fresh_and_reloaded(
+        tmp_path, monkeypatch):
+    monkeypatch.setenv("MXTPU_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+    fn, args = _toy_step()
+    engine.invoke_compiled("toy_scoped_step", fn, {}, *args,
+                           persist_name="toy_scoped_step")
+    assert any(n == "toy_scoped_step" for n, _c in memobs.executables())
+    found, table = _scopes_found("jit_toy_step")
+    assert {"mxtpu.mlp", "mxtpu.step.optimizer",
+            "mxtpu.step.health"} <= found
+    # the forward / backward split of one scope
+    assert {bwd for scope, bwd, _inh in table.values()
+            if scope == "mxtpu.mlp"} == {False, True}
+    # a second call renders nothing again: cached on the executable
+    def boom(_self):
+        raise AssertionError("HLO text rendered twice")
+    import jax
+    with monkeypatch.context() as m:
+        m.setattr(jax.stages.Compiled, "as_text", boom)
+        assert _scopes_found("jit_toy_step")[0] == found
+    # a fresh memory tier: the executable comes back from the persist
+    # tier and still says where its ops came from
+    h0 = engine.persist.counters()["hits"]
+    engine.clear_cache()
+    telemetry.reset()
+    assert memobs.executables() == []
+    engine.invoke_compiled("toy_scoped_step", fn, {}, *args,
+                           persist_name="toy_scoped_step")
+    assert engine.persist.counters()["hits"] == h0 + 1
+    assert _scopes_found("jit_toy_step")[0] == found
+    engine.drop_cached("toy_scoped_step", persistent=True)
+
+
+def test_no_hlo_text_is_rendered_until_device_scopes_is_called(
+        tmp_path, monkeypatch):
+    """``setup_s`` is an end-to-end metric: an untraced run must not
+    render a program's text, neither when it compiles nor when it
+    reloads (on the one-chip machine; a process with several devices
+    renders it once, at the fresh compile, for the collectives)."""
+    import jax
+    monkeypatch.setenv("MXTPU_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+    monkeypatch.setattr(memobs, "_single_device", lambda: True)
+
+    def boom(_self):
+        raise AssertionError("an untraced run rendered HLO text")
+    monkeypatch.setattr(jax.stages.Compiled, "as_text", boom)
+    fn, args = _toy_step()
+    engine.invoke_compiled("toy_untraced_step", fn, {}, *args,
+                           persist_name="toy_untraced_step")
+    engine.clear_cache()
+    engine.invoke_compiled("toy_untraced_step", fn, {}, *args,
+                           persist_name="toy_untraced_step")
+    assert telemetry.events("persist_error") == []
+    assert [n for n, _c in memobs.executables()
+            if n == "toy_untraced_step"]
+    engine.drop_cached("toy_untraced_step", persistent=True)
+
+
+def test_the_map_outlives_the_owner_of_a_fresh_executable():
+    """The benchmark's train driver drops its trainer, and with it the
+    freshly compiled step, before the trace is read (the chip showed a
+    weak reference dead by then): the record keeps the newest
+    executable of a name until ``telemetry.reset()``."""
+    import gc
+    fn, args = _toy_step()
+    engine.invoke_compiled("toy_owned_step", fn, {}, *args,
+                           persist_name="toy_owned_step")
+    engine.drop_cached("toy_owned_step")      # the engine lets go of it
+    gc.collect()
+    assert "mxtpu.step.optimizer" in _scopes_found("jit_toy_step")[0]
+    # not in the JSON face of the record
+    assert "executable" not in \
+        engine.cache_info()["memory"]["per_program"]["toy_owned_step"]
+    telemetry.reset()
+    assert mx.profiler.device_scopes() == {}
+
+
+def test_two_executables_of_one_module_name_are_never_merged(tmp_path):
+    """``fusion.N`` of two compiles of one function name mean different
+    things: the module's map is the NEWEST executable's alone, and the
+    reader says that the name is shared."""
+    import jax
+    import jax.numpy as jnp
+
+    def twin(scope):
+        def toy_twin(w):
+            with mx.profiler.device_scope(scope):
+                return jnp.tanh(w) * 2.0
+        return toy_twin
+
+    for name, scope in (("toy_twin_a", "mxtpu.mlp"),
+                        ("toy_twin_b", "mxtpu.head")):
+        engine.invoke_compiled(name, twin(scope), {},
+                               np.ones((4, 4), "f4"), persist_name=name)
+    try:
+        found, _table = _scopes_found("jit_toy_twin")
+        assert found == {"mxtpu.head"}
+        jax.profiler.start_trace(str(tmp_path))
+        jnp.ones((2,)).block_until_ready()
+        jax.profiler.stop_trace()
+        out = json.loads(mx.profiler.device_dumps(str(tmp_path)))
+        assert out["shadowed"] == ["jit_toy_twin"]
+    finally:
+        for name in ("toy_twin_a", "toy_twin_b"):
+            engine.drop_cached(name, persistent=True)
+
+
+def test_disabled_telemetry_keeps_no_executable():
+    telemetry.disable()
+    try:
+        engine.invoke_compiled("toy_dark_step", lambda w: w * 2.0, {},
+                               np.ones((4, 4), "f4"),
+                               persist_name="toy_dark_step")
+        assert memobs.executables() == []
+        assert mx.profiler.device_scopes() == {}
+    finally:
+        telemetry.enable()
+        engine.drop_cached("toy_dark_step")

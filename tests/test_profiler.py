@@ -12,7 +12,11 @@ Until PR 4 the profiler was only incidentally exercised through
   ``DataParallelTrainer.step`` nested under their root;
 * ``dump()`` chrome-trace JSON round-trip;
 * ``dumps()`` aggregate table AND the (previously silently ignored)
-  ``format_="json"`` mode; unknown formats raise.
+  ``format_="json"`` mode; unknown formats raise;
+* the device half: ``scope_of`` / ``scopes_of_text`` read the
+  ``mxtpu.*`` scope out of an ``op_name``, ``reduce_device`` (the pure
+  part of ``device_dumps``) sums device time by program and scope on a
+  hand-built event list.
 """
 import glob
 import json
@@ -166,20 +170,23 @@ def _inside(child, parent):
 
 @pytest.mark.time_limit(120)
 def test_trainer_step_spans_nest_under_their_root():
-    """Two steps give two ``mxtpu.trainer.step`` roots; each root's
+    """Six steps give six ``mxtpu.trainer.step`` roots; each root's
     phases lie inside it on its thread, carry its ``step`` id, and
-    leave under a fifth of it to no span."""
+    leave under a fifth of it to no span (in the best of the six: a warm
+    toy step is under a millisecond, and on a loaded host one pause of
+    the process between two phases is a fifth of that)."""
     dpt, data, label = _tiny_trainer()
     profiler.set_state("run")
-    for _ in range(2):
+    for _ in range(6):
         dpt.step(data, label).wait_to_read()
     profiler.set_state("stop")
     with profiler._lock:
         spans = [e for e in profiler._events
                  if e["name"].startswith("mxtpu.trainer.")]
     roots = [e for e in spans if e["name"] == "mxtpu.trainer.step"]
-    assert len(roots) == 2 and all(e["cat"] == "spmd_step" for e in roots)
-    assert roots[1]["args"]["step"] == roots[0]["args"]["step"] + 1
+    assert len(roots) == 6 and all(e["cat"] == "spmd_step" for e in roots)
+    assert [e["args"]["step"] for e in roots] == \
+        list(range(roots[0]["args"]["step"], roots[0]["args"]["step"] + 6))
     top = {"prologue", "place_batch", "rng_key", "gather_args",
            "dispatch", "write_back"}
     covered = []
@@ -306,3 +313,188 @@ def test_spans_land_in_a_jax_profiler_trace(tmp_path):
     assert root["step_num"] == root["step"] == dpt._span_step
     assert seen["mxtpu.trainer.execute"][0]["step"] == root["step"]
     assert {s["op"] for s in seen["mxtpu.engine.execute"]} >= {"dot"}
+
+
+# -- device time by scope (the pure parts) ------------------------------------
+
+@pytest.mark.parametrize("op_name, want", [
+    ("jit(f)/mxtpu.mlp/jit(<unknown>)/dot_general", ("mxtpu.mlp", False)),
+    # the backward twin that value_and_grad makes
+    ("jit(f)/transpose(jvp(mxtpu.mlp))/dot_general", ("mxtpu.mlp", True)),
+    ("jit(f)/jvp(mxtpu.mlp)/dot_general", ("mxtpu.mlp", False)),
+    # the innermost scope wins
+    ("jit(f)/mxtpu.mixer.mla/mxtpu.mixer.mla.attend/exp",
+     ("mxtpu.mixer.mla.attend", False)),
+    # a fusion's op_name may join several paths: the first one counts
+    ("jit(f)/mxtpu.head/add;jit(f)/transpose(jvp(mxtpu.mlp))/mul",
+     ("mxtpu.head", False)),
+    ("jit(f)/transpose(jvp(mxtpu.loss))/mul;jit(f)/mxtpu.mlp/add",
+     ("mxtpu.loss", True)),
+    ("jit(f)/broadcast_in_dim", None),
+])
+def test_scope_of_an_op_name(op_name, want):
+    assert profiler.scope_of(op_name) == want
+
+
+def test_scopes_of_text_reads_module_and_instructions():
+    text = """HloModule jit_decode_b48x256, is_scheduled=true, entry={...}
+
+%fused_computation (p: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0)
+  ROOT %add.7 = f32[4]{0} add(%p, %p), metadata={op_name="jit(decode_b48x256)/mxtpu.mlp/add"}
+}
+
+ENTRY %main (x: f32[4], w: f32[4]) -> f32[4] {
+  %x = f32[4]{0} parameter(0), metadata={op_name="x"}
+  %w = f32[4]{0} parameter(1), metadata={op_name="w"}
+  %copy-start.2 = (f32[4]{0}, f32[4]{0}, u32[]) copy-start(%w)
+  %copy.1 = f32[4]{0} copy(%x)
+  %copy-done.2 = f32[4]{0} copy-done(%copy-start.2)
+  %plain.4 = f32[4]{0} add(%x, %x), metadata={op_name="jit(decode_b48x256)/add"}
+  %fusion.3 = f32[4]{0} fusion(%x, %copy-done.2), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(decode_b48x256)/mxtpu.mlp/add;jit(decode_b48x256)/mxtpu.head/mul"}
+  ROOT %while.56 = f32[4]{0} while(%fusion.3), condition=%c, body=%b, metadata={op_name="jit(decode_b48x256)/mxtpu.mixer.mamba/while"}
+}
+"""
+    module, table = profiler.scopes_of_text(text)
+    assert module == "jit_decode_b48x256"
+    mlp, inherited = ("mxtpu.mlp", False, False), ("mxtpu.mlp", False, True)
+    assert table == {
+        "add.7": mlp, "p": inherited, "fusion.3": mlp,
+        "while.56": ("mxtpu.mixer.mamba", False, False),
+        # what the compiler inserted FOR the fusion (no op_name of its
+        # own) counts with it, through the start / done chain, and says
+        # that the name is its consumer's
+        "copy-done.2": inherited, "copy-start.2": inherited}
+    # inserted and consumed by nothing scoped; named, but under no scope
+    assert "copy.1" not in table and "plain.4" not in table
+
+
+# two programs that both hold ``fusion.1``, under different scopes; an
+# enclosing ``while`` whose body ops are events of their own; a module
+# the process holds no executable of
+_OPS = [("fusion.1", 0.0, 1.0), ("while.2", 1.0, 4.0),
+        ("fusion.3", 1.5, 2.0), ("fusion.3", 2.5, 3.0),
+        ("copy.9", 4.0, 4.5),
+        ("fusion.1", 10.0, 11.0), ("fusion.4", 11.0, 11.5),
+        ("fusion.1", 20.0, 21.0), ("while.2", 21.0, 24.0),
+        ("fusion.3", 21.5, 22.0), ("fusion.3", 22.5, 23.0),
+        ("copy.9", 24.0, 24.5),
+        ("fusion.1", 30.0, 32.0)]
+_RUNS = [("jit_full_step(123)", 0.0, 5.0), ("jit_decode_b2x8(9)", 10.0, 12.0),
+         ("jit_full_step(123)", 20.0, 25.0), ("jit_other(7)", 30.0, 32.0)]
+_MAP = {"jit_full_step": {"fusion.1": ("mxtpu.mlp", False, False),
+                          "while.2": ("mxtpu.mixer.mamba", False, False),
+                          "fusion.3": ("mxtpu.mixer.mamba", True, False)},
+        "jit_decode_b2x8": {"fusion.1": ("mxtpu.head", False, False)}}
+
+
+@pytest.fixture(scope="module")
+def reduced():
+    return profiler.reduce_device(_OPS, _RUNS, _MAP)
+
+
+def test_reduce_device_joins_an_op_to_the_map_of_its_own_program(reduced):
+    progs = reduced["programs"]
+    assert set(progs) == {"jit_full_step", "jit_decode_b2x8", "jit_other"}
+    step, decode = progs["jit_full_step"], progs["jit_decode_b2x8"]
+    assert (step["runs"], decode["runs"]) == (2, 1)
+    assert step["ms_per_run"] == pytest.approx(5000.0)
+    # ``fusion.1`` is the MLP in one program and the head in the other
+    assert step["scopes"]["mxtpu.mlp"]["ms_per_run"] == pytest.approx(1000.0)
+    assert decode["scopes"]["mxtpu.head"]["ms_per_run"] == \
+        pytest.approx(1000.0)
+    assert "mxtpu.mlp" not in decode["scopes"]
+    # an op the map does not name
+    assert step["scopes"][profiler.NO_SCOPE]["ms_per_run"] == \
+        pytest.approx(500.0)
+    assert decode["scopes"][profiler.NO_SCOPE]["top"] == \
+        [["fusion.4", pytest.approx(500.0)]]
+
+
+def test_reduce_device_counts_an_enclosing_op_by_its_self_time(reduced):
+    mamba = reduced["programs"]["jit_full_step"]["scopes"][
+        "mxtpu.mixer.mamba"]
+    # the while is 3 s of which its two body ops take 1: self 2 (forward,
+    # by its own op_name), the body ops backward
+    assert mamba["forward_ms"] == pytest.approx(2000.0)
+    assert mamba["backward_ms"] == pytest.approx(1000.0)
+    assert mamba["ms_per_run"] == pytest.approx(3000.0)
+    assert mamba["ops"] == 2
+    assert mamba["top"][0] == ["while.2", pytest.approx(2000.0)]
+    # what the loop is, bodies included: the trace's flat ``while.2``
+    assert mamba["enclosing"] == [["while.2", pytest.approx(3000.0)]]
+    assert reduced["programs"]["jit_full_step"]["scopes"][
+        "mxtpu.mlp"]["enclosing"] == []
+
+
+def test_reduce_device_names_a_program_without_a_map(reduced):
+    other = reduced["programs"]["jit_other"]
+    assert list(other["scopes"]) == [profiler.UNKNOWN_PROGRAM]
+    assert other["scopes"][profiler.UNKNOWN_PROGRAM]["ms_per_run"] == \
+        pytest.approx(2000.0)
+
+
+def test_reduce_device_scopes_sum_to_the_busy_time(reduced):
+    for prog in reduced["programs"].values():
+        assert sum(s["ms_per_run"] for s in prog["scopes"].values()) == \
+            pytest.approx(prog["busy_ms_per_run"])
+        for s in prog["scopes"].values():
+            assert s["forward_ms"] + s["backward_ms"] == \
+                pytest.approx(s["ms_per_run"])
+    # nested events count once: 4.5 + 1.5 + 4.5 + 2 s of ops
+    assert reduced["busy_ms"] == pytest.approx(12500.0)
+    assert sum(p["busy_share"] for p in reduced["programs"].values()) == \
+        pytest.approx(1.0)
+    assert reduced["programs"]["jit_full_step"]["busy_share"] == \
+        pytest.approx(9.0 / 12.5)
+
+
+def test_reduce_device_keeps_inherited_time_apart_within_a_scope(reduced):
+    """A ``copy-done`` the compiler inserted carries no name: the map
+    gives it its consumer's, and the table says how much of a scope's
+    time is of that kind, so the named part can be read alone."""
+    red = profiler.reduce_device(
+        [("fusion.1", 0.0, 1.0), ("copy-done.5", 1.0, 1.5),
+         ("copy-done.6", 1.5, 1.75)],
+        [("jit_full_step(123)", 0.0, 2.0)],
+        {"jit_full_step": {"fusion.1": ("mxtpu.mlp", False, False),
+                           "copy-done.5": ("mxtpu.mlp", True, True)}})
+    mlp = red["programs"]["jit_full_step"]["scopes"]["mxtpu.mlp"]
+    assert mlp["ms_per_run"] == pytest.approx(1500.0)
+    assert mlp["inherited_ms"] == pytest.approx(500.0)
+    assert (mlp["forward_ms"], mlp["backward_ms"]) == \
+        (pytest.approx(1000.0), pytest.approx(500.0))
+    # consumed by nothing scoped: under no scope, inherited from nobody
+    bare = red["programs"]["jit_full_step"]["scopes"][profiler.NO_SCOPE]
+    assert (bare["ms_per_run"], bare["inherited_ms"]) == \
+        (pytest.approx(250.0), 0.0)
+    assert all(s["inherited_ms"] == 0.0 for p in reduced["programs"].values()
+               for s in p["scopes"].values())
+
+
+def test_reduce_device_keeps_an_op_outside_every_run_apart():
+    red = profiler.reduce_device([("fusion.1", 6.0, 7.0)], _RUNS, _MAP)
+    assert list(red["programs"]) == [profiler.OUTSIDE_RUNS]
+
+
+def test_device_dumps_wants_a_trace_and_a_known_format(tmp_path):
+    with pytest.raises(MXNetError, match="unknown dumps format"):
+        profiler.device_dumps(str(tmp_path), format_="yaml")
+    with pytest.raises(MXNetError, match="no .xplane.pb"):
+        profiler.device_dumps(str(tmp_path))
+
+
+def test_device_dumps_reads_a_trace_without_a_device_plane(tmp_path):
+    """A CPU trace has no ``/device:`` plane with ``XLA Ops``: the table
+    is empty, not an error (device numbers come from a chip)."""
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    _run_some_ops()
+    jax.profiler.stop_trace()
+    out = json.loads(profiler.device_dumps(str(tmp_path)))
+    assert out["path"].endswith(".xplane.pb")
+    assert out["programs"] == {} and out["busy_ms"] == 0
+    assert set(out["seconds"]) == {"map", "read", "reduce"}
+    assert out["shadowed"] == []
+    table = profiler.device_dumps(str(tmp_path), format_="table")
+    assert table.startswith("Program / scope")

@@ -1505,3 +1505,29 @@ def test_preemption_drain_reads_what_is_owed(lm_bucket, tmp_path):
     row, = json.load(open(out["manifest"]))["requests"]
     assert len(row["generated"]) == 2       # first token + the owed one
     assert ra.state == "queued"
+
+
+def test_two_buckets_compile_modules_named_by_bucket_and_kind(net):
+    """A served program says which bucket it is: a device trace reads
+    ``jit_decode_b2x8`` and ``jit_decode_b1x16`` where both buckets'
+    closures compiled to ``jit_decode_pure``; the reader of device time
+    by scope joins an op to the map of ITS program by that name."""
+    srv = Server(net, buckets=[(2, 8), (1, 16)], max_new_tokens=3)
+    assert [srv._pure_for(b, kind, k).__name__
+            for b in srv.sched.buckets
+            for kind, k in (("prefill", 0), ("decode", 0), ("decode", 4))] \
+        == ["prefill_b2x8", "decode_b2x8", "decode_b2x8k4",
+            "prefill_b1x16", "decode_b1x16", "decode_b1x16k4"]
+    short, long_ = srv.submit(_prompt(1, 5)), srv.submit(_prompt(2, 12))
+    srv.run()
+    assert len(short.generated) == len(long_.generated) == 3
+    modules = mx.profiler.device_scopes()
+    assert {"jit_prefill_b2x8", "jit_decode_b2x8", "jit_prefill_b1x16",
+            "jit_decode_b1x16"} <= set(modules)
+    for name in ("jit_prefill_b2x8", "jit_decode_b1x16"):
+        assert {"mxtpu.embed", "mxtpu.mixer.full", "mxtpu.mlp",
+                "mxtpu.head", "mxtpu.serving.pick"} <= \
+            {scope for scope, _bwd, _inh in modules[name].values()}, name
+    # the engine's names and persist keys did not move
+    assert set(srv._variants) == {"_b2x8_prefill", "_b2x8_decode",
+                                  "_b1x16_prefill", "_b1x16_decode"}

@@ -153,7 +153,7 @@ def test_page_write_is_one_in_place_op_for_v5e(one_chip, name,
     page = sds(shape, jnp.bfloat16)
     new = sds((shape[0], 1 if per_row else 128) + shape[2:],
               jnp.bfloat16)
-    # offsets as ``_decode_step_slots`` hands them: float32
+    # offsets as ``decode_step`` hands them: float32
     off = sds((shape[0],) if per_row else (), jnp.float32)
     compiled = jax.jit(_cache_update, donate_argnums=0).lower(
         page, new, off).compile()
@@ -224,3 +224,128 @@ def test_absorbed_latent_decode_expands_no_key_or_value_for_v5e(
     else:
         assert _mosaic_calls(compiled) == 0
         assert temp < 2 * (4 + 2) * scores
+
+
+# -- whole programs: every product carries a device scope ---------------------
+
+class _Captured(Exception):
+    """Raised by the capturing seam: nothing is compiled for or run on
+    the CPU, the program is compiled for the described chip instead."""
+
+
+def _unscoped_products(compiled):
+    """Instruction names of the ``dot`` / ``convolution`` (what the TPU
+    compiler makes of a dot) / Mosaic ``custom-call`` instructions of a
+    compiled program, every computation of it, whose ``op_name`` names no
+    ``mxtpu.*`` scope; and how many such instructions there are."""
+    import re
+    from mxnet_tpu import profiler
+    product = re.compile(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s.*?\s"
+        r"(dot|convolution|custom-call)\(")
+    text = compiled.as_text()
+    _module, table = profiler.scopes_of_text(text)
+    seen, bare = 0, []
+    for line in text.splitlines():
+        hit = product.match(line)
+        if hit is None or (hit.group(2) == "custom-call"
+                           and "tpu_custom_call" not in line):
+            continue
+        seen += 1
+        if hit.group(1) not in table:
+            bare.append(line.strip()[:200])
+    return seen, bare, {scope for scope, _bwd, _inh in table.values()}
+
+
+def _for_chip(arrays, one_chip):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        arrays)
+
+
+def test_every_product_of_a_bert_train_step_carries_a_scope(one_chip):
+    """BERT-base widths (two layers of the twelve: the scopes do not
+    depend on depth), the fused step as ``DataParallelTrainer`` traces
+    it, compiled for the described chip: forward, backward, the Adam
+    rule and the health plane are all named."""
+    import numpy as np
+    import mxnet_tpu as mx
+    from mxnet_tpu import models, nd, parallel
+    from mxnet_tpu.gluon.loss import SoftmaxCrossEntropyLoss
+
+    b, s, m, vocab = 8, 128, 20, 8192
+    np.random.seed(0)
+    net = models.BERTForPretrain(models.bert_base(
+        vocab_size=vocab, max_length=s, num_layers=2))
+    net.initialize(mx.init.Xavier())
+    sce = SoftmaxCrossEntropyLoss()
+
+    def loss_fn(outs, label):
+        mlm_scores, nsp_scores = outs
+        return sce(mlm_scores, label[:, :m].reshape((-1,))).mean() \
+            + sce(nsp_scores, label[:, m]).mean()
+
+    dpt = parallel.DataParallelTrainer(
+        net, loss_fn, "adam", {"learning_rate": 1e-4},
+        mesh=parallel.make_mesh({"dp": 1}, devices=jax.devices()[:1]),
+        fuse_step=True)
+    got = {}
+
+    def capture(suffix, jitted, pyfn, vals, donate):
+        got["fn"], got["vals"] = pyfn, vals
+        raise _Captured()
+
+    dpt._tiered_exec = capture
+    rng = np.random.RandomState(0)
+    data = tuple(nd.array(a.astype("f4")) for a in (
+        rng.randint(0, vocab, (b, s)), rng.randint(0, 2, (b, s)),
+        np.full((b,), s), rng.randint(0, s, (b, m))))
+    label = nd.array(np.concatenate(
+        [rng.randint(0, vocab, (b, m)), rng.randint(0, 2, (b, 1))],
+        axis=1).astype("f4"))
+    with pytest.raises(_Captured):
+        dpt.step(data, label)
+    assert got["fn"].__name__ == "full_step"
+    compiled = jax.jit(got["fn"]).lower(
+        *_for_chip(got["vals"], one_chip)).compile()
+    assert compiled.as_text().startswith("HloModule jit_full_step")
+    seen, bare, scopes = _unscoped_products(compiled)
+    assert seen >= 30 and bare == []
+    assert {"mxtpu.embed", "mxtpu.mixer.full", "mxtpu.mlp", "mxtpu.head",
+            "mxtpu.loss", "mxtpu.step.optimizer",
+            "mxtpu.step.health"} <= scopes
+
+
+def test_every_product_of_a_llama_decode_program_carries_a_scope(
+        one_chip, monkeypatch):
+    """A windowed Llama's decode program, as ``Server`` traces it for a
+    bucket, compiled for the described chip."""
+    import numpy as np
+    import mxnet_tpu as mx
+    from mxnet_tpu import engine
+    from mxnet_tpu.models import LlamaForCausalLM, LlamaModel
+    from mxnet_tpu.serving import Server
+
+    np.random.seed(0)
+    lm = LlamaForCausalLM(LlamaModel(
+        2048, 512, 1408, 2, 4, num_kv_heads=2, sliding_window=4096),
+        tie_embeddings=False)
+    lm.initialize(mx.init.Xavier())
+    srv = Server(lm, buckets=[(8, 128)], max_new_tokens=128)
+    got = {}
+
+    def capture(name, pure, attrs, *flat, **_kw):
+        got["fn"], got["flat"] = pure, flat
+        raise _Captured()
+
+    with monkeypatch.context() as m:    # the model's ops go through it
+        m.setattr(engine, "invoke_compiled", capture)
+        with pytest.raises(_Captured):
+            srv._decode_impl(srv.sched.buckets[0], 1)
+    compiled = jax.jit(got["fn"]).lower(
+        *_for_chip(list(got["flat"]), one_chip)).compile()
+    assert compiled.as_text().startswith("HloModule jit_decode_b8x128")
+    seen, bare, scopes = _unscoped_products(compiled)
+    assert seen >= 10 and bare == []
+    assert {"mxtpu.embed", "mxtpu.mixer.swa", "mxtpu.mlp", "mxtpu.head",
+            "mxtpu.serving.pick"} <= scopes
