@@ -262,8 +262,10 @@ _reg("MXTPU_SERVING_SLOTS", int, 4,
 _reg("MXTPU_SERVING_BUCKETS", str, "32,128",
      "Default prompt-length buckets for serving.Server (comma-"
      "separated): a request lands in the smallest bucket holding its "
-     "prompt (right-padded there); each bucket owns one compiled "
-     "prefill and one compiled decode program.")
+     "prompt; each bucket owns one compiled decode program and a "
+     "ladder of compiled prefill programs (its length halved down to "
+     "256 positions; a prompt is right-padded to the shortest rung "
+     "that holds it).")
 _reg("MXTPU_SERVING_MAX_NEW_TOKENS", int, 32,
      "Default per-request generation cap for serving.Server; sizes "
      "the KV-cache pages (cache_len = prompt_len bucket + this).")

@@ -14,8 +14,9 @@ prefill/decode seams into that story:
   ``(slots, prompt_len)`` buckets: admits and evicts swap slot
   contents and an active-mask input, NEVER shapes, so steady state
   retraces nothing;
-* :mod:`~.server` — ``Server``: one compiled prefill + one compiled
-  decode program per bucket (plus scan-bulked ``decode_multi``),
+* :mod:`~.server` — ``Server``: one compiled decode program per bucket
+  (plus scan-bulked ``decode_multi``) and a ladder of compiled prefill
+  programs (an admission runs the shortest that holds its prompt),
   greedy/temperature/top-k sampling with the CachedOp fold_in RNG
   scheme, ``save_signature``/``warm_start`` through the PR 5
   persistent tier (a fresh process serves its first token with 0
@@ -27,8 +28,8 @@ See docs/serving.md for the bucket anatomy, a scheduler walkthrough,
 the warm-start workflow, and the telemetry field reference.
 """
 from .kvcache import KVCachePool
-from .scheduler import Bucket, BucketScheduler, Request
+from .scheduler import Bucket, BucketScheduler, Request, prefill_ladder
 from .server import Server, servers
 
 __all__ = ["KVCachePool", "Bucket", "BucketScheduler", "Request",
-           "Server", "servers"]
+           "Server", "servers", "prefill_ladder"]

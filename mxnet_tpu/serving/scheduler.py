@@ -1,13 +1,15 @@
 """Continuous-batching scheduler: admit/evict between decode steps
 into FIXED bucket shapes (docs/serving.md).
 
-The TPU contract that shapes this module: a compiled program exists
-per ``(batch_slots, prompt_len_bucket)`` pair and NOTHING else may
-vary.  So the scheduler never changes shapes — admission swaps a
-slot's cache page + flips its active-mask bit, eviction flips the bit
-back, and the decode program runs the same avals every step.  Steady
-state therefore performs ZERO retraces across any admit/evict
-sequence (asserted in tier-1 via ``engine.cache_info()``).
+The TPU contract that shapes this module: a bucket is a
+``(batch_slots, prompt_len_bucket)`` pair with ONE compiled decode
+program and a short fixed LADDER of prefill programs
+(:func:`prefill_ladder`), and NOTHING else may vary.  So the scheduler
+never changes shapes — admission swaps a slot's cache page + flips its
+active-mask bit, eviction flips the bit back, and the decode program
+runs the same avals every step.  Steady state therefore performs ZERO
+retraces across any admit/evict sequence (asserted in tier-1 via
+``engine.cache_info()``).
 
 Pure host logic: no jax, no dispatches.  ``Server`` (``server.py``)
 owns the compiled programs and drives this scheduler between them.
@@ -23,7 +25,7 @@ import numpy as np
 
 from ..base import MXNetError
 
-__all__ = ["Request", "Bucket", "BucketScheduler"]
+__all__ = ["Request", "Bucket", "BucketScheduler", "prefill_ladder"]
 
 _req_uid = itertools.count(1)
 
@@ -117,14 +119,42 @@ class Request:
         return len(self.generated) >= self.max_new_tokens
 
 
+#: the shortest rung of a prefill ladder, and what every rung below a
+#: bucket's own ``prompt_len`` is a multiple of (a multiple of 128
+#: positions keeps the lane tiles of a page stored positions-minor
+#: whole).  256, not 128: every rung is one more program to reload in a
+#: process's warm-up, and the 128 rungs cost over 5% of the set-up of
+#: the largest served programs (PERF.md section 6, PR 38)
+RUNG_FLOOR = 256
+
+
+def prefill_ladder(prompt_len: int, below: int = 0) -> tuple:
+    """The prompt shapes a bucket of ``prompt_len`` positions has a
+    prefill program for, shortest first: ``prompt_len`` halved for as
+    long as the half is a multiple of ``RUNG_FLOOR`` and longer than
+    ``below``, the next smaller bucket's ``prompt_len`` (a prompt that
+    short never lands here).  ``(48, 256), (24, 1024)`` give ``(256,)``
+    and ``(512, 1024)``, ``(96, 512)`` gives ``(256, 512)``; under 512
+    positions there is one rung."""
+    rungs = [int(prompt_len)]
+    while True:
+        half, odd = divmod(rungs[0], 2)
+        if odd or half % RUNG_FLOOR or half <= below:
+            return tuple(rungs)
+        rungs.insert(0, half)
+
+
 class Bucket:
     """One fixed ``(slots, prompt_len)`` shape class and its host-side
-    slot table.  ``cache_len = prompt_len + max_new_tokens`` positions
-    per slot; per-slot decode offsets are the ABSOLUTE next position
-    (they drive rope + the cache scatter + the validity mask as
-    dynamic inputs)."""
+    slot table: slots, pages and ONE decode program, and a ladder of
+    prefill programs (``rungs``; ``below`` is the next smaller bucket's
+    ``prompt_len``).  ``cache_len = prompt_len + max_new_tokens``
+    positions per slot; per-slot decode offsets are the ABSOLUTE next
+    position (they drive rope + the cache scatter + the validity mask
+    as dynamic inputs)."""
 
-    def __init__(self, slots: int, prompt_len: int, cache_len: int):
+    def __init__(self, slots: int, prompt_len: int, cache_len: int,
+                 below: int = 0):
         if slots < 1 or prompt_len < 1 or cache_len <= prompt_len:
             raise MXNetError(
                 f"bad bucket (slots={slots}, prompt_len={prompt_len}, "
@@ -133,6 +163,8 @@ class Bucket:
         self.slots = int(slots)
         self.prompt_len = int(prompt_len)
         self.cache_len = int(cache_len)
+        self.below = int(below)
+        self.rungs = prefill_ladder(self.prompt_len, self.below)
         self.requests: List[Optional[Request]] = [None] * self.slots
         self.offsets = np.zeros(self.slots, np.float32)
         self.active = np.zeros(self.slots, np.float32)
@@ -141,6 +173,16 @@ class Bucket:
     @property
     def key(self):
         return (self.slots, self.prompt_len)
+
+    def rung_for(self, prompt_len: int) -> int:
+        """The shortest rung that holds a prompt of ``prompt_len``
+        tokens: the shape its admission is padded to and prefilled at."""
+        return next(r for r in self.rungs if prompt_len <= r)
+
+    def resized(self, slots: int) -> "Bucket":
+        """An empty bucket of the same shape class with ``slots``
+        slots."""
+        return Bucket(slots, self.prompt_len, self.cache_len, self.below)
 
     def n_active(self) -> int:
         return int(self.active.sum())
@@ -203,9 +245,11 @@ class BucketScheduler:
     """FIFO admission over fixed buckets + a bounded wait queue.
 
     ``buckets``: list of ``(slots, prompt_len)`` pairs (one compiled
-    prefill and decode program each).  A request lands in the SMALLEST
-    bucket whose ``prompt_len`` holds its prompt (right-padded there);
-    prompts longer than every bucket are rejected.  The queue is
+    decode program and a ladder of prefill programs each).  A request
+    lands in the SMALLEST bucket whose ``prompt_len`` holds its prompt,
+    and is prefilled there right-padded to the shortest rung of the
+    bucket's ladder that holds it (``Bucket.rung_for``); prompts longer
+    than every bucket are rejected.  The queue is
     bounded by ``max_queue`` — overflow is the ``slot_oom`` signal
     (the caller records the retained telemetry event).
     """
@@ -216,11 +260,12 @@ class BucketScheduler:
                              "bucket")
         self.max_new_tokens = int(max_new_tokens)
         self.max_queue = int(max_queue)
-        self.buckets: List[Bucket] = [
-            Bucket(s, p, p + self.max_new_tokens)
-            for s, p in sorted(buckets, key=lambda b: b[1])]
-        if len({b.prompt_len for b in self.buckets}) != len(self.buckets):
+        rows = sorted(buckets, key=lambda b: b[1])
+        if len({p for _s, p in rows}) != len(rows):
             raise MXNetError("duplicate prompt_len buckets")
+        self.buckets: List[Bucket] = [
+            Bucket(s, p, p + self.max_new_tokens, below)
+            for (s, p), below in zip(rows, [0] + [p for _s, p in rows])]
         # no terminal-request registry: callers hold their own Request
         # references, and a server-side dict of every finished request
         # would grow without bound on a production stream

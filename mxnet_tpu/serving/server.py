@@ -1,18 +1,23 @@
 """``Server``: the engine + persist integration of the serving plane.
 
-One compiled PREFILL program and one compiled DECODE program per
-``(slots, prompt_len)`` bucket (plus ``decode_multi(K)`` lax.scan
-variants), all dispatched through ``engine.invoke_compiled`` with the
-bucket's state pool DONATED.  What a slot holds is the model's to say
-(``lm.state_spec``: K/V pages, rolling windows, recurrent and conv
-state, any rank and dtype; docs/serving.md, "State kinds"); the server
-moves the buffers and never looks inside a layer:
+One compiled DECODE program per ``(slots, prompt_len)`` bucket (plus
+``decode_multi(K)`` lax.scan variants) and a short LADDER of compiled
+PREFILL programs (``scheduler.prefill_ladder``: the bucket's
+``prompt_len`` halved down to 256 positions), all dispatched through
+``engine.invoke_compiled`` with the bucket's state pool DONATED.  What
+a slot holds is the model's to say (``lm.state_spec``: K/V pages,
+rolling windows, recurrent and conv state, any rank and dtype;
+docs/serving.md, "State kinds"); the server moves the buffers and never
+looks inside a layer:
 
-* **admit** — prefill one right-padded prompt at batch 1 into a batch-1
-  state, scatter every buffer of it into the pool at the assigned slot
-  (``lax.dynamic_update_slice`` per buffer, at its own rank), and sample
-  the first token at the prompt's own last position — ONE dispatch per
-  admission;
+* **admit** — prefill one prompt at batch 1, right-padded to the
+  shortest rung of its bucket's ladder that holds it, into a batch-1
+  state of that length, scatter every buffer of it into the pool at the
+  assigned slot (``lax.dynamic_update_slice`` per buffer, at its own
+  rank, from position 0), and sample the first token at the prompt's own
+  last position — ONE dispatch per admission.  A bucket's first
+  admission makes every rung's program ready (``_warm_ladder``), so no
+  later one compiles;
 * **decode** — every active slot advances one token in lockstep at its
   OWN absolute position (per-slot rope offsets / cache scatter /
   validity mask ride as dynamic inputs), the sampler picks
@@ -104,13 +109,17 @@ class _Owed:
     """One dispatch whose tokens the host has not read yet: ``out`` is
     the program's un-donated output, ``take`` the ``(column, request,
     n)`` rows that say whose tokens it holds (``n`` of its ``k`` steps,
-    the rest were decoded past the request's budget)."""
+    the rest were decoded past the request's budget).  ``variant`` is
+    the program's own ``k`` (``Server._suffix``: a multi-step decode's
+    steps, a prefill's rung, 0 for the plain program), which names it."""
 
-    __slots__ = ("kind", "bucket", "out", "k", "take", "t0", "ids")
+    __slots__ = ("kind", "bucket", "out", "k", "take", "t0", "ids",
+                 "variant")
 
-    def __init__(self, kind, bucket, out, k, take, t0, ids):
+    def __init__(self, kind, bucket, out, k, take, t0, ids, variant):
         self.kind, self.bucket, self.out, self.k = kind, bucket, out, k
         self.take, self.t0, self.ids = take, t0, ids
+        self.variant = variant
 
 
 def _dispatch_key(key_raw, counter):
@@ -673,7 +682,6 @@ class Server:
         from ..elastic import faults as _faults
         from ..elastic import resize as _resize
         from ..elastic.manager import record_recovery
-        from .scheduler import Bucket
         import jax.numpy as jnp
 
         new_slots = int(new_slots)
@@ -712,8 +720,7 @@ class Server:
             new_hash = self._compute_struct_hash(buckets=new_rows)
             new_base = f"serving_{self.lm.name}_{new_hash}"
             P = len(self._param_nds)
-            shadow = {b.key: Bucket(new_slots, b.prompt_len,
-                                    b.cache_len)
+            shadow = {b.key: b.resized(new_slots)
                       for b in self.sched.buckets}
             import jax
             prewarmed: Dict[str, dict] = {}
@@ -879,8 +886,7 @@ class Server:
                                   requeue=True):
                         requeued += 1
             self.sched.buckets = sorted(
-                (Bucket(new_slots, b.prompt_len, b.cache_len)
-                 for b in shadow.values()),
+                (b.resized(new_slots) for b in shadow.values()),
                 key=lambda x: x.prompt_len)
             self._pools = {
                 b.key: KVCachePool(self.lm, new_slots, b.cache_len,
@@ -1108,8 +1114,18 @@ class Server:
 
     # -- program builders --------------------------------------------------
     def _suffix(self, bucket, kind: str, k: int = 0) -> str:
+        """A bucket program's name after the server's: ``k`` is what
+        tells the variants of one kind apart, the steps of a multi-step
+        decode or the rung of a prefill shorter than the bucket's
+        ``prompt_len`` (:meth:`_rung_k`); the plain programs have 0."""
         return f"_b{bucket.slots}x{bucket.prompt_len}_{kind}" + \
             (f"{k}" if k else "")
+
+    @staticmethod
+    def _rung_k(bucket, rung: int) -> int:
+        """The ``k`` of the prefill program of ``rung``: the full rung
+        keeps the name a bucket's one prefill program always had."""
+        return 0 if rung == bucket.prompt_len else int(rung)
 
     def _bucket_for_suffix(self, suffix: str):
         for b in self.sched.buckets:
@@ -1122,7 +1138,7 @@ class Server:
         fn = self._pure_cache.get(key)
         if fn is None:
             if kind == "prefill":
-                fn = self._make_prefill(bucket)
+                fn = self._make_prefill(bucket, k or bucket.prompt_len)
             elif kind == "decode" and not k:
                 fn = self._make_decode(bucket)
             elif kind == "decode" and k:
@@ -1265,12 +1281,15 @@ class Server:
 
         return decode_multi_pure
 
-    def _make_prefill(self, bucket):
+    def _make_prefill(self, bucket, rung: int):
         lm, ctx = self.lm, self.ctx
         params = self._param_nds
         P, NS = len(params), self._n_state(bucket)
-        # the batch-1 state one prompt of this bucket prefills into
-        one = self._spec_for(1, bucket.prompt_len)
+        # the batch-1 state a prompt padded to ``rung`` prefills into;
+        # it lands in the slot's buffers from position 0, and what lies
+        # past it there keeps the last tenant's values, which the
+        # per-row validity mask never exposes
+        one = self._spec_for(1, rung)
 
         def prefill_pure(*flat):
             import jax
@@ -1349,6 +1368,8 @@ class Server:
             donate = tuple(range(P, P + NS))
         name = self.name + suffix
         persist_name = self._persist_base + suffix
+        if kind == "prefill" and suffix not in self._warmed:
+            self._warm_ladder(bucket, suffix, flat, donate)
         m0, f0 = engine.compile_counts()
         # the step-owner bracket doubles as the guardian plane's
         # heartbeat: a hung serving dispatch is watchdog-visible
@@ -1368,22 +1389,7 @@ class Server:
             n_out = len(res) - NS
             pool.adopt(res[n_out:])
             if suffix not in self._variants:
-                self._variants[suffix] = {
-                    "suffix": suffix, "kind": kind, "k": k,
-                    "donate": [int(i) for i in donate],
-                    "avals": engine.persist.sig_to_json(
-                        engine.persist.aval_sig(flat))}
-                # the wire auditor (analysis.wire_passes): serving decode/
-                # prefill legs classify via the plan's decode spec; no
-                # observatory reconciliation (program="") — serving wire
-                # is GSPMD-implicit on the decode mesh
-                try:
-                    from ..analysis import wire_passes as _wire
-                    _wire.note_step(
-                        f"serving:{self.lm.name}", suffix, pure, flat,
-                        plan=self.plan, kind=kind, program="")
-                except Exception:
-                    pass
+                self._note_variant(suffix, kind, k, donate, pure, flat)
             if suffix not in self._warmed:
                 # first dispatch of this variant pays its compile; every
                 # later one is steady state and must compile NOTHING
@@ -1395,6 +1401,55 @@ class Server:
                 stats["steady_misses"] += m1 - m0
                 stats["steady_fresh_compiles"] += f1 - f0
         return res[0]
+
+    def _note_variant(self, suffix, kind, k, donate, pure, flat):
+        """Record a variant's manifest row (what ``save_signature``,
+        ``warm_start`` and ``resize_slots``' prewarm carry), once."""
+        from .. import engine
+        self._variants[suffix] = {
+            "suffix": suffix, "kind": kind, "k": k,
+            "donate": [int(i) for i in donate],
+            "avals": engine.persist.sig_to_json(
+                engine.persist.aval_sig(flat))}
+        # the wire auditor (analysis.wire_passes): serving decode/
+        # prefill legs classify via the plan's decode spec; no
+        # observatory reconciliation (program="") — serving wire
+        # is GSPMD-implicit on the decode mesh
+        try:
+            from ..analysis import wire_passes as _wire
+            _wire.note_step(
+                f"serving:{self.lm.name}", suffix, pure, flat,
+                plan=self.plan, kind=kind, program="")
+        except Exception:
+            pass
+
+    def _warm_ladder(self, bucket, suffix, flat, donate):
+        """Before the first dispatch of a prefill program that is not
+        warm: make every OTHER rung of the bucket's ladder ready too
+        (``engine.aot_compile``: no execution; this dispatch's inputs
+        with the prompt's shape replaced), record each as a variant and
+        mark it warmed, so its first live dispatch is already steady
+        state.  After a bucket's first admission no rung compiles,
+        whichever prompt lengths the traffic brings later."""
+        import jax
+        from .. import engine
+        at = len(flat) - _N_INPUTS["prefill"]       # the prompt's place
+        prompt = flat[at]
+        for rung in bucket.rungs:
+            k = self._rung_k(bucket, rung)
+            sfx = self._suffix(bucket, "prefill", k)
+            if sfx == suffix or sfx in self._warmed:
+                continue
+            args = list(flat)
+            args[at] = jax.ShapeDtypeStruct(
+                (1, rung), prompt.dtype,
+                sharding=getattr(prompt, "sharding", None))
+            pure = self._pure_for(bucket, "prefill", k)
+            engine.aot_compile(self.name + sfx, pure, {}, args,
+                               donate=donate,
+                               persist_name=self._persist_base + sfx)
+            self._note_variant(sfx, "prefill", k, donate, pure, args)
+            self._warmed.add(sfx)
 
     def _poison(self, pool, name: str, e: Exception):
         """Latch the post-donation failure of the dispatch ``name`` and
@@ -1441,13 +1496,15 @@ class Server:
         return self._key_base, np.asarray(n, np.uint32)
 
     def _admit(self, bucket, slot: int, req: Request):
-        """Enqueue one admission's prefill; its first token is read
+        """Enqueue one admission's prefill, at the shortest rung of the
+        bucket's ladder that holds the prompt; its first token is read
         after this round's decodes are enqueued (:meth:`_read`)."""
+        rung = bucket.rung_for(req.prompt_len)
         with _span("mxtpu.serving.admit", "serving", req=req.id,
-                   bucket=bucket.prompt_len, slot=slot):
-            self._admit_impl(bucket, slot, req)
+                   bucket=bucket.prompt_len, rung=rung, slot=slot):
+            self._admit_impl(bucket, slot, req, rung)
 
-    def _admit_impl(self, bucket, slot: int, req: Request):
+    def _admit_impl(self, bucket, slot: int, req: Request, rung: int):
         from .. import telemetry
         t0 = req.admit_t = time.perf_counter()
         telemetry.histogram(
@@ -1455,8 +1512,7 @@ class Server:
             "submit -> start of the admission (s)").observe(
             t0 - req.submit_t)
         with _span("mxtpu.serving.build_inputs", "serving", req=req.id):
-            S = bucket.prompt_len
-            prompt = np.zeros((1, S), np.float32)
+            prompt = np.zeros((1, rung), np.float32)
             prompt[0, :req.prompt_len] = req.prompt
             extra = [prompt,
                      np.asarray([req.prompt_len - 1], np.float32),
@@ -1466,12 +1522,23 @@ class Server:
         # pre-dispatch failures (trace/compile, retries exhausted)
         # propagate to step(), which releases THIS placement and the
         # ones behind it back to the queue in FIFO order
-        out = self._dispatch(bucket, "prefill", extra, req=req.id)
+        variant = self._rung_k(bucket, rung)
+        out = self._dispatch(bucket, "prefill", extra, k=variant,
+                             req=req.id)
         with _span("mxtpu.serving.bookkeeping", "serving", req=req.id):
             telemetry.counter("mxtpu_serving_prefills_total",
                               "admission prefill dispatches").inc()
+            # their ratio is the live share of what prefill ran over
+            telemetry.counter(
+                "mxtpu_serving_prompt_tokens_total",
+                "prompt tokens of the admissions prefilled").inc(
+                req.prompt_len)
+            telemetry.counter(
+                "mxtpu_serving_prefill_positions_total",
+                "positions the admissions' prefill programs ran over "
+                "(each prompt padded to its rung)").inc(rung)
             self._owe("prefill", bucket, out, 1, [(0, slot, req)], t0,
-                      {"req": req.id})
+                      {"req": req.id}, variant)
 
     def _decode(self, bucket, decode_steps: int):
         """Enqueue one decode of the bucket; nothing is read here."""
@@ -1489,8 +1556,8 @@ class Server:
                      *self._rng_inputs()]
         ahead = any(rec.kind == "decode" and rec.bucket is bucket
                     for rec in self._owed)
-        out = self._dispatch(bucket, "decode", extra,
-                             k=0 if k == 1 else k)
+        variant = 0 if k == 1 else k
+        out = self._dispatch(bucket, "decode", extra, k=variant)
         with _span("mxtpu.serving.bookkeeping", "serving"):
             if ahead:
                 telemetry.counter(
@@ -1503,9 +1570,10 @@ class Server:
             bucket.offsets += k * active
             slots = [int(j) for j in np.nonzero(active > 0)[0]]
             self._owe("decode", bucket, out, k,
-                      [(j, j, bucket.requests[j]) for j in slots], t0, {})
+                      [(j, j, bucket.requests[j]) for j in slots], t0, {},
+                      variant)
 
-    def _owe(self, kind, bucket, out, k, rows, t0, ids):
+    def _owe(self, kind, bucket, out, k, rows, t0, ids, variant):
         """The count half of a dispatch's bookkeeping, at dispatch time:
         each ``(column, slot, request)`` row is owed as many of the
         dispatch's ``k`` tokens as its budget still holds, and a request
@@ -1519,7 +1587,8 @@ class Server:
             take.append((col, req, n))
             if req.room() == 0:
                 bucket.release(slot)
-        self._owed.append(_Owed(kind, bucket, out, k, take, t0, ids))
+        self._owed.append(
+            _Owed(kind, bucket, out, k, take, t0, ids, variant))
 
     def _disown(self, req: Request):
         """Strike ``req`` from the owed dispatches: whatever the device
@@ -1545,9 +1614,11 @@ class Server:
         bookkeeping; returns the tokens delivered.  The host waits for
         the device here and nowhere else."""
         first = rec.kind == "prefill"
+        ids = dict(rec.ids, rung=rec.variant or rec.bucket.prompt_len) \
+            if first else rec.ids
         with _span("mxtpu.serving.admit" if first
                    else "mxtpu.serving.decode", "serving",
-                   bucket=rec.bucket.prompt_len, **rec.ids):
+                   bucket=rec.bucket.prompt_len, **ids):
             return self._read_impl(rec, first)
 
     def _read_impl(self, rec: _Owed, first: bool) -> int:
@@ -1561,7 +1632,7 @@ class Server:
             except Exception as e:
                 self._poison(
                     self._pools[rec.bucket.key], self.name + self._suffix(
-                        rec.bucket, rec.kind, 0 if rec.k == 1 else rec.k), e)
+                        rec.bucket, rec.kind, rec.variant), e)
         with _span("mxtpu.serving.bookkeeping", "serving", **rec.ids):
             if self._stat_rows:
                 # the model's counts came back behind the tokens, and

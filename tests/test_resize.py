@@ -466,6 +466,40 @@ def test_serving_slot_grow_shrink_churn(lm):
     assert len(resize_mod.resizes()) == 2
 
 
+@pytest.mark.time_limit(300)
+def test_serving_resize_carries_every_rung_of_the_prefill_ladder(lm):
+    """A bucket's prefill ladder (docs/serving.md, "Buckets") is part of
+    what a resize pre-warms: every rung is a recorded variant, so after
+    the swap a prompt of any length compiles nothing, with a resident
+    that was admitted through a short rung keeping its progress."""
+    from mxnet_tpu.serving import Server
+    ref = Server(lm, buckets=[(2, 512)], max_new_tokens=6)
+    ref_out = ref.generate([_prompt(0, 100), _prompt(1, 300)])
+
+    srv = Server(lm, buckets=[(2, 512)], max_new_tokens=6)
+    r1 = srv.submit(_prompt(0, 100))            # rung 256; 512 is made
+    srv.step()
+    srv.step()
+    rec = srv.resize_slots(3)
+    assert rec["prewarmed_variants"] == 3       # two rungs + decode
+    assert sorted(srv._variants) == [
+        "_b3x512_decode", "_b3x512_prefill", "_b3x512_prefill256"]
+    assert [v["k"] for _s, v in sorted(srv._variants.items())] \
+        == [0, 0, 256]
+    bucket, = srv.sched.buckets
+    assert (bucket.slots, bucket.rungs) == (3, (256, 512))
+    m0, f0 = engine.compile_counts()
+    r2 = srv.submit(_prompt(1, 300))            # the rung never run
+    srv.run()
+    assert tuple(b - a for a, b in zip((m0, f0), engine.compile_counts())) \
+        == (0, 0)
+    np.testing.assert_array_equal(r1.tokens(), ref_out[0])
+    np.testing.assert_array_equal(r2.tokens(), ref_out[1])
+    st = srv.stats()["buckets"]["3x512"]
+    assert st["steady_dispatches"] > 0
+    assert st["steady_misses"] == st["steady_fresh_compiles"] == 0
+
+
 def test_serving_resize_fault_matrix(lm):
     from mxnet_tpu.serving import Server
     srv = Server(lm, buckets=[(2, 8)], max_new_tokens=6)

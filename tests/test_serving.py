@@ -617,10 +617,11 @@ def test_round_spans_nest_and_share_the_request_id(net):
     assert len(named("mxtpu.serving.schedule", rnd)) == 1
     admit, first = named("mxtpu.serving.admit", rnd)
     decode, late = named("mxtpu.serving.decode", rnd)
-    assert admit["args"] == {"req": req.id, "bucket": 8, "slot": 1}
+    assert admit["args"] == {"req": req.id, "bucket": 8, "rung": 8,
+                             "slot": 1}
     assert decode["args"] == {"bucket": 8, "active": 2}
     assert late["args"] == {"bucket": 8}
-    assert first["args"] == {"bucket": 8, "req": req.id}
+    assert first["args"] == {"bucket": 8, "req": req.id, "rung": 8}
     # everything is enqueued before anything is read
     order = [admit, decode, late, first]
     assert all(a["ts"] + a["dur"] <= b["ts"]
