@@ -178,6 +178,15 @@ def _summary(loop, t_w0, t_w1, t_end):
     }
 
 
+# How long set-up waits for the requests that make the first call of every
+# program.  Their first rounds COMPILE in a run that finds no cache (a
+# bucket's decode and every rung of its prefill ladder): 121-124 s for the
+# probe of ``reason_closed`` and ``longgen_closed`` (my chip run, PR 39,
+# call 1), which the former 120 s cut after one token, so that a sound run
+# read ``correct`` false.  Only a server that never answers waits it out.
+FIRST_CALLS_LIMIT_S = 600.0
+
+
 def _probe(run, net, srv, ctx, builder, loop):
     """The first request of every run: its greedy tokens against a plain
     full-sequence forward of the same weights (chip_smoke.py's rule: each
@@ -188,7 +197,7 @@ def _probe(run, net, srv, ctx, builder, loop):
     prompt = gen.rng_for(run.seed, 5).integers(
         1, vocab, int(pr["prompt_len"])).astype(np.float32)
     rec = loop.submit(prompt, int(pr["new_tokens"]), time.perf_counter())
-    loop.drain(120.0)
+    loop.drain(FIRST_CALLS_LIMIT_S)
     req = rec["req"]
     run.checks.hold(req is not None and len(req.generated)
                     == int(pr["new_tokens"]),
@@ -239,7 +248,7 @@ def run(run):
         for n in tr["warm_prompt_lens"]:
             loop.submit(rng.integers(1, vocab, int(n)).astype(np.float32),
                         2, time.perf_counter())
-        loop.drain(120.0)
+        loop.drain(FIRST_CALLS_LIMIT_S)
     warm_requests = len(loop.requests)
     runtime.hold_on_platform(
         checks, [(p.name, p.data(ctx)._data)

@@ -16,9 +16,11 @@ reader (the parent of the PR that added it) gives None, and every metric
 that reads it is left out of the line; a reader that is there and RAISES
 fails the traced run, so a broken reader cannot pass for an untraced run.
 
-The metrics that read it (``chipbench/held_per_layer.json``) are held
-out of ``BENCHMARK.json`` until its tests allow an append;
-``chipbench/run_held.py`` runs a cell with them.
+The 15 metrics that read it (``step_device_ms.train`` to
+``attend_roofline_share.mla``) are entries of ``BENCHMARK.json`` like any
+other; the first of their readers that a traced run calls reads the table.
+(``chipbench/run_held.py``, their side entry until PR 39, stays only while
+documents outside the benchmark name it, and adds nothing.)
 
 The rest works on that table alone, so ``chipbench/tests`` checks it on a
 hand-built one.

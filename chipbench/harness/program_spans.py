@@ -65,10 +65,14 @@ def read_spans(path):
 
 # -- pure functions over (name, thread, start, end[, ids]) lists -------------
 
+def within(span, t0, t1):
+    return span[2] >= t0 and span[3] <= t1
+
+
 def inside(spans, t0, t1):
     """The spans that lie wholly inside [t0, t1]: a span cut by the
     window's edge has no duration worth a median."""
-    return [s for s in spans if s[2] >= t0 and s[3] <= t1]
+    return [s for s in spans if within(s, t0, t1)]
 
 
 def parents(spans):
@@ -163,9 +167,18 @@ def minus_child(spans, kids, i, child_name):
         if spans[k][0] == child_name)
 
 
+def admission(span, i):
+    """What names the admission a ``serving.admit`` span belongs to: its
+    ``req`` id, or, for a span that carries none, the span itself."""
+    req = span[4].get("req") if len(span) > 4 else None
+    return ("span", i) if req is None else ("req", req)
+
+
 def reduce_spans(spans, window, device_ops):
     """Everything the metric files read, from one trace's lists."""
     t0, t1 = window
+    cut = {admission(s, None) for s in spans
+           if s[0] == PREFIX + "serving.admit" and not within(s, t0, t1)}
     spans = inside(spans, t0, t1)
     if not spans:
         return None
@@ -178,12 +191,18 @@ def reduce_spans(spans, window, device_ops):
     idle, named_share = name_gaps(gaps, spans, par, kids)
 
     # serving: a round's host time is what its decode (or admit) spans
-    # take beside their token_read child, in which the host only waits
+    # take beside their token_read child, in which the host only waits.
+    # One admission is every admit span of one ``req`` id (since the
+    # program enqueues a prefill and reads its token later, two): their
+    # sum, and none of an admission that the window's edge cut in two.
     read = PREFIX + "serving.token_read"
-    decode_host, decode_rounds, admit_host = [], [], []
+    decode_host, decode_rounds, admits = [], [], {}
     for i, s in enumerate(spans):
         if s[0] == PREFIX + "serving.admit":
-            admit_host.append(minus_child(spans, kids, i, read))
+            key = admission(s, i)
+            if key not in cut:
+                admits[key] = admits.get(key, 0.0) \
+                    + minus_child(spans, kids, i, read)
         elif s[0] == PREFIX + "serving.round":
             names = [spans[k][0] for k in kids[i]]
             if PREFIX + "serving.admit" in names \
@@ -196,7 +215,8 @@ def reduce_spans(spans, window, device_ops):
     return {
         "window_s": t1 - t0, "durations": durations, "selfs": selfs,
         "idle_s": idle, "idle_named_share": named_share,
-        "decode_host_s": decode_host, "admit_host_s": admit_host,
+        "decode_host_s": decode_host,
+        "admit_host_s": list(admits.values()),
         "decode_only_rounds": decode_rounds,
     }
 

@@ -1,8 +1,7 @@
 """The readers of device time by scope, on a hand-built table, and that
-every metric that reads it is found by name.  The 15 entries are HELD in
-``chipbench/held_per_layer.json`` (the accepted cells' tests pin each
-cell's metric set and the tail of ``per_layer``); ``run_held.py`` runs a
-cell with them.
+every metric that reads it is found by name in ``BENCHMARK.json`` (the
+15 entries PR 37 wrote and PR 39 moved in), each held to its layer, its
+end-to-end metric and AT LEAST the cells it began with.
 
 Run by hand: ``JAX_PLATFORMS=cpu python -m pytest chipbench/tests -q``.
 """
@@ -16,17 +15,16 @@ BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
 sys.path.insert(0, ROOT)
 
-from chipbench import run_held  # noqa: E402
+from chipbench import run_held                                # noqa: E402
 from chipbench.harness import device_scopes as ds, resolve  # noqa: E402
 
-BENCH = resolve.load_benchmark()
-HELD = resolve.load_json(BENCH_DIR, "held_per_layer.json")
 TRAIN = "bert_base.pretrain_s128"
 CHAT = "mistral_7b_l8.chat_steady"
+PANGU = "pangu_ultra_moe_ep16.longgen_closed"
 CLOSED = ["mistral_7b_l8.offline_closed", "phi4_mini_flash.reason_closed",
-          "trinity_large_ep8.decode_closed",
-          "pangu_ultra_moe_ep16.longgen_closed"]
-# name -> (layer, the end-to-end metric it moves, its cells)
+          "trinity_large_ep8.decode_closed", PANGU]
+# name -> (layer, the end-to-end metric it moves, the cells its list of
+# ``workloads`` BEGINS with: a later closed cell appends itself)
 NEW = {
     "step_device_ms.train": ("trainer", "train_tokens_per_s", [TRAIN]),
     "attention_device_ms.train":
@@ -48,7 +46,7 @@ NEW = {
         ("ops / kernels", "serve_tokens_per_s", CLOSED),
     "decode_ffn_ms.offline": ("ops / kernels", "serve_tokens_per_s", CLOSED),
     "attend_roofline_share.mla":
-        ("ops / kernels", "serve_tokens_per_s", CLOSED[-1:]),
+        ("ops / kernels", "serve_tokens_per_s", [PANGU]),
 }
 
 
@@ -72,39 +70,63 @@ def traced(table):
     return {"trace": {"window_s": 3.0}, ds.KEY: table}
 
 
-def test_the_15_held_entries_name_cells_layers_and_metrics_that_exist():
-    entries = {m["name"]: m for m in HELD}
-    cells = {w["name"] for w in BENCH["workloads"]}
-    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
-    layers = {m["layer"] for m in BENCH["per_layer"]}
-    assert len(NEW) == 15 and list(entries) == list(NEW)
-    # held, not accepted: no name is in both places
-    assert not set(NEW) & {m["name"] for m in BENCH["per_layer"]}
-    for name, (layer, moves, workloads) in NEW.items():
-        m = entries[name]
+def test_the_15_entries_name_cells_layers_and_metrics_that_exist(bench):
+    listed = [m["name"] for m in bench["per_layer"]]
+    cells = {w["name"] for w in bench["workloads"]}
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    # a layer that the benchmark named before these entries came
+    layers = {m["layer"] for m in bench["per_layer"] if m["name"] not in NEW}
+    # all 15, once each, in the order PR 37 wrote them
+    at = [listed.index(name) for name in NEW]
+    assert len(NEW) == 15 and at == sorted(at)
+    assert all(listed.count(name) == 1 for name in NEW)
+    for (name, (layer, moves, workloads)), i in zip(NEW.items(), at):
+        m = bench["per_layer"][i]
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-        assert (m["layer"], m["moves"], m["workloads"]) == \
-            (layer, moves, workloads), name
-        assert layer in layers and set(workloads) <= cells
-        assert set(workloads) <= set(e2e[moves]["workloads"])
+        assert (m["layer"], m["moves"]) == (layer, moves), name
+        assert m["workloads"][:len(workloads)] == workloads, name
+        assert layer in layers and set(m["workloads"]) <= cells
+        # each cell listed reports the metric this one moves
+        assert set(m["workloads"]) <= set(e2e[moves]["workloads"])
         assert m["source"] == "device_trace"
         assert m["better"] == ("higher" if "roofline" in name else "lower")
         assert m["unit"] == ("%" if "share" in name else "ms")
         assert callable(resolve.load_module("layer_metrics", name).read)
 
 
-def test_run_held_reads_a_cell_with_the_held_entries_at_the_end():
-    merged = run_held.with_held(BENCH)
-    assert merged["per_layer"][:-15] == BENCH["per_layer"]
-    assert [m["name"] for m in merged["per_layer"][-15:]] == list(NEW)
-    assert {k: v for k, v in merged.items() if k != "per_layer"} == \
-        {k: v for k, v in BENCH.items() if k != "per_layer"}
-    for cell, want in ((TRAIN, 7), (CHAT, 2), (CLOSED[0], 6),
-                       (CLOSED[-1], 7)):
-        mine = [m["name"] for m in resolve.metrics_of(
-            merged, "per_layer", cell) if m["name"] in NEW]
-        assert len(mine) == want, (cell, mine)
+def test_each_cell_reads_its_part_of_the_15(bench):
+    """At least: train 7, the open loop 2, a closed loop 6, and the
+    latent attention's roofline beside them in its cell."""
+    def mine(cell):
+        return [m["name"] for m in resolve.metrics_of(
+            bench, "per_layer", cell) if m["name"] in NEW]
+    for cell, want in ((TRAIN, 7), (CHAT, 2), (CLOSED[0], 6), (PANGU, 7)):
+        assert len(mine(cell)) >= want, (cell, mine(cell))
+    # every cell that reads a decode program's time reads its split too
+    by = {m["name"]: m for m in bench["per_layer"]}
+    for cell in by["decode_device_ms.offline"]["workloads"]:
+        assert len(mine(cell)) >= 6, (cell, mine(cell))
+
+
+def test_the_former_side_entry_adds_nothing_to_the_benchmark(bench):
+    """``run_held.py`` and ``held_per_layer.json`` stay for the documents
+    that name them: each entry once held is an entry of the benchmark,
+    equal but for the cells that joined its ``workloads`` since, so the
+    side entry runs a cell as ``run.py`` does."""
+    held = resolve.load_json(BENCH_DIR, "held_per_layer.json")
+    by = {m["name"]: m for m in bench["per_layer"]}
+    assert [m["name"] for m in held] == list(NEW)
+    for m in held:
+        n = len(m["workloads"])
+        assert dict(by[m["name"]],
+                    workloads=by[m["name"]]["workloads"][:n]) == m
+    assert run_held.with_held(bench) == bench
+    # an entry that the benchmark lacks would still be read
+    less = dict(bench, per_layer=[m for m in bench["per_layer"]
+                                  if m["name"] != held[0]["name"]])
+    assert run_held.with_held(less)["per_layer"] == \
+        less["per_layer"] + held[:1]
 
 
 def test_an_untraced_run_opens_no_file_and_prints_none_of_them(

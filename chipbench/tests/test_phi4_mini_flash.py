@@ -49,7 +49,8 @@ def built(cell, builder):
     return shapes, net, srv, ctx
 
 
-def test_the_cell_its_files_and_its_metrics_resolve_by_name(cell, builder):
+def test_the_cell_its_files_and_its_metrics_resolve_by_name(
+        cell, builder, bench):
     workload, config, traffic = cell
     assert workload["chips"] == 1 and config["reduced"] == []
     assert traffic["driver"] == "serve_loop"
@@ -58,25 +59,34 @@ def test_the_cell_its_files_and_its_metrics_resolve_by_name(cell, builder):
     for fn in ("build_server", "n_params", "full_forward_logits",
                "decode_bytes_per_round", "flops_per_token"):
         assert callable(getattr(builder, fn))
+    assert resolve.cell(bench, CELL) == cell
     names = {m["name"] for g in ("end_to_end", "per_layer")
-             for m in resolve.metrics_of(BENCH, g, CELL)}
+             for m in resolve.metrics_of(bench, g, CELL)}
     # the closed-loop reductions are the accepted cell's own entries, with
-    # this cell appended: one name a reduction, no twin files
-    assert names == {
+    # this cell appended: one name a reduction, no twin files.  A floor:
+    # what the cell reports at least, whatever later PRs add to it
+    assert names >= {
         "serve_tokens_per_s", "setup_s", "compile_s",
         "decode_round_ms.offline", "occupancy.offline",
         "steady_tokens_per_s.offline", "device_idle_share.offline",
         "state_bytes_per_slot.reason", "decode_hbm_share.reason",
         "mfu.reason"}
     for g, sub in resolve.GROUP_DIRS.items():
-        for m in resolve.metrics_of(BENCH, g, CELL):
+        for m in resolve.metrics_of(bench, g, CELL):
             assert callable(resolve.load_module(sub, m["name"]).read)
+    by = {m["name"]: m for m in bench["per_layer"]}
+    # the state gauge's list began with this cell; its two own entries
+    for name in ("decode_hbm_share.reason", "mfu.reason"):
+        assert (by[name]["workloads"][0], by[name]["moves"],
+                by[name]["layer"]) == (
+            CELL, "serve_tokens_per_s", "ops / kernels")
+    assert by["state_bytes_per_slot.reason"]["workloads"][0] == CELL
 
 
-def test_the_file_holds_every_number_of_the_source(cell):
+def test_the_file_holds_every_number_of_the_source(cell, bench):
     config = cell[1]
     assert {k: config[k] for k in CATALOG} == CATALOG
-    entry = next(c for c in BENCH["configs"] if c["name"] == config["name"])
+    entry = next(c for c in bench["configs"] if c["name"] == config["name"])
     assert entry["reduced"] == [] and entry["file"].endswith(
         "phi4_mini_flash.json")
     assert config["source"] in entry["source"]

@@ -15,7 +15,6 @@ sys.path.insert(0, ROOT)
 
 from chipbench.harness import program_spans as ps, resolve  # noqa: E402
 
-BENCH = resolve.load_benchmark()
 T, S, E = "mxtpu.trainer.", "mxtpu.serving.", "mxtpu.engine."
 
 # one train step on thread 0, one helper span on thread 1, and a step
@@ -105,14 +104,46 @@ def test_the_whole_reduction_of_a_serving_trace():
     assert ps.reduce_spans(SERVE[:1], (0.1, 3.9), OPS) is None
 
 
-def _new_metrics():
-    return [m for m in BENCH["per_layer"]
-            if m["name"].rsplit(".", 1)[0] in (
-                "step_place_batch_ms", "step_gather_args_ms",
-                "step_execute_ms", "step_write_back_ms",
-                "step_unattributed_ms", "decode_host_ms", "admit_host_ms",
-                "queue_wait_p95_ms", "engine_lookup_us",
-                "idle_named_share")]
+# one admission a request since the program enqueues a prefill and reads
+# its first token later: two ``admit`` spans that carry one ``req`` id
+ADMITS = [
+    (S + "round", 0, 0.0, 4.0, {"round": 1}),
+    (S + "admit", 0, 0.25, 1.0, {"req": 7, "slot": 3}),     # enqueue
+    (S + "admit", 0, 1.0, 1.5, {"req": 8, "slot": 4}),
+    (S + "decode", 0, 1.5, 2.0, {}),
+    (S + "admit", 0, 2.0, 3.0, {"req": 7}),                 # its read
+    (S + "token_read", 0, 2.25, 2.75, {"req": 7}),
+    (S + "admit", 0, 3.0, 3.25, {"req": 8}),
+    (S + "admit", 0, 3.5, 3.75, {}),        # a program before ``req`` ids
+    (S + "admit", 0, 3.75, 4.0),            # a recorded list without ids
+    (S + "round", 0, 4.0, 9.0, {"round": 2}),
+    (S + "admit", 0, 4.0, 6.0, {"req": 9}),     # enqueued inside ...
+    (S + "admit", 0, 8.5, 9.5, {"req": 9})]     # ... read past the edge
+
+
+def test_an_admission_is_the_sum_of_its_spans_by_request():
+    red = ps.reduce_spans(ADMITS, (0.0, 9.0), [])
+    # 7: 0.75 + (1.0 - 0.5 of waiting); 8: 0.5 + 0.25; two of their own;
+    # 9 is cut by the window's edge and counts for nothing
+    assert sorted(red["admit_host_s"]) == [
+        pytest.approx(0.25), pytest.approx(0.25), pytest.approx(0.75),
+        pytest.approx(1.25)]
+    assert ps.summary(red)["admit_host_median_ms"] == pytest.approx(500.0)
+    # a span before the window counts as one cut, like one past it
+    late = ps.reduce_spans(ADMITS, (0.5, 9.0), [])
+    assert sorted(late["admit_host_s"]) == [
+        pytest.approx(0.25), pytest.approx(0.25), pytest.approx(0.75)]
+
+
+# PR 27's metrics that read the program's spans and are in the benchmark
+# today: at least these, each in at least one cell
+SPAN_METRICS = [
+    "step_place_batch_ms.train", "step_gather_args_ms.train",
+    "step_execute_ms.train", "step_write_back_ms.train",
+    "step_unattributed_ms.train", "decode_host_ms.chat",
+    "decode_host_ms.offline", "admit_host_ms.chat", "admit_host_ms.offline",
+    "queue_wait_p95_ms.chat", "engine_lookup_us.chat",
+    "idle_named_share.train"]
 
 
 def test_an_untraced_run_reads_no_file_and_prints_none_of_them(
@@ -123,22 +154,33 @@ def test_an_untraced_run_reads_no_file_and_prints_none_of_them(
     monkeypatch.setattr(ps, "read_spans", no_file)
     obs = {"trace": None, "requests": [], "window": (0.0, 1.0)}
     assert ps.of(obs) is None
-    new = _new_metrics()
-    assert len(new) == 14
-    for m in new:
-        read = resolve.load_module("layer_metrics", m["name"]).read
-        assert read(obs) is None, m["name"]
+    for name in SPAN_METRICS:
+        read = resolve.load_module("layer_metrics", name).read
+        assert read(obs) is None, name
     assert capsys.readouterr().out == ""
 
 
-def test_every_new_metric_reads_its_span_from_the_reduction(capsys):
+def test_every_span_metric_names_cells_that_report_what_it_moves(bench):
     layers = {"trainer", "serving", "engine + tiers", "device"}
-    cells = {w["name"] for w in BENCH["workloads"]}
-    e2e = {m["name"] for m in BENCH["end_to_end"]}
-    for m in _new_metrics():
-        assert m["layer"] in layers and m["moves"] in e2e
-        assert set(m["workloads"]) <= cells and len(m["workloads"]) == 1
-        assert m["source"] in ("program_counter", "device_trace")
+    cells = {w["name"] for w in bench["workloads"]}
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    by = {m["name"]: m for m in bench["per_layer"]}
+    for m in (by[name] for name in SPAN_METRICS):
+        assert m["layer"] in layers and m["source"] in (
+            "program_counter", "device_trace")
+        # one cell or several, each of which reports the metric it moves
+        assert m["workloads"] and set(m["workloads"]) <= cells
+        assert set(m["workloads"]) <= set(
+            e2e[m["moves"]].get("workloads", cells)), m["name"]
+    # ``Server.step`` enters the same spans for every model: every cell
+    # that reports a closed loop's round reports the host's side of it,
+    # and of an admission
+    for name in ("decode_host_ms.offline", "admit_host_ms.offline"):
+        assert by[name]["workloads"] == \
+            by["decode_round_ms.offline"]["workloads"]
+
+
+def test_every_span_metric_reads_its_span_from_the_reduction(capsys):
 
     def read(name, obs):
         return resolve.load_module("layer_metrics", name).read(obs)
@@ -149,8 +191,13 @@ def test_every_new_metric_reads_its_span_from_the_reduction(capsys):
     assert read("decode_host_ms.offline", serve) == pytest.approx(2500.0)
     assert read("admit_host_ms.chat", serve) == pytest.approx(1000.0)
     assert read("engine_lookup_us.chat", serve) == pytest.approx(250e3)
-    assert read("idle_named_share.offline", serve) == \
+    assert read("admit_host_ms.offline", serve) == pytest.approx(1000.0)
+    # the share of the idle time a leaf span names is a metric of the
+    # train cell alone; a serving run keeps it in its ``program_spans`` line
+    assert read("idle_named_share.train", serve) == \
         pytest.approx(100 * 0.75 / 1.375)
+    assert ps.summary(serve[ps.KEY])["idle_named_share"] == \
+        pytest.approx(0.75 / 1.375)
     assert read("step_execute_ms.train", serve) is None     # no such span
     train = {"trace": {"window_s": 10.0},
              ps.KEY: ps.reduce_spans(TRAIN, (0.0, 10.0), [])}

@@ -40,6 +40,9 @@ CATALOG = {
 REDUCED = {"num_hidden_layers": 5, "num_dense_layers": 1,
            "layer_types": (PERIOD * 2)[:5], "num_experts": 32,
            "vocab_size": 25024}
+MOE = [name + ".moe" for name in (
+    "expert_tokens_per_round", "experts_touched_share", "decode_hbm_share",
+    "mfu")]
 
 
 @pytest.fixture(scope="module")
@@ -61,7 +64,8 @@ def built(cell, builder):
     return shapes, net, srv, ctx
 
 
-def test_the_cell_its_files_and_its_metrics_resolve_by_name(cell, builder):
+def test_the_cell_its_files_and_its_metrics_resolve_by_name(
+        cell, builder, bench):
     workload, config, traffic = cell
     assert workload["chips"] == 1
     assert traffic["driver"] == "serve_loop"
@@ -75,27 +79,33 @@ def test_the_cell_its_files_and_its_metrics_resolve_by_name(cell, builder):
                "state_bytes_per_slot", "flops_per_token",
                "full_forward_logits", "decode_bytes_per_round"):
         assert callable(getattr(builder, fn))
+    assert resolve.cell(bench, CELL) == cell
     names = {m["name"] for g in ("end_to_end", "per_layer")
-             for m in resolve.metrics_of(BENCH, g, CELL)}
+             for m in resolve.metrics_of(bench, g, CELL)}
     # the closed-loop reductions are the accepted cells' own entries, with
-    # this cell appended: one name a reduction, no twin files
-    assert names == {
+    # this cell appended: one name a reduction, no twin files.  A floor:
+    # what the cell reports at least, whatever later PRs add to it
+    assert names >= {
         "serve_tokens_per_s", "setup_s", "compile_s",
         "decode_round_ms.offline", "occupancy.offline",
         "steady_tokens_per_s.offline", "device_idle_share.offline",
-        "state_bytes_per_slot.reason", "expert_tokens_per_round.moe",
-        "experts_touched_share.moe", "decode_hbm_share.moe", "mfu.moe"}
+        "state_bytes_per_slot.reason"} | set(MOE)
     for g, sub in resolve.GROUP_DIRS.items():
-        for m in resolve.metrics_of(BENCH, g, CELL):
+        for m in resolve.metrics_of(bench, g, CELL):
             assert callable(resolve.load_module(sub, m["name"]).read)
-    new = [m for m in BENCH["per_layer"] if m["name"].endswith(".moe")]
-    assert [(m["workloads"], m["moves"], m["layer"]) for m in new] == [
-        ([CELL], "serve_tokens_per_s", "ops / kernels")] * 4
+    # the four entries this cell brought, by NAME (a later cell's entry
+    # may end ``.moe`` too), in the order it brought them
+    listed = [m["name"] for m in bench["per_layer"]]
+    at = [listed.index(name) for name in MOE]
+    assert at == sorted(at)
+    assert [(m["workloads"][0], m["moves"], m["layer"])
+            for m in (bench["per_layer"][i] for i in at)] == [
+        (CELL, "serve_tokens_per_s", "ops / kernels")] * 4
 
 
-def test_the_file_holds_the_source_and_states_the_cut(cell):
+def test_the_file_holds_the_source_and_states_the_cut(cell, bench):
     config = cell[1]
-    entry = next(c for c in BENCH["configs"] if c["name"] == config["name"])
+    entry = next(c for c in bench["configs"] if c["name"] == config["name"])
     assert sorted(entry["reduced"]) == sorted(config["reduced"]) \
         == sorted(REDUCED)
     # every key of the source, unchanged unless listed; never a width
