@@ -20,6 +20,9 @@ from .afmoe import (AfmoeModel, AfmoeForCausalLM, get_afmoe, afmoe_tiny,
 from . import pangu_moe
 from .pangu_moe import (PanguMoeModel, PanguMoeForCausalLM, get_pangu_moe,
                         pangu_moe_tiny, pangu_ultra_moe_ep16)
+from . import solar_open2
+from .solar_open2 import (SolarOpen2Model, SolarOpen2ForCausalLM,
+                          get_solar_open2, solar_open2_tiny, solar_open2_ep8)
 from . import hf_loader
 from .hf_loader import (read_safetensors, write_safetensors,
                         load_hf_llama, export_hf_llama,
@@ -51,6 +54,8 @@ __all__ = ["hf_loader", "read_safetensors", "write_safetensors",
            "get_afmoe", "afmoe_tiny", "trinity_large_ep8",
            "pangu_moe", "PanguMoeModel", "PanguMoeForCausalLM",
            "get_pangu_moe", "pangu_moe_tiny", "pangu_ultra_moe_ep16",
+           "solar_open2", "SolarOpen2Model", "SolarOpen2ForCausalLM",
+           "get_solar_open2", "solar_open2_tiny", "solar_open2_ep8",
            "nmt", "TransformerNMT", "BeamSearchScorer",
            "BeamSearchSampler", "get_nmt", "nmt_tiny",
            "transformer_en_de_512", "segmentation", "FCN", "DeepLabV3",

@@ -73,11 +73,12 @@ class _RMSNorm(HybridBlock):
 
 
 class _GatedAttention(HybridBlock):
-    """Grouped-query attention with per-head RMS norms on q and k, RoPE
-    only where ``rotary``, and a sigmoid gate on the heads' output."""
+    """Grouped-query attention with per-head RMS norms on q and k (where
+    ``qk_norm``), RoPE only on a window layer, and a sigmoid gate on the
+    heads' output."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim, eps,
-                 rope_base, window=None, **kwargs):
+                 rope_base, window=None, qk_norm=True, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise MXNetError(
@@ -86,6 +87,7 @@ class _GatedAttention(HybridBlock):
         self._h, self._kv, self._d = num_heads, num_kv_heads, head_dim
         self._base = float(rope_base)
         self.window = window                 # None: a full layer, no RoPE
+        self.qk_norm = qk_norm
         #: the device scope; the layer's norms around it carry it too
         self.scope = "mxtpu.mixer.swa" if window else "mxtpu.mixer.full"
         h, kv, d = num_heads, num_kv_heads, head_dim
@@ -93,8 +95,9 @@ class _GatedAttention(HybridBlock):
             # [q, k, v, gate] rows of ONE projection
             self.qkvg_proj = _dense((2 * h + 2 * kv) * d, units, False,
                                     "qkvg_")
-            self.q_norm = _RMSNorm(d, eps, prefix="qnorm_")
-            self.k_norm = _RMSNorm(d, eps, prefix="knorm_")
+            if qk_norm:
+                self.q_norm = _RMSNorm(d, eps, prefix="qnorm_")
+                self.k_norm = _RMSNorm(d, eps, prefix="knorm_")
             self.o_proj = _dense(units, h * d, False, "o_")
 
     def _qkvg(self, a, offset):
@@ -107,8 +110,10 @@ class _GatedAttention(HybridBlock):
         h, kv, d = self._h, self._kv, self._d
         q, k, v, g = _split(self.qkvg_proj(a),
                             (h * d, kv * d, kv * d, h * d))
-        q = self.q_norm(q.reshape((b, s, h, d)))
-        k = self.k_norm(k.reshape((b, s, kv, d)))
+        q = q.reshape((b, s, h, d))
+        q = self.q_norm(q) if self.qk_norm else q
+        k = k.reshape((b, s, kv, d))
+        k = self.k_norm(k) if self.qk_norm else k
         if self.window:
             q = nd.rope(q, offset=offset, base=self._base)
             k = nd.rope(k, offset=offset, base=self._base)
@@ -204,25 +209,36 @@ class _RoutedFFN(HybridBlock):
 
 class _Layer(HybridBlock):
     """``h + RMS_2(Mixer(RMS_1(h)))`` then ``h + RMS_4(FFN(RMS_3(h)))``;
-    ``mixer(prefix=...)`` builds the attention block (``pangu_moe.py``
-    gives another), ``moe`` the arguments of an expert layer's
-    ``_RoutedFFN``."""
+    with ``sandwich=False`` the pre-norm layer ``h + Mixer(RMS_1(h))``
+    then ``h + FFN(RMS_3(h))`` (no ``ln2``, ``ln4``).  ``mixer(prefix=...)``
+    builds the mixer (``pangu_moe.py`` and ``solar_open2.py`` give
+    others), ``moe`` the arguments of an expert layer's ``_RoutedFFN``."""
 
     def __init__(self, mixer, dense, units, hidden, moe_hidden, eps, moe,
-                 **kwargs):
+                 sandwich=True, **kwargs):
         super().__init__(**kwargs)
         self.dense = dense
+        self.sandwich = sandwich
         with self.name_scope():
             self.ln1 = _RMSNorm(units, eps, prefix="ln1_")
             self.attn = mixer(prefix="attn_")
-            self.ln2 = _RMSNorm(units, eps, prefix="ln2_")
+            if sandwich:
+                self.ln2 = _RMSNorm(units, eps, prefix="ln2_")
             self.ln3 = _RMSNorm(units, eps, prefix="ln3_")
             if dense:
                 self.ffn = _MLP(units, hidden, prefix="mlp_")
             else:
                 self.ffn = _RoutedFFN(units, moe_hidden, prefix="moe_",
                                       **moe)
-            self.ln4 = _RMSNorm(units, eps, prefix="ln4_")
+            if sandwich:
+                self.ln4 = _RMSNorm(units, eps, prefix="ln4_")
+
+    def _post(self, norm, x):
+        """A sub-layer's output as the residual stream takes it: through
+        its post-norm ``norm`` (``ln2``, ``ln4``) in a sandwich, else as
+        it is, in float32."""
+        return getattr(self, norm)(x) if self.sandwich \
+            else x.astype("float32")
 
     def pre(self, h, wdt):
         """The mixer's input: the first norm, in the weights' dtype."""
@@ -237,11 +253,11 @@ class _Layer(HybridBlock):
         device scope (``mxtpu.moe`` around an expert layer, whose router,
         experts and shared expert name themselves inside it)."""
         with device_scope(self.attn.scope):
-            h = h + self.ln2(mix)
+            h = h + self._post("ln2", mix)
         with device_scope("mxtpu.mlp" if self.dense else "mxtpu.moe"):
             m = self.ln3(h).astype(wdt)
             if self.dense:
-                return h + self.ln4(self.ffn(m))
+                return h + self._post("ln4", self.ffn(m))
             out, counted, selected = self.ffn.route(m, valid)
             rows = m.shape[0] * m.shape[1] if valid is None \
                 else valid.sum().astype("int32")
@@ -249,7 +265,7 @@ class _Layer(HybridBlock):
                 stats[i] = stats[i] + c
             if picked is not None:
                 picked.append(selected)
-            return h + self.ln4(out)
+            return h + self._post("ln4", out)
 
 
 class AfmoeModel(HybridBlock):

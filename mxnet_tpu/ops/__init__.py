@@ -14,4 +14,5 @@ from . import latent_attention  # noqa: F401  (MLA: expanded + absorbed)
 from . import det     # noqa: F401  (roi_align / box_nms / box_iou)
 from . import moe     # noqa: F401  (expert-parallel MoE FFN)
 from . import ssm     # noqa: F401  (selective scan + causal conv)
+from . import delta_rule  # noqa: F401  (gated delta rule, matrix state)
 from . import quantization_ops  # noqa: F401  (int8 quantize family)

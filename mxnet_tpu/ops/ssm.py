@@ -33,6 +33,31 @@ def _valid(last_pos, s):
         .astype(jnp.float32)
 
 
+def _in_chunks(chunk, *ts):
+    """The ``xs`` of a ``lax.scan`` over chunks: each (B, S, ...) array
+    padded with zeros along S to a multiple of ``min(chunk, S)`` positions
+    and cut into (chunks, B, chunk, ...).  Returns (ch, the arrays)."""
+    b, s = ts[0].shape[:2]
+    ch = min(int(chunk), s)
+    pad = (-s) % ch
+    nc = (s + pad) // ch
+    out = []
+    for t in ts:
+        if pad:
+            t = jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        out.append(t.reshape((b, nc, ch) + t.shape[2:])
+                   .transpose((1, 0) + tuple(range(2, t.ndim + 1))))
+    return ch, out
+
+
+def _from_chunks(ys, s):
+    """A scan's per-chunk outputs (chunks, B, chunk, ...) back to (B, S,
+    ...), the padding cut off."""
+    b = ys.shape[1]
+    ys = ys.transpose((1, 0) + tuple(range(2, ys.ndim)))
+    return ys.reshape((b, -1) + ys.shape[3:])[:, :s]
+
+
 def _ssm_terms(x, dt, dt_bias, a_log, bmat):
     """float32 (decay, drive) of the recurrence, ``(..., N, Di)``."""
     f32 = jnp.float32
@@ -93,15 +118,8 @@ def selective_scan(x, dt, bmat, cmat, a_log, d_skip, dt_bias, last_pos, *,
     delta = delta * valid                     # frozen past last_pos: exact
     xf = x.astype(f32) * valid
     cm = cmat.astype(f32)
-    ch = min(int(chunk), s)
-    pad = (-s) % ch
-    if pad:                                   # delta = 0, x = 0: no-ops
-        delta, xf, bm, cm = (jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
-                             for t in (delta, xf, bm, cm))
-    nc = (s + pad) // ch
-
-    def chunks(t):                            # (B, S, F) -> (nc, B, ch, F)
-        return t.reshape(b, nc, ch, t.shape[-1]).transpose(1, 0, 2, 3)
+    # padded positions have delta = 0, x = 0: no-ops
+    _ch, xs = _in_chunks(chunk, delta, xf, bm, cm)
 
     def combine(left, right):
         return left[0] * right[0], right[0] * left[1] + right[1]
@@ -114,10 +132,9 @@ def selective_scan(x, dt, bmat, cmat, a_log, d_skip, dt_bias, last_pos, *,
         st = acc_a * state[:, None] + acc_b
         return st[:, -1], jnp.einsum("bln,blnd->bld", cc, st)
 
-    state, ys = lax.scan(body, jnp.zeros((b, n, di), f32),
-                         (chunks(delta), chunks(xf), chunks(bm), chunks(cm)))
-    y = ys.transpose(1, 0, 2, 3).reshape(b, s + pad, di)[:, :s]
-    y = y + d_skip.astype(f32)[None, None, :] * xf[:, :s]
+    state, ys = lax.scan(body, jnp.zeros((b, n, di), f32), tuple(xs))
+    y = _from_chunks(ys, s)
+    y = y + d_skip.astype(f32)[None, None, :] * xf
     return y.astype(x.dtype), state
 
 

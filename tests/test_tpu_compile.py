@@ -6,8 +6,9 @@ too much VMEM, a kernel that cannot be partitioned); this file can, at
 about two seconds a kernel and no chip time.  Shapes are the main
 path's real widths: BERT-base, a Mistral prefill, 4096-causal, the
 ``window=`` band, the ``kmask=`` variant, one ``rtc.PallasKernel``, the
-per-row K,V page write of every serving cell's decode program, and the
-absorbed latent attention of a Pangu decode round.
+per-row K,V page write of every serving cell's decode program, the
+absorbed latent attention of a Pangu decode round, and the gated delta
+rule's scan and update (composed XLA, no kernel: held to calling none).
 Nothing runs, so nothing here says a kernel is RIGHT or FAST — that is
 ``chip_smoke.py`` and ``tests/test_on_tpu.py``.
 
@@ -225,6 +226,37 @@ def test_absorbed_latent_decode_expands_no_key_or_value_for_v5e(
         assert _mosaic_calls(compiled) == 0
         assert temp < 2 * (4 + 2) * scores
 
+
+
+def test_the_delta_rule_compiles_in_place_and_calls_nothing_for_v5e(
+        one_chip):
+    """The gated delta rule at the Solar cell's shapes (64 heads of 128):
+    the prefill scan of a 1,024-position rung and the one-token update of
+    256 slots.  Neither holds a kernel or a solver (the chunk's
+    triangular system is products); the update writes
+    the donated float32 state in place, its 1 GiB aliased to the output
+    with no temporary of its size."""
+    from mxnet_tpu.ops.delta_rule import delta_scan, delta_step
+    heads, d, f32 = 64, 128, jnp.float32
+
+    def sds(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    scan = jax.jit(delta_scan).lower(
+        sds((1, 1024, heads, d)), sds((1, 1024, heads, d)),
+        sds((1, 1024, heads, d), jnp.bfloat16), sds((1, 1024, heads, d)),
+        sds((1, 1024, heads)), sds((1, heads, d, d)), sds((1,))).compile()
+    state = 256 * heads * d * d * 4
+    step = jax.jit(delta_step, donate_argnums=(5,)).lower(
+        sds((256, heads, d)), sds((256, heads, d)),
+        sds((256, heads, d), jnp.bfloat16), sds((256, heads, d)),
+        sds((256, heads)), sds((256, heads, d, d))).compile()
+    for compiled in (scan, step):
+        text = compiled.as_text()
+        assert _mosaic_calls(compiled) == 0
+        assert "triangular-solve" not in text and "lapack" not in text
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes == state
+    assert mem.temp_size_in_bytes < state // 8
 
 # -- whole programs: every product carries a device scope ---------------------
 

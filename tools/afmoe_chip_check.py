@@ -21,9 +21,12 @@ the program's picks (``given``), at the stated precision and with the
 control the builder's reference knows (a ``PRECISIONS`` key beyond those
 three: Pangu's ``softmax_bfloat16``), and for a latent-attention model a
 reference whose shared rotated key is zero (the ``q_r . k_r`` term left
-out), each as the cell would run it.  PERF.md section 6 (PR 33, PR 35)
-has the readings.  ``--rehearse`` runs the config's toy shapes on
-any backend.
+out), each as the cell would run it.  For a model whose reference also
+compares the served matrix states (``--cell
+solar_open2_ep8.bulkgen_closed``) every control runs through that whole
+comparison, and the reference's line gives the states' readings.  PERF.md
+section 6 has the readings.  ``--rehearse`` runs the config's toy shapes
+on any backend.
 """
 import argparse
 import json
@@ -118,6 +121,16 @@ def main():
         if any(k.endswith("attn_dkv_weight") for k in weights):
             controls.append("no_k_r_term")
         for name in controls:
+            if hasattr(builder, "served_states"):
+                # a model whose reference also holds the served matrix
+                # states to its own: the control as the cell would run it
+                worst = max(_regrets(builder.full_forward_logits(
+                    net, tokens, ctx, precision=name), req))
+                print(json.dumps({
+                    "seed": seed, "rule": f"the {name} control, as the cell "
+                    "would run it", "probe_worst_regret_share": worst,
+                    "gap_share": limit, "held": worst <= limit}), flush=True)
+                continue
             w, precision = weights, name
             if name == "no_k_r_term":
                 # latent attention without its shared rotated key: the
