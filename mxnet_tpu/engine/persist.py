@@ -66,8 +66,10 @@ __all__ = ["enabled", "cache_dir", "fingerprint", "aval_sig",
 #: absorbed latent attention inside Pangu's decode program a kernel.
 #: 4: PR 37 put ``mxtpu.*`` device scopes on the fused train step and on
 #: BERT's and Llama's blocks: metadata only, but an older entry's
-#: executable reads ``(no scope)`` in ``profiler.device_dumps``
-LIBRARY_SALT = "mxtpu-compile-cache-4"
+#: executable reads ``(no scope)`` in ``profiler.device_dumps``.
+#: 5: the grouped expert products inside every routed-expert model's
+#: programs became one Pallas kernel (``ops/grouped_matmul.py``)
+LIBRARY_SALT = "mxtpu-compile-cache-5"
 
 _MAGIC = b"MXTPUCC1"
 #: header layout version; 2 added ``devices`` (an entry without it

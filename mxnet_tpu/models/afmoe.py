@@ -194,9 +194,10 @@ class _RoutedFFN(HybridBlock):
         bias = nd.zeros(self.router.shape[:1], ctx=ctx) \
             if self.bias is None else self.bias.data(ctx)
         # the op names its own halves mxtpu.moe.router / mxtpu.moe.experts.
-        # The TPU compiler gives a grouped product an ``op_name`` of its
-        # own (``ragged-dot-none``) and keeps only the CALL SITE's
-        # prefix: the call says ``.experts`` so that they do
+        # Where the grouped products are ``lax.ragged_dot`` (no kernel),
+        # the TPU compiler gives each an ``op_name`` of its own
+        # (``ragged-dot-none``) and keeps only the CALL SITE's prefix: the
+        # call says ``.experts`` so that they do
         with device_scope("mxtpu.moe.experts"):
             out, held, touched, selected = nd._contrib_RoutedExperts(
                 m.reshape((b * s, u)), self.router.data(ctx),

@@ -20,7 +20,8 @@ it holds, routes over all of them (sigmoid scores, a selection bias that
 picks and does not weigh, normalised and scaled gates), and returns the
 partial sum its own experts give.  No capacity and no dropped token:
 assignments are sorted by expert and the products are grouped
-(``jax.lax.ragged_dot``), so any imbalance is exact.  It runs without an
+(``grouped_matmul.expert_sum``: ``jax.lax.ragged_dot``, or on a TPU one
+expert-major Pallas kernel), so any imbalance is exact.  It runs without an
 exchange; nothing here stands in for the absent chips.  Two layers until
 one serves both (ROADMAP, debts).
 """
@@ -31,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..profiler import device_scope
+from . import grouped_matmul
 from .registry import register
 
 
@@ -153,14 +155,7 @@ def routed_experts(x, scores_w, bias, w_gate, w_up, w_down, *rest, k=1,
         back = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=order.dtype))
     with device_scope("mxtpu.moe.experts"):
-        xs = jnp.take(x, order // k, axis=0)
-        grouped = dict(group_sizes=sizes, preferred_element_type=f32)
-        mid = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, **grouped)) \
-            * jax.lax.ragged_dot(xs, w_up, **grouped)
-        ys = jax.lax.ragged_dot(mid.astype(x.dtype), w_down, **grouped)
-        # rows past the last group belong to no held expert: what a
-        # grouped product leaves there is not a result
-        ys = jnp.where(g_sorted[:, None] > 0, ys * g_sorted[:, None], 0.0)
-        out = jnp.sum(jnp.take(ys, back, axis=0).reshape(t, k, -1), axis=1)
+        out = grouped_matmul.expert_sum(x, order // k, sizes, g_sorted, back,
+                                        w_gate, w_up, w_down)
     return (out, jnp.sum(held, dtype=jnp.int32),
             jnp.sum(sizes > 0, dtype=jnp.int32), selected.astype(jnp.int32))

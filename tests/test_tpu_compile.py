@@ -7,8 +7,11 @@ about two seconds a kernel and no chip time.  Shapes are the main
 path's real widths: BERT-base, a Mistral prefill, 4096-causal, the
 ``window=`` band, the ``kmask=`` variant, one ``rtc.PallasKernel``, the
 per-row K,V page write of every serving cell's decode program, the
-absorbed latent attention of a Pangu decode round, and the gated delta
-rule's scan and update (composed XLA, no kernel: held to calling none).
+absorbed latent attention of a Pangu decode round, the gated delta
+rule's scan and update (composed XLA, no kernel: held to calling none),
+and the grouped expert products of the three expert cells, alone at
+each cell's decode and largest prefill and inside a tiny expert
+server's decode program.
 Nothing runs, so nothing here says a kernel is RIGHT or FAST — that is
 ``chip_smoke.py`` and ``tests/test_on_tpu.py``.
 
@@ -257,6 +260,82 @@ def test_the_delta_rule_compiles_in_place_and_calls_nothing_for_v5e(
     mem = step.memory_analysis()
     assert mem.alias_size_in_bytes == state
     assert mem.temp_size_in_bytes < state // 8
+
+# one expert layer's grouped products in each expert cell: held experts,
+# d, h, top-k, the rows of a decode round and of the largest prefill rung
+_EXPERTS = {
+    "solar_open2_ep8": (40, 4096, 1280, 8, 256, 1024),
+    "pangu_ultra_moe_ep16": (16, 7680, 2048, 8, 160, 1024),
+    "trinity_large_ep8": (32, 3072, 3072, 4, 160, 512),
+}
+
+
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+@pytest.mark.parametrize("cell", sorted(_EXPERTS))
+def test_grouped_expert_products_are_one_kernel_for_v5e(one_chip, cell,
+                                                        phase):
+    """``grouped_matmul.expert_sum`` at the cell's widths, bfloat16, for
+    a TPU: ONE Mosaic call, the expert-major kernel, and no
+    ``ragged-dot`` (whose row tile of up to 512 made Solar's products
+    MXU-bound on padding, PERF.md section 5); it compiles within the
+    VMEM it asks for, which stays well inside the chip's 128 MiB."""
+    from mxnet_tpu.ops import grouped_matmul as gm
+    n, d, h, k, decode_rows, prefill_rows = _EXPERTS[cell]
+    t = decode_rows if phase == "decode" else prefill_rows
+    m = t * k
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    i32 = jnp.int32
+    compiled = jax.jit(gm.expert_sum).lower(
+        sds((t, d)), sds((m,), i32), sds((n,), i32), sds((m,), jnp.float32),
+        sds((m,), i32), sds((n, d, h)), sds((n, d, h)),
+        sds((n, h, d))).compile()
+    text = compiled.as_text()
+    assert _mosaic_calls(compiled) == 1
+    assert "grouped_expert_ffn" in text and "ragged" not in text
+    bh = gm._block(d, h, 2)
+    assert h % bh == 0 and 3 * d * bh * 2 <= gm._STEP_BYTES
+
+
+def test_a_tiny_expert_servers_decode_program_holds_the_kernel_for_v5e(
+        one_chip, monkeypatch):
+    """An AFMoE decoder at widths of 128 (the tiny configuration's
+    routing, 8 of 16 experts held), its decode program as ``Server``
+    traces it, compiled for the described chip: each expert layer's
+    products are the kernel, under ``mxtpu.moe.experts``, and no
+    ``ragged-dot`` is left."""
+    import numpy as np
+    import mxnet_tpu as mx
+    from mxnet_tpu import engine
+    from mxnet_tpu.models.afmoe import AfmoeForCausalLM, afmoe_tiny
+    from mxnet_tpu.serving import Server
+
+    np.random.seed(0)
+    lm = AfmoeForCausalLM(afmoe_tiny(vocab_size=61, units=128,
+                                     moe_hidden=128, experts_held=(4, 8)))
+    lm.initialize(mx.init.Xavier())
+    srv = Server(lm, buckets=[(4, 16)], max_new_tokens=4)
+    got = {}
+
+    def capture(name, pure, attrs, *flat, **_kw):
+        got["fn"], got["flat"] = pure, flat
+        raise _Captured()
+
+    with monkeypatch.context() as m:
+        m.setattr(engine, "invoke_compiled", capture)
+        with pytest.raises(_Captured):
+            srv._decode_impl(srv.sched.buckets[0], 1)
+    compiled = jax.jit(got["fn"]).lower(
+        *_for_chip(list(got["flat"]), one_chip)).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_decode_b4x16")
+    assert "ragged" not in text
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "grouped_expert_ffn" in line]
+    assert len(kernels) == 4                     # one an expert layer
+    assert all("mxtpu.moe.experts" in line for line in kernels)
+
 
 # -- whole programs: every product carries a device scope ---------------------
 
